@@ -18,7 +18,7 @@ per-op grad ops (python/paddle/fluid/backward.py).
 import jax
 import jax.numpy as jnp
 
-from ..ops.registry import get_kernel, KernelCtx, accel
+from ..ops.registry import get_kernel, KernelCtx, accel, lowering_for
 from .framework import grad_var_name
 from .dtypes import is_float
 
@@ -376,6 +376,13 @@ def build_step_fn(program, fetch_names, is_test, place,
             exec_op(e, op, i, key, is_test, place, block)
 
     def step(persist, feed, key):
+        # Pallas dispatch (trace-time) follows the Place this step is
+        # compiled for; place=None callers scope it themselves or take
+        # the process default
+        with lowering_for(getattr(place, "platform", None)):
+            return _step(persist, feed, key)
+
+    def _step(persist, feed, key):
         env = {}
         env.update(feed)
         env.update(persist)

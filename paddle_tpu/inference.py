@@ -182,9 +182,9 @@ class InferenceEngine:
     def _compile_fn(self, sig):
         """Build + cache the jitted step for `sig`; caller holds the
         single-flight leadership for this signature. The trace/compile
-        is retried under the resilience policy: on relay-attached
-        backends a compile RPC can flake (UNAVAILABLE / deadline) —
-        transient failures (incl. the inference.compile chaos point)
+        is retried under the resilience policy: a remote compile
+        service can flake (UNAVAILABLE / deadline) — transient
+        failures (incl. the inference.compile chaos point)
         are absorbed, real trace errors classify fatal and surface
         unchanged."""
         from .resilience import chaos as _chaos
@@ -308,13 +308,7 @@ class InferenceEngine:
         lowered = jax.jit(
             lambda p, f: fn(p, f)).lower(self._persist, feed)
         compiled = lowered.compile()
-        try:
-            cost = compiled.cost_analysis()
-        except Exception:
-            cost = {}
-        if isinstance(cost, (list, tuple)):
-            # older jax wraps the per-executable dict in a list
-            cost = cost[0] if cost else {}
+        cost = compiled.cost_analysis() or {}
         return {"flops": cost.get("flops"),
                 "bytes accessed": cost.get("bytes accessed"),
                 "signature": sorted(feed_shapes.items())}

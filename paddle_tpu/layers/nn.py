@@ -1405,7 +1405,7 @@ def multi_head_attention(queries, keys, values, attn_bias=None, d_key=64,
     `..._qkv`/`..._kv` weight), so checkpoints are not interchangeable
     between the two layouts — therefore OPT-IN (default off keeps every
     existing model's names and checkpoints stable); the perf paths
-    (bench.py, tools/mfu_probe.py) opt in with fused_qkv=True."""
+    (bench.py, chip_smoke.py) opt in with fused_qkv=True."""
     from . import tensor as _t
     if fused_qkv is None:
         fused_qkv = False

@@ -32,7 +32,7 @@ class TransformerConfig:
         # one [d, 3HDh] qkv matmul (MXU tiling) — OPT-IN: the default
         # False keeps the reference's per-projection weight names, so
         # checkpoints from prior builds / converted reference models
-        # load unchanged; the perf paths (bench.py, tools/mfu_probe.py)
+        # load unchanged; the perf paths (bench.py, chip_smoke.py)
         # pass fused_qkv=True explicitly
         self.fused_qkv = fused_qkv
 
@@ -608,7 +608,7 @@ class IncrementalDecoder:
         # fused dequantize-attend for the int8 cache. Each call below
         # still self-gates (try_* convention) — None keeps the exact
         # jnp composition, and PADDLE_TPU_KERN=off never loads kern.
-        from ..ops.registry import accel as _accel
+        from ..ops.registry import accel as _accel, lowering_for
         fused_dequant = _accel("dequant_attend_int8") if quant else None
         fused_decode = None if quant else _accel("decode_attend")
 
@@ -642,7 +642,13 @@ class IncrementalDecoder:
             def cache_read(c, i):
                 return c[0][i]
 
-        def body(p, kcache, vcache, ck, cv, src_bias, ids, pos, seed):
+        def body(*args):
+            # the registry consults inside follow this decoder's device
+            # (device=None: the process default)
+            with lowering_for(getattr(self.device, "platform", None)):
+                return _body(*args)
+
+        def _body(p, kcache, vcache, ck, cv, src_bias, ids, pos, seed):
             rows = jnp.arange(S)
             x = jnp.take(p["trg_emb.w_0"],
                          jnp.clip(ids.astype(jnp.int32), 0, V - 1),
@@ -720,9 +726,7 @@ class IncrementalDecoder:
                     logits.astype(jnp.float32))
 
         # flat signatures so donation sees individual cache buffers;
-        # donating the caches on accelerators keeps the update in
-        # place (CPU can't donate — jax warns and copies)
-        cpu = jax.default_backend() == "cpu"
+        # donating the caches keeps the update in place
         if quant:
             def step(p, kc_q, kc_s, vc_q, vc_s, ck, cv, src_bias,
                      ids, pos, seed):
@@ -731,14 +735,14 @@ class IncrementalDecoder:
                     ids, pos, seed)
                 out = kcache + vcache + (nxt,)
                 return out + (lg,) if ret_logits else out
-            donate = () if cpu else (1, 2, 3, 4)
+            donate = (1, 2, 3, 4)
         else:
             def step(p, kc, vc, ck, cv, src_bias, ids, pos, seed):
                 kcache, vcache, nxt, lg = body(
                     p, (kc,), (vc,), ck, cv, src_bias, ids, pos, seed)
                 out = kcache + vcache + (nxt,)
                 return out + (lg,) if ret_logits else out
-            donate = () if cpu else (1, 2)
+            donate = (1, 2)
         return jax.jit(step, donate_argnums=donate)
 
     # ------------------------------------------------- compile sharing

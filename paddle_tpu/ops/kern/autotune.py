@@ -56,15 +56,12 @@ def reset():
 def platform():
     """The timing platform component of the key. Interpret mode is its
     OWN platform: interpreter timings must never warm a hardware key."""
+    import jax
     from ..pallas import flash_attention as fa
     use, interpret = fa.active()
-    if use and interpret:
-        return "interpret"
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:
-        return "unknown"
+    if use:
+        return "interpret" if interpret else "tpu"
+    return jax.default_backend()
 
 
 def _dtype_of(args):

@@ -6,7 +6,7 @@ at its own position, so the effective attention is RAGGED: slot s
 attends to t <= pos[s] of a fixed T_max buffer. The jnp composition
 materializes [S, H, T] scores and, on the int8 cache, a fully
 dequantized fp32 [S, T, H, Dh] copy of BOTH caches every step. These
-kernels stream the cache through VMEM in (block_t, Dh) tiles with
+kernels stream the cache through VMEM in (block_t, H, Dh) tiles with
 flash-style online softmax instead:
 
 - decode_attend       fp32/bf16 cache: one pass over K and V, no
@@ -20,11 +20,12 @@ flash-style online softmax instead:
                       copy never exists, so HBM read bytes drop ~4x on
                       the decode hot path (the EQuARX fusion argument).
 
-Grid is (slots, heads, n_t) with t innermost and "arbitrary" (online
-softmax carries m/l/acc scratch across t-steps, exactly the flash
-kernel's structure); q rows are [1, Dh] tiles — legal Mosaic blocks by
-the block==dim rule the flash bias rows already rely on. pos arrives
-lane-replicated [S, 128] (1-lane vectors are not a legal VMEM tile).
+Grid is (slots, n_t) with t innermost and "arbitrary" (online softmax
+carries m/l/acc scratch across t-steps, exactly the flash kernel's
+structure). Every block keeps the cache's own last two dims (H, Dh)
+whole — q and the output as [H, Dh] rows, K/V as [bt, H, Dh] tiles —
+which is the one block shape Mosaic takes at any head count and width;
+pos rides scalar prefetch (SMEM) and steers the cache index_map.
 
 Numerics convention matches the decoder composition exactly: f32
 logits, mask to -1e30 (vs the composition's -inf — both vanish in
@@ -71,21 +72,31 @@ def _pick_bt(T, pref=None):
 
 
 # ------------------------------------------------------------ kernels
-def _attend_body(s, pos, j, bt, v_f, m_ref, l_ref, acc_ref):
-    """Shared online-softmax update for one [1, bt] score row against a
-    [bt, Dh] value tile."""
-    k_pos = j * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+# One grid step handles ALL heads of one slot for one [bt] stretch of
+# the pool: the cache block is (bt, H, Dh) — the array's own last two
+# dims, so Mosaic's (8, 128)-or-full block rule holds at any head count
+# and head width, and heads sit on sublanes exactly as the cache stores
+# them. A single query row per head makes the "matmul" a matrix-vector
+# product, done on the VPU as multiply + lane reduce with every
+# intermediate kept rank-3 ([bt, H, 1]) so no lane<->sublane shuffle
+# or in-kernel reshape is needed.
+def _attend_block(q, k_f, v_f, pos, j, bt, scale, m_ref, l_ref, acc_ref):
+    """Online-softmax update for one block. q [H, Dh]; k_f/v_f
+    [bt, H, Dh] (or a lane-broadcastable factorization of them, see
+    _dequant_kernel); scratch m/l [H, LANES] lane-replicated, acc
+    [H, Dh]."""
+    s = jnp.sum(k_f * q[None], axis=-1, keepdims=True) * scale  # [bt,H,1]
+    k_pos = j * bt + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     s = jnp.where(k_pos <= pos, s, fa._NEG_INF)
-    m_prev = m_ref[...][:, :1]
+    m_prev = m_ref[...][:, :1]                                   # [H, 1]
     l_prev = l_ref[...][:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+    p = jnp.exp(s - m_new[None])                                 # [bt,H,1]
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    l_new = l_prev * alpha + jnp.sum(p, axis=0)
     m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
     l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-    acc_ref[...] = acc_ref[...] * alpha + fa._dot(
-        p.astype(v_f.dtype), v_f)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v_f, axis=0)
 
 
 def _init(j, m_ref, l_ref, acc_ref):
@@ -105,90 +116,112 @@ def _flush(j, n_t, l_ref, acc_ref, o_ref):
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref,
+def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale, n_t, bt):
-    """q_ref [1, Dh]; k/v_ref [bt, Dh]; pos_ref [1, LANES] int32."""
-    j = pl.program_id(2)
+    """pos_ref [S] int32 in SMEM (scalar prefetch); q_ref/o_ref [H, Dh];
+    k/v_ref [bt, H, Dh]."""
+    j = pl.program_id(1)
     _init(j, m_ref, l_ref, acc_ref)
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
 
-    @pl.when(j * bt <= pos)   # whole blocks above pos never load compute
+    @pl.when(j * bt <= pos)   # whole blocks above pos: no compute
     def _compute():
-        s = fa._dot_t(q_ref[...], k_ref[...]) * scale        # [1, bt]
-        _attend_body(s, pos, j, bt, v_ref[...], m_ref, l_ref, acc_ref)
+        _attend_block(q_ref[...].astype(jnp.float32),
+                      k_ref[...].astype(jnp.float32),
+                      v_ref[...].astype(jnp.float32),
+                      pos, j, bt, scale, m_ref, l_ref, acc_ref)
 
     _flush(j, n_t, l_ref, acc_ref, o_ref)
 
 
-def _dequant_kernel(q_ref, kq_ref, ks_ref, vq_ref, vs_ref, pos_ref,
-                    o_ref, m_ref, l_ref, acc_ref, *, scale, n_t, bt,
-                    qblock):
-    """int8 codes [bt, Dh] + scales [bt, Dh/qblock] per tile; dequantize
-    in VMEM right before each dot — no fp32 cache copy in HBM."""
-    j = pl.program_id(2)
+def _lane_scales(s, dh):
+    """Per-block scales [bt, H, nb] -> a factor that multiplies a
+    [bt, H, dh] tile: [bt, H, 1] (lane broadcast) for one block per
+    head, else [bt, H, dh] built by nb lane-iota selects (no reshape of
+    the tile)."""
+    nb = s.shape[-1]
+    if nb == 1:
+        return s
+    blk = jax.lax.broadcasted_iota(jnp.int32, (1, 1, dh), 2) // (dh // nb)
+    out = jnp.zeros(s.shape[:2] + (dh,), jnp.float32)
+    for b in range(nb):
+        out = jnp.where(blk == b, s[:, :, b:b + 1], out)
+    return out
+
+
+def _dequant_kernel(pos_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
+                    o_ref, m_ref, l_ref, acc_ref, *, scale, n_t, bt):
+    """int8 codes [bt, H, Dh] + scales [bt, H, Dh/qblock] per tile;
+    dequantize in VMEM right before use — no fp32 cache copy in HBM."""
+    j = pl.program_id(1)
     _init(j, m_ref, l_ref, acc_ref)
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
 
     @pl.when(j * bt <= pos)
     def _compute():
-        nb = ks_ref.shape[1]
-        dh = kq_ref.shape[1]
-        k_f = (kq_ref[...].astype(jnp.float32).reshape(bt, nb, qblock)
-               * ks_ref[...][..., None]).reshape(bt, dh)
-        s = fa._dot_t(q_ref[...].astype(jnp.float32), k_f) * scale
-        v_f = (vq_ref[...].astype(jnp.float32).reshape(bt, nb, qblock)
-               * vs_ref[...][..., None]).reshape(bt, dh)
-        _attend_body(s, pos, j, bt, v_f, m_ref, l_ref, acc_ref)
+        dh = kq_ref.shape[-1]
+        k_f = kq_ref[...].astype(jnp.float32) * _lane_scales(
+            ks_ref[...], dh)
+        v_f = vq_ref[...].astype(jnp.float32) * _lane_scales(
+            vs_ref[...], dh)
+        _attend_block(q_ref[...].astype(jnp.float32), k_f, v_f,
+                      pos, j, bt, scale, m_ref, l_ref, acc_ref)
 
     _flush(j, n_t, l_ref, acc_ref, o_ref)
 
 
 # -------------------------------------------------------------- calls
-def _common_wiring(S, H, Dh, T, bt, q, inputs, in_specs, kernel, interpret):
+def _call(kernel, q, caches, pos, bt, interpret):
+    """Shared wiring: grid (slots, T/bt) with t innermost; pos rides
+    scalar prefetch so the cache index_map can stop at each slot's last
+    live block — steps past it re-name the same block and Mosaic's
+    pipeline skips the DMA, which is what makes the read ragged."""
+    S, H, Dh = q.shape
+    T = caches[0].shape[1]
     n_t = T // bt
-    pos_rep = inputs[-1]
-    out = pl.pallas_call(
+
+    def row(s, j, pos_ref):
+        return (s, 0, 0)
+
+    def blk(s, j, pos_ref):
+        last = jnp.clip(pos_ref[s] // bt, 0, n_t - 1)
+        return (s, jnp.minimum(j, last), 0, 0)
+
+    in_specs = [pl.BlockSpec((None, H, Dh), row)] + [
+        pl.BlockSpec((None, bt, H, c.shape[-1]), blk) for c in caches]
+    return pl.pallas_call(
         kernel,
-        grid=(S, H, n_t),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, 1, Dh), lambda s, h, j: (s, h, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S, n_t),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, H, Dh), row),
+            scratch_shapes=[
+                pltpu.VMEM((H, fa._LANES), jnp.float32),
+                pltpu.VMEM((H, fa._LANES), jnp.float32),
+                pltpu.VMEM((H, Dh), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((S, H, Dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, fa._LANES), jnp.float32),
-            pltpu.VMEM((1, fa._LANES), jnp.float32),
-            pltpu.VMEM((1, Dh), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(*inputs)
-    del pos_rep
-    return out
+    )(pos.astype(jnp.int32), q, *caches)
 
 
 def decode_attend(q, k, v, pos, scale=None, block_t=None,
                   interpret=False):
     """q [S,H,Dh], k/v [S,T,H,Dh], pos [S] int32 (attend to t <=
     pos[s]) -> [S,H,Dh]."""
-    S, H, Dh = q.shape
+    Dh = q.shape[-1]
     T = k.shape[1]
     scale = float(scale) if scale is not None else Dh ** -0.5
     bt = _pick_bt(T, block_t)
     if not bt:
         raise NotImplementedError("pool depth must tile")
     STATS["pallas_calls"] += 1
-    pos_rep = jnp.broadcast_to(pos.astype(jnp.int32)[:, None],
-                               (S, fa._LANES))
-    in_specs = [
-        pl.BlockSpec((None, 1, Dh), lambda s, h, j: (s, h, 0)),
-        pl.BlockSpec((None, bt, None, Dh), lambda s, h, j: (s, j, h, 0)),
-        pl.BlockSpec((None, bt, None, Dh), lambda s, h, j: (s, j, h, 0)),
-        pl.BlockSpec((1, fa._LANES), lambda s, h, j: (s, 0)),
-    ]
     kern = functools.partial(_decode_kernel, scale=scale, n_t=T // bt,
                              bt=bt)
-    return _common_wiring(S, H, Dh, T, bt, q, (q, k, v, pos_rep),
-                          in_specs, kern, interpret)
+    return _call(kern, q, (k, v), pos, bt, interpret)
 
 
 def dequant_attend(q, kq, ks, vq, vs, pos, scale=None, block_t=None,
@@ -196,30 +229,16 @@ def dequant_attend(q, kq, ks, vq, vs, pos, scale=None, block_t=None,
     """q [S,H,Dh] f32; kq/vq [S,T,H,Dh] int8; ks/vs [S,T,H,Dh/qblock]
     f32 per-block scales; pos [S] int32 -> [S,H,Dh] f32. qblock is
     implied by the scale layout (Dh // ks.shape[-1])."""
-    S, H, Dh = q.shape
+    Dh = q.shape[-1]
     T = kq.shape[1]
-    nb = ks.shape[-1]
-    qblock = Dh // nb
     scale = float(scale) if scale is not None else Dh ** -0.5
     bt = _pick_bt(T, block_t)
     if not bt:
         raise NotImplementedError("pool depth must tile")
     STATS["pallas_calls"] += 1
-    pos_rep = jnp.broadcast_to(pos.astype(jnp.int32)[:, None],
-                               (S, fa._LANES))
-    in_specs = [
-        pl.BlockSpec((None, 1, Dh), lambda s, h, j: (s, h, 0)),
-        pl.BlockSpec((None, bt, None, Dh), lambda s, h, j: (s, j, h, 0)),
-        pl.BlockSpec((None, bt, None, nb), lambda s, h, j: (s, j, h, 0)),
-        pl.BlockSpec((None, bt, None, Dh), lambda s, h, j: (s, j, h, 0)),
-        pl.BlockSpec((None, bt, None, nb), lambda s, h, j: (s, j, h, 0)),
-        pl.BlockSpec((1, fa._LANES), lambda s, h, j: (s, 0)),
-    ]
     kern = functools.partial(_dequant_kernel, scale=scale, n_t=T // bt,
-                             bt=bt, qblock=qblock)
-    return _common_wiring(S, H, Dh, T, bt, q,
-                          (q, kq, ks, vq, vs, pos_rep), in_specs, kern,
-                          interpret)
+                             bt=bt)
+    return _call(kern, q, (kq, ks, vq, vs), pos, bt, interpret)
 
 
 # ---------------------------------------------------------- reference

@@ -19,7 +19,8 @@ not that it silently fell back.
 import functools
 
 __all__ = ["KernelSpec", "register", "get", "names", "specs", "adapter",
-           "dispatch", "parity_check", "STATS", "KERN_SPECS", "ADAPTERS"]
+           "dispatch", "parity_check", "compare_leaves", "STATS",
+           "KERN_SPECS", "ADAPTERS"]
 
 KERN_SPECS = {}   # kernel name -> KernelSpec
 ADAPTERS = {}     # adapter key (op type or library-call name) -> kernel name
@@ -143,25 +144,27 @@ def _leaves(tree):
     return [tree]
 
 
-def parity_check(name, args, kwargs=None):
-    """The numerics gate every registered kernel carries: run fn vs
-    reference on the same inputs, compare within spec.tol. Returns
-    (ok, detail) — ok is None when the kernel's own gate rejected the
-    inputs (nothing ran, nothing to compare)."""
+def compare_leaves(got, ref, tol, scale_atol=False):
+    """Compare two output trees leaf by leaf within `tol` = (rtol,
+    atol): integers exactly, floats by |g - r| <= atol + rtol * |r|.
+    `tol` is an fp32 tolerance — a leaf stored in fewer bits cannot
+    agree more closely than its own epsilon, so both terms are floored
+    there. `scale_atol` scales atol by the leaf's largest magnitude
+    (for long reductions, whose error follows the size of the terms
+    and not of a possibly cancelling result). Returns (ok, detail)."""
     import numpy as np
-    spec = get(name)
-    kwargs = dict(kwargs or {})
-    out = spec.fn(*args, **kwargs)
-    if out is None:
-        return None, "probe rejected (jnp fallback path)"
-    ref = spec.reference(*args, **kwargs)
-    got_l, ref_l = _leaves(out), _leaves(ref)
+    import jax.numpy as jnp
+    got_l, ref_l = _leaves(got), _leaves(ref)
     if len(got_l) != len(ref_l):
         return False, (f"output arity {len(got_l)} != reference "
                        f"{len(ref_l)}")
-    rtol, atol = spec.tol
     worst = 0.0
     for i, (g, r) in enumerate(zip(got_l, ref_l)):
+        rtol, atol = tol
+        if jnp.issubdtype(g.dtype, jnp.floating) \
+                and jnp.finfo(g.dtype).bits < 32:
+            eps = float(jnp.finfo(g.dtype).eps)
+            rtol, atol = max(rtol, eps), max(atol, eps)
         g, r = np.asarray(g), np.asarray(r)
         if g.shape != r.shape:
             return False, f"leaf {i}: shape {g.shape} != {r.shape}"
@@ -170,9 +173,32 @@ def parity_check(name, args, kwargs=None):
                 return False, f"leaf {i}: integer mismatch"
             continue
         g64, r64 = g.astype(np.float64), r.astype(np.float64)
-        err = np.abs(g64 - r64) - (atol + rtol * np.abs(r64))
-        worst = max(worst, float(err.max(initial=0.0)))
-        if worst > 0:
+        if scale_atol:
+            atol *= max(1.0, float(np.abs(r64).max(initial=0.0)))
+        diff = np.abs(g64 - r64)
+        worst = max(worst, float(diff.max(initial=0.0)))
+        over = float((diff - (atol + rtol * np.abs(r64))).max(
+            initial=0.0))
+        if not over <= 0:       # NaN fails too
             return False, (f"leaf {i}: tolerance exceeded by "
-                           f"{worst:.3e} (rtol={rtol}, atol={atol})")
-    return True, f"max over-tolerance 0.0 ({len(got_l)} outputs)"
+                           f"{over:.3e} (rtol={rtol}, atol={atol})")
+    return True, f"max |diff| {worst:.3e} over {len(got_l)} outputs"
+
+
+def parity_check(name, args, kwargs=None):
+    """The numerics gate every registered kernel carries: run fn as
+    deployed vs the reference at "highest" matmul precision on the
+    same inputs, compare within spec.tol (compare_leaves). Returns
+    (ok, detail) — ok is None when the kernel's own gate rejected the
+    inputs (nothing ran, nothing to compare)."""
+    import jax
+    spec = get(name)
+    kwargs = dict(kwargs or {})
+    out = spec.fn(*args, **kwargs)
+    if out is None:
+        return None, "probe rejected (jnp fallback path)"
+    # the ground truth multiplies at full precision: on a TPU the
+    # default for an fp32 jnp matmul is a single bf16 pass
+    with jax.default_matmul_precision("highest"):
+        ref = spec.reference(*args, **kwargs)
+    return compare_leaves(out, ref, spec.tol)

@@ -87,7 +87,11 @@ def _pool_kernel(inv_ref, w_ref, tab_ref, out_ref, *, mean):
     for f in range(F):
         hit = (iota == inv[:, f:f + 1]).astype(jnp.float32)
         counts += hit * w[:, f:f + 1] if has_w else hit
+    # HIGHEST: this matmul IS the gather, and at the MXU's default an
+    # fp32 operand is cut to one bf16 pass — rows would come back with
+    # 8 mantissa bits (seen on v5e: 3e-3 off the jnp gather)
     acc = jnp.dot(counts, tab_ref[...].astype(jnp.float32),
+                  precision=jax.lax.Precision.HIGHEST,
                   preferred_element_type=jnp.float32)
     if mean:
         denom = jnp.maximum(

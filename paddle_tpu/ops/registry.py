@@ -15,11 +15,13 @@ Kernel signature:
     .place    the target Place
     .accel    the Pallas dispatch seam (see accel() below)
 """
+import contextlib
 import os
+import threading
 
 __all__ = ["kernel", "get_kernel", "has_kernel", "closest_kernels",
            "KernelCtx", "KERNELS", "autocast", "accel", "kern_enabled",
-           "ENV_KERN"]
+           "ENV_KERN", "lowering_for", "mosaic_target"]
 
 KERNELS = {}
 
@@ -35,6 +37,43 @@ ENV_KERN = "PADDLE_TPU_KERN"
 def kern_enabled():
     return os.environ.get(ENV_KERN, "").lower() not in ("off", "0",
                                                         "false")
+
+
+_lowering = threading.local()
+
+
+@contextlib.contextmanager
+def lowering_for(platform, partitioned=False):
+    """Scope a trace to the platform it is compiled for. The tracers
+    (core/trace.py, ParallelExecutor, IncrementalDecoder) enter it with
+    the platform of their Place / mesh / device, so Pallas dispatch
+    follows the program's target and not the process default:
+    Executor(CPUPlace()) on a TPU host lowers no Mosaic kernel.
+    `partitioned=True` marks a jit that GSPMD partitions over several
+    devices — Mosaic custom calls cannot be auto-partitioned (JAX
+    refuses to lower them outside a fully-manual shard_map), so kernels
+    stay off there. platform=None leaves the scope unchanged."""
+    if platform is None:
+        yield
+        return
+    prev = getattr(_lowering, "target", None)
+    _lowering.target = (platform, partitioned)
+    try:
+        yield
+    finally:
+        _lowering.target = prev
+
+
+def mosaic_target():
+    """True when the trace in progress may lower a Mosaic (compiled
+    Pallas TPU) kernel: its target — the enclosing lowering_for scope,
+    else the process's default backend — is a TPU, unpartitioned."""
+    target = getattr(_lowering, "target", None)
+    if target is None:
+        import jax
+        return jax.default_backend() == "tpu"
+    platform, partitioned = target
+    return platform == "tpu" and not partitioned
 
 
 def accel(op_type):

@@ -276,10 +276,7 @@ def _tie(x, token):
     collective cannot be hoisted to overlap (the overlap=0 baseline)."""
     if token is None:
         return x
-    barrier = getattr(lax, "optimization_barrier", None)
-    if barrier is None:        # very old jax: no barrier, stay overlapped
-        return x
-    x, _ = barrier((x, token))
+    x, _ = lax.optimization_barrier((x, token))
     return x
 
 

@@ -32,6 +32,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..core.framework import default_main_program
 from ..core.scope import global_scope
 from ..core.trace import build_step_fn
+from ..ops.registry import lowering_for
 from ..core.dtypes import as_jnp_dtype
 from .. import telemetry as _tm
 from ..resilience import chaos as _chaos
@@ -60,6 +61,16 @@ class ParallelExecutor:
         else:
             self.mesh = mesh if mesh is not None else local_mesh("dp")
             self._shardings = {}
+        # like Executor(): with no mesh given it takes the process's
+        # devices, and says which. use_cuda (the reference's default
+        # True) is accepted and not interpreted; an explicit
+        # use_tpu=True is a demand and fails without TPU devices.
+        self.platform = self.mesh.devices.flat[0].platform
+        if use_tpu and self.platform != "tpu":
+            raise RuntimeError(
+                f"ParallelExecutor(use_tpu=True): the mesh holds "
+                f"{self.mesh.devices.size} {self.platform!r} device(s), "
+                f"no TPU")
         # gradient-sync policy (parallel/gradsync.py): explicit arg >
         # PADDLE_TPU_GRAD_SYNC > minimize(grad_sync=...) program hint.
         # None keeps the implicit-XLA-all-reduce path bit-identical
@@ -119,6 +130,9 @@ class ParallelExecutor:
                     "grad_sync policies need a 'dp' axis on the mesh")
         self._cache = {}
         self._step = 0
+        # the placed (global) feed arrays of the most recent run(): how
+        # the batch was actually sharded, readable by smoke checks
+        self.last_feeds = {}
         # recompile-explainer state (telemetry on only): named fields
         # of every compile key seen, plus the latest explanation
         self._seen_fields = []
@@ -337,7 +351,9 @@ class ParallelExecutor:
         def mapped(persist_in, feed_in, key_in):
             key_in = jax.random.fold_in(key_in,
                                         jax.lax.axis_index("dp"))
-            fetches, new_persist = step(persist_in, feed_in, key_in)
+            # fully-manual shard_map: per-member code, kernels allowed
+            with lowering_for(self.platform):
+                fetches, new_persist = step(persist_in, feed_in, key_in)
             out = []
             for f, kind in zip(fetches, fetch_kind):
                 if kind == "mean":
@@ -491,6 +507,8 @@ class ParallelExecutor:
             feed_sh[k] = sh
             feed_arrays[k] = self._feed_to_global(arr, sh)
 
+        self.last_feeds = feed_arrays
+
         engine = self.sparse_engine
         engine_rows = set(engine.row_var_names) if engine else ()
         persist = {}
@@ -586,8 +604,13 @@ class ParallelExecutor:
 
                 def wrapped(persist_in, feed_in, key_in, _step=step_fn,
                             _sh=dict(persist_sh)):
-                    fetches, new_persist = _step(persist_in, feed_in,
-                                                 key_in)
+                    # this jit is partitioned by GSPMD, which cannot
+                    # split a Mosaic custom call: more than one device
+                    # keeps the partitionable jnp compositions
+                    with lowering_for(self.platform,
+                                      partitioned=self.device_count > 1):
+                        fetches, new_persist = _step(
+                            persist_in, feed_in, key_in)
                     # pin state outputs to their input layout so the
                     # scope keeps genuinely sharded arrays between
                     # steps (tp/ZeRO)

@@ -190,7 +190,13 @@ def multiprocess_reader(readers, use_pipe=True, queue_size=1000):
     reference's multi-process reader; order across readers is arrival
     order). Both modes carry pickled samples: `use_pipe=True` uses one
     multiprocessing.Pipe per reader (no /dev/shm requirement),
-    otherwise a shared bounded Queue."""
+    otherwise a shared bounded Queue.
+
+    The children are fork()ed (the readers are closures), so under a
+    live TPU runtime they inherit the parent's libtpu state without
+    owning the chip: a reader that touches JAX in the child fails or
+    hangs. Readers must stay numpy/python-only; not exercised on a
+    chip yet (chip_smoke.py feeds in-process)."""
     import multiprocessing
 
     if not isinstance(readers, list) or not readers:

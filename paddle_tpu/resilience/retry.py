@@ -2,8 +2,8 @@
 
 The reference stack assumed flaky transports everywhere (pserver RPC
 retries, grpc deadlines, brpc backup requests); the jax_graft rebuild
-talks to relays, coordinators, and shared filesystems that flake the
-same way. This is the ONE policy object the rest of the repo wraps
+talks to coordinators, remote services and shared filesystems that
+flake the same way. This is the ONE policy object the rest of the repo wraps
 those seams with — fleet init/barrier, telemetry spool I/O, inference
 compile — instead of ad-hoc sleep loops.
 

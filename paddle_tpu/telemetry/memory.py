@@ -1,9 +1,9 @@
 """Device-memory watermark gauges.
 
 jax exposes per-device allocator stats through `Device.memory_stats()`,
-but support varies by backend, version, AND device: TPU returns a
-populated dict, this image's CPU devices (jax 0.4.37) return None,
-some plugin backends raise, and a mixed-platform process (cpu host
+but support varies by backend AND device: TPU returns a populated
+dict, CPU devices return None, some plugin backends raise, and a
+mixed-platform process (cpu host
 devices alongside an accelerator) supports it on some local devices
 only. The capability probe is therefore PER DEVICE — each device is
 probed once (cached per process) and degrades individually, so one

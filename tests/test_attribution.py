@@ -258,7 +258,9 @@ def test_peak_flops_table(monkeypatch):
 
     assert attr.peak_flops(_Dev("TPU v5p")) == 459e12
     assert attr.peak_flops(_Dev("TPU v4")) == 275e12
-    assert attr.peak_flops(_Dev("TPU7x")) == 197e12  # platform default
+    assert attr.peak_flops(_Dev("TPU v5 lite")) == 197e12
+    # a TPU the table does not know has NO peak (never a v5e default)
+    assert attr.peak_flops(_Dev("TPU7x")) is None
     assert attr.peak_flops(_Dev("cpu", platform="cpu")) is None
     monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "5e13")
     assert attr.peak_flops(_Dev("cpu", platform="cpu")) == 5e13

@@ -1,20 +1,16 @@
-"""bench.py's survival contract (VERDICT r3 #1): whatever happens to
-the backend or the driver's timer, stdout's last line is valid JSON
-with the headline metric schema. Three rounds of BENCH artifacts died
-to violations of this; it is load-bearing enough to pin with tests.
+"""bench.py's contract: ONE process, one JSON line that names the
+device it ran on, and no number without a chip unless a CPU run was
+asked for by name (JAX_PLATFORMS=cpu — plumbing evidence, labelled
+`"platform": "cpu"`). The rest of this file pins that default-off
+features leave the default paths untouched.
 
-Runs the real bench.py as a subprocess on the CPU backend with the TPU
-probe short-circuited (BENCH_TOTAL_BUDGET_S small, BENCH_ONLY=mnist)
-— ~40s total.
+Runs the real bench.py as a subprocess with BENCH_ONLY=mnist.
 """
 import json
 import os
-import signal
 import subprocess
 import sys
 import time
-
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "bench.py")
@@ -23,7 +19,6 @@ BENCH = os.path.join(REPO, "bench.py")
 def _env():
     env = dict(os.environ)
     env.update(JAX_PLATFORMS="cpu", BENCH_ONLY="mnist",
-               BENCH_TOTAL_BUDGET_S="120",
                # keep test runs out of the committed perf spine
                BENCH_HISTORY_PATH=os.devnull)
     env.pop("XLA_FLAGS", None)
@@ -38,6 +33,8 @@ def _parse_last(stdout):
 
 
 def test_final_line_schema_on_cpu():
+    """The explicit CPU run: rc 0, one result line, and the line says
+    it is a CPU line."""
     # telemetry is off in _env(): the run must not grow a telemetry
     # artifact (and, via the assertions below, stdout stays pinned)
     tele_artifact = os.path.join(REPO, "BENCH_telemetry.json")
@@ -48,28 +45,30 @@ def test_final_line_schema_on_cpu():
     assert p.returncode == 0, p.stderr[-800:]
     assert not os.path.exists(tele_artifact), \
         "telemetry-off bench wrote BENCH_telemetry.json"
-    last_line = [l for l in p.stdout.strip().splitlines()
-                 if l.strip()][-1]
-    # round-5 VERDICT: an embedded probe trail overflowed the driver's
-    # tail capture — the final line must stay compact, with the full
-    # trail in the BENCH_probe.json artifact instead
-    assert len(last_line) < 2048, \
-        f"final line is {len(last_line)}B (budget 2048)"
     obj = _parse_last(p.stdout)
-    for key in ("metric", "value", "unit", "vs_baseline", "platform"):
+    for key in ("metric", "value", "unit", "platform", "device_kind",
+                "device_count"):
         assert key in obj, (key, obj)
     assert obj["metric"] == "transformer_base_train_tokens_per_sec"
     assert obj["platform"] == "cpu"
     assert obj["mnist_mlp_steps_per_sec"] > 0
-    # the probe record must say WHY this is a CPU line
-    assert obj["probe"]["cpu_fallback_ran"] is True
-    assert isinstance(obj["probe"]["attempts"], int)  # counts, not trails
-    trail = os.path.join(REPO, "BENCH_probe.json")
-    assert os.path.exists(trail)
-    with open(trail) as f:
-        full = json.load(f)
-    assert isinstance(full["probe"]["attempts"], list)
-    assert isinstance(full["probe"]["children"], list)
+    assert "mfu" not in obj          # no peak off the chip, no MFU
+    json_lines = [l for l in p.stdout.splitlines()
+                  if l.strip().startswith("{")]
+    assert len(json_lines) == 1, json_lines
+
+
+def test_no_chip_no_number():
+    """No TPU and no explicit JAX_PLATFORMS=cpu: the run fails, names
+    the platform it found, and puts no result on stdout."""
+    env = _env()
+    del env["JAX_PLATFORMS"]
+    p = subprocess.run([sys.executable, BENCH], env=env,
+                       capture_output=True, text=True, timeout=400)
+    assert p.returncode != 0
+    assert "no TPU" in p.stderr and "'cpu'" in p.stderr
+    assert not [l for l in p.stdout.splitlines()
+                if l.strip().startswith("{")], p.stdout[-400:]
 
 
 def test_telemetry_off_cached_fast_path():
@@ -796,21 +795,3 @@ def test_telemetry_artifact_helper(tmp_path):
     finally:
         tm.disable()
         tm.reset()
-
-
-def test_sigterm_flushes_parseable_line():
-    """Kill bench mid-run (the driver-timeout scenario): the last
-    stdout line must still parse — the t=0 bootstrap guarantees it."""
-    proc = subprocess.Popen([sys.executable, BENCH], env=_env(),
-                            stdout=subprocess.PIPE,
-                            stderr=subprocess.DEVNULL, text=True)
-    time.sleep(6)  # inside backend bring-up, before any result
-    proc.send_signal(signal.SIGTERM)
-    try:
-        out, _ = proc.communicate(timeout=60)
-    except subprocess.TimeoutExpired:
-        proc.kill()
-        pytest.fail("bench did not exit after SIGTERM")
-    obj = _parse_last(out)
-    assert obj["metric"] == "transformer_base_train_tokens_per_sec"
-    assert "value" in obj and "platform" in obj

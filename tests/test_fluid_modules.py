@@ -481,13 +481,35 @@ def test_utils_ploter(tmp_path, monkeypatch):
 
 
 def test_is_compiled_with_cuda_compat():
-    """ref core.is_compiled_with_cuda: the device-branch predicate;
-    False under the forced-CPU test config (no backend init involved),
-    so reference programs branch to CPUPlace here and to
-    CUDAPlace→TPUPlace when the accelerator platform is active."""
+    """ref core.is_compiled_with_cuda: the device-branch predicate. It
+    asks the backend: False on the CPU test config, so reference
+    programs branch to CPUPlace here and to CUDAPlace→TPUPlace where
+    JAX holds a TPU."""
     from paddle_tpu import core
     assert core.is_compiled_with_cuda() is False  # conftest forces cpu
     assert core.is_compiled_with_tpu() is False
+
+
+def test_explicit_accelerator_place_needs_the_chip():
+    """An explicit TPUPlace/CUDAPlace never resolves to another
+    device: no TPU backend, or an id past the last device, raises.
+    Executor() with no place keeps choosing (CPU here)."""
+    import pytest
+    import paddle_tpu as fluid
+    for place in (fluid.TPUPlace(0), fluid.CUDAPlace(0)):
+        with pytest.raises(RuntimeError, match="no 'tpu' backend"):
+            place.jax_device()
+    assert fluid.CPUPlace().jax_device().platform == "cpu"
+    with pytest.raises(RuntimeError, match="TPUPlace"):
+        fluid.Executor(fluid.TPUPlace(0)).run(
+            fluid.default_startup_program())
+    assert fluid.Executor().place == fluid.CPUPlace()
+
+    class _NinthCpu(fluid.CPUPlace):
+        def __init__(self):
+            fluid.core.place.Place.__init__(self, 8)
+    with pytest.raises(RuntimeError, match="8 local 'cpu' device"):
+        _NinthCpu().jax_device()      # conftest gives 8: ids 0..7
 
 
 def test_async_executor_native_parser_matches_python(tmp_path):

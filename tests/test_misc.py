@@ -434,17 +434,21 @@ def test_run_scanned_feed_validation():
 
 
 def test_compile_cache_env_gate(tmp_path):
-    """PADDLE_TPU_COMPILE_CACHE=<dir> persists XLA executables across
-    processes (MIGRATING 'Execution model'); unset → no writes."""
+    """The persistent compile cache is placed from outside: with
+    JAX_COMPILATION_CACHE_DIR=<dir> the package sets no directory of
+    its own and executables land there and nowhere else; unset, the
+    cache is <checkout>/.jax_compile_cache (MIGRATING 'Execution
+    model')."""
     import subprocess
     import sys
     import os
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     code = (
         "import jax; jax.config.update('jax_platforms','cpu')\n"
         "import numpy as np, paddle_tpu as pt\n"
-        # drop the gate's 0.5s threshold AFTER import: CPU-sized test
-        # compiles are fast, and the threshold is what's under test
-        # only in so far as the cache dir config took effect
+        "print('CACHE_DIR=' + str(jax.config.jax_compilation_cache_dir))\n"
+        # CPU-sized test compiles are fast: drop JAX's own 1s floor so
+        # the run below writes an entry
         "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)\n"
         "from paddle_tpu import layers\n"
         "x = layers.data('x', shape=[64])\n"
@@ -453,11 +457,25 @@ def test_compile_cache_env_gate(tmp_path):
         "exe.run(pt.default_startup_program())\n"
         "exe.run(feed={'x': np.zeros((4,64),'float32')}, fetch_list=[y])\n"
     )
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PADDLE_TPU_COMPILE_CACHE=str(tmp_path / "cc"))
-    r = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=240)
-    assert r.returncode == 0, r.stderr[-800:]
     cc = tmp_path / "cc"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+               JAX_COMPILATION_CACHE_DIR=str(cc))
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=240,
+                       cwd=str(tmp_path))
+    assert r.returncode == 0, r.stderr[-800:]
+    assert f"CACHE_DIR={cc}" in r.stdout
     assert cc.is_dir() and any(cc.iterdir()), \
-        "compile cache dir empty — env gate did not take effect"
+        "compile cache dir empty — JAX_COMPILATION_CACHE_DIR ignored"
+
+    # default path: fixed, inside the checkout, independent of the cwd
+    env.pop("JAX_COMPILATION_CACHE_DIR")
+    r = subprocess.run(
+        [sys.executable, "-c",
+         "import jax, paddle_tpu\n"
+         "print('CACHE_DIR=' + str(jax.config.jax_compilation_cache_dir))"],
+        env=env, capture_output=True, text=True, timeout=240,
+        cwd=str(tmp_path))
+    assert r.returncode == 0, r.stderr[-800:]
+    assert f"CACHE_DIR={os.path.join(repo, '.jax_compile_cache')}" \
+        in r.stdout

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-import paddle_tpu as pt  # noqa: F401  (jax 0.4.37 shims)
+import paddle_tpu as pt  # noqa: F401  (registers the op kernels)
 from paddle_tpu.ops.pallas import embedding as pe
 from paddle_tpu.ops.registry import get_kernel, KernelCtx
 

@@ -26,6 +26,35 @@ def test_devices_available():
     assert len(jax.devices()) == 8
 
 
+def test_parallel_executor_reports_mesh_and_refuses_missing_tpu():
+    """ParallelExecutor takes the process's devices like Executor()
+    does and says which (platform, device_count); an explicit
+    use_tpu=True over a CPU mesh raises instead of running there."""
+    loss = _build_mlp()
+    pexe = pt.ParallelExecutor(loss_name=loss.name)   # use_cuda=True
+    assert (pexe.platform, pexe.device_count) == ("cpu", 8)
+    with pytest.raises(RuntimeError, match="8 'cpu' device"):
+        pt.ParallelExecutor(loss_name=loss.name, use_tpu=True)
+
+
+def test_pallas_dispatch_follows_the_lowering_target():
+    """Mosaic kernels lower only where the program is compiled for a
+    TPU, unpartitioned: the tracers scope that from their Place / mesh
+    (ops.registry.lowering_for), not from the process default."""
+    from paddle_tpu.ops.registry import lowering_for, mosaic_target
+    assert mosaic_target() is False          # default backend: cpu
+    with lowering_for("tpu"):
+        assert mosaic_target() is True
+        with lowering_for("cpu"):            # Executor(CPUPlace())
+            assert mosaic_target() is False
+        with lowering_for(None):             # place=None: unchanged
+            assert mosaic_target() is True
+        with lowering_for("tpu", partitioned=True):   # GSPMD jit
+            assert mosaic_target() is False
+        assert mosaic_target() is True
+    assert mosaic_target() is False
+
+
 def test_ring_attention_matches_full():
     mesh = make_mesh(sp=8)
     B, H, T, D = 2, 4, 64, 16

@@ -268,13 +268,11 @@ def test_executor_close_clears_caches_and_flushes(tmp_path, monkeypatch):
     out = _tiny_program()
     exe = pt.Executor(pt.CPUPlace())
     exe.run(pt.default_startup_program())
-    exe._scan_gate_cache["sentinel"] = True
     tm.enable()
     tm.counter("t.pre_close").inc()
     monkeypatch.setenv("PADDLE_TPU_TELEMETRY_DIR", str(tmp_path))
     exe.close()
     assert exe._cache == {}
-    assert exe._scan_gate_cache == {}       # the PR-1 leak, fixed
     assert exe._seen_keys == set()
     assert exe._step_counters == {}
     # close() flushed the artifacts

@@ -1878,9 +1878,11 @@ def _guard_append_history(cases):
                 timeout=10).stdout.strip() or None
         except Exception:  # noqa: BLE001 — sha is optional
             sha = None
+        import jax
+        dev = jax.devices()[0]      # the backend the bench just ran on
         common = {"schema": slo.HISTORY_SCHEMA,
-                  "platform": os.environ.get("JAX_PLATFORMS", "cpu"),
-                  "device_kind": "cpu", "git_sha": sha,
+                  "platform": dev.platform,
+                  "device_kind": dev.device_kind, "git_sha": sha,
                   "unix_time": round(time.time(), 1),
                   "stage": "guard"}
         recs = []
@@ -2211,9 +2213,11 @@ def _scale_append_history(ramp):
                 timeout=10).stdout.strip() or None
         except Exception:  # noqa: BLE001 — sha is optional
             sha = None
+        import jax
+        dev = jax.devices()[0]      # the backend the bench just ran on
         common = {"schema": slo.HISTORY_SCHEMA,
-                  "platform": os.environ.get("JAX_PLATFORMS", "cpu"),
-                  "device_kind": "cpu", "git_sha": sha,
+                  "platform": dev.platform,
+                  "device_kind": dev.device_kind, "git_sha": sha,
                   "unix_time": round(time.time(), 1),
                   "stage": "scale"}
         recs = []
@@ -2383,9 +2387,9 @@ def main(argv=None):
     p.add_argument("--max-queue", type=int, default=256)
     p.add_argument("--deadline-ms", type=float, default=None,
                    help="default per-request deadline")
-    p.add_argument("--platform", default="cpu",
-                   help="JAX_PLATFORMS to force ('env' keeps the "
-                        "environment's value)")
+    p.add_argument("--platform", default="env",
+                   help="JAX_PLATFORMS to force before backend init "
+                        "(default 'env': keep the environment's)")
     p.add_argument("--bench", action="store_true",
                    help="closed-loop load generator; implies no "
                         "serve-forever")

@@ -41,7 +41,7 @@ a live top(1) over the telemetry spool.
 Examples:
   python tools/tpustat.py --model mnist --steps 20 --json
   python tools/tpustat.py --model resnet --steps 10 --prom
-  python tools/tpustat.py --model mnist --platform env   # real backend
+  python tools/tpustat.py --model mnist --platform cpu   # force CPU
   python tools/tpustat.py --fleet /run/spool --trace fleet.json
   python tools/tpustat.py --fleet --selftest --json      # CI gate
   python tools/tpustat.py --model mnist --slo --rules ci.rules
@@ -885,10 +885,9 @@ def main(argv=None):
                    help="run the step loop through the tpupipe async "
                         "window (Executor.run(async_steps=K)); 0 = "
                         "synchronous")
-    p.add_argument("--platform", default="cpu",
+    p.add_argument("--platform", default="env",
                    help="JAX_PLATFORMS to force before backend init "
-                        "('env' keeps the environment's value; default "
-                        "cpu so the CLI never hangs on a down relay)")
+                        "(default 'env': keep the environment's)")
     p.add_argument("--trace", metavar="PATH", default=None,
                    help="Chrome trace output "
                         "(default /tmp/tpustat_<model>.trace.json)")
