@@ -1,0 +1,13 @@
+"""Layer: kernels. Device time per step of the Mosaic kernel of that name
+(`pl.pallas_call(name=...)`): `.layer_norm_fwd`, `.layer_norm_bwd`. Found
+by name, so a second kernel in the step does not disturb it."""
+from chipbench import program_trace
+
+
+def read(facts, name):
+    tr = program_trace.load(__file__)
+    if not facts.get("on_chip") or not tr or not any(tr["chips"]):
+        return None
+    secs = program_trace.kernel_seconds(tr["chips"]).get(
+        name.split(".", 1)[1])
+    return 1e3 * secs / facts["steps"] if secs else None

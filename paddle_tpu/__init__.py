@@ -36,6 +36,7 @@ if not _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             _os.path.abspath(__file__))), ".jax_compile_cache"))
 
 from . import telemetry         # runtime metrics/spans (dep-free; first)
+telemetry.compiles.install()    # the compile log's listener: jax is here
 from . import ops               # registers all kernels
 from . import unique_name
 from .core.framework import (

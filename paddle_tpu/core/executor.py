@@ -212,7 +212,7 @@ class Executor:
             a = a.base if isinstance(a.base, np.ndarray) else None
         return True
 
-    def _put_feeds(self, program, feed, dev):
+    def _put_feeds(self, program, feed, dev, span=None):
         """Feed values → device arrays with ONE transfer each: dtype
         casts happen host-side, and values that are already jax Arrays
         of the right dtype pass through untouched. Numpy feeds are
@@ -222,11 +222,14 @@ class Executor:
         reuse requires the buffer be genuinely immutable (read-only
         down its base chain, so an in-place mutation is impossible
         rather than merely unexpected); feed_cache="trust" reuses any
-        identical buffer for loops that promise not to mutate."""
+        identical buffer for loops that promise not to mutate. `span`
+        (executor.feed_put) gets the counts: calls of device_put, their
+        bytes, and feeds served from the reuse cache."""
         feed_arrays = {}
         cache = self._feed_cache if self.feed_cache else None
         trust = self.feed_cache == "trust"
         tm_on = _tm.enabled()
+        puts = reused = nbytes = 0
         for k, v in feed.items():
             npdt = self._feed_dtype(program, k)
             if isinstance(v, jax.Array) and (npdt is None
@@ -240,6 +243,7 @@ class Executor:
                         and ent[1] is dev and ent[2] == npdt \
                         and (trust or self._host_immutable(v)):
                     feed_arrays[k] = ent[3]
+                    reused += 1
                     if tm_on:
                         _tm.counter("executor.feed_put.reused").inc()
                     continue
@@ -247,8 +251,12 @@ class Executor:
             if npdt is not None and arr.dtype != npdt:
                 arr = arr.astype(npdt)
             feed_arrays[k] = jax.device_put(arr, dev)
+            puts += 1
+            nbytes += arr.nbytes
             if cache is not None and isinstance(v, np.ndarray):
                 cache[k] = (weakref.ref(v), dev, npdt, feed_arrays[k])
+        if span is not None:
+            span.set(puts=puts, reused=reused, bytes=nbytes)
         return feed_arrays
 
     def _collect_persist(self, program, scope):
@@ -394,8 +402,19 @@ class Executor:
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
             return_numpy=True, use_program_cache=True, is_test=None,
             validate=None, check_nan_inf=None, async_steps=None):
-        k_async = resolve_async_steps(async_steps, self.async_steps)
         program = program if program is not None else default_main_program()
+        # the parent of every span below; `compile_run` joins its counts
+        # once the compile key is known
+        with _tm.span("executor.run", step=self._step,
+                      program=program._version) as run_span:
+            return self._run(run_span, program, feed, fetch_list, scope,
+                             return_numpy, use_program_cache, is_test,
+                             validate, check_nan_inf, async_steps)
+
+    def _run(self, run_span, program, feed, fetch_list, scope,
+             return_numpy, use_program_cache, is_test, validate,
+             check_nan_inf, async_steps):
+        k_async = resolve_async_steps(async_steps, self.async_steps)
         scope = scope if scope is not None else global_scope()
         feed = dict(feed or {})
         dev = self.place.jax_device()
@@ -462,9 +481,10 @@ class Executor:
         ml_on = _tm.memledger_enabled()
         t_fp = time.perf_counter() if tm_on else 0.0
         with _tm.span("executor.feed_put", feeds=len(feed),
-                      step=self._step - 1):
+                      step=self._step - 1) as put_span:
             try:
-                feed_arrays = self._put_feeds(program, feed, dev)
+                feed_arrays = self._put_feeds(program, feed, dev,
+                                              put_span)
             except Exception as e:
                 if ml_on:
                     from ..telemetry import memledger as _ml
@@ -481,44 +501,54 @@ class Executor:
             _tm.histogram("executor.feed_put_seconds").observe(
                 time.perf_counter() - t_fp)
 
-        persist = self._collect_persist(program, scope)
-        self._unalias_feeds(feed_arrays, persist)
-        if feed_arrays:
-            persist = self._commit(persist, dev)
+        with _tm.span("executor.prepare") as prep_span:
+            persist = self._collect_persist(program, scope)
+            self._unalias_feeds(feed_arrays, persist)
+            if feed_arrays:
+                persist = self._commit(persist, dev)
+            prep_span.set(persist=len(persist))
 
-        from . import trace as _trace
-        ckey = (id(program), program._version, _feed_signature(feed_arrays),
-                tuple(fetch_names), bool(is_test), seed,
-                _trace.FUSE_OPTIMIZER_TAIL, _trace.FUSE_MAX_ELEMS)
-        if not self.donate_state:
-            # only the non-default mode grows the key — the donating
-            # path keeps the historical 8-tuple (bench-contract pin)
-            ckey = ckey + ("nodonate",)
-        fn = self._cache.get(ckey) if use_program_cache else None
-        # first-run (compile) detection must survive use_program_cache=False
-        first_run = ckey not in self._seen_keys
-        if first_run and tm_on and self._seen_keys:
-            # a NEW compile key while others are cached: diff it
-            # against the nearest seen neighbor and say which
-            # component busted the cache (tpuscope recompile explainer)
-            from ..telemetry import attribution as _attr
-            self.last_recompile = _attr.explain_recompile(
-                "executor", _attr.executor_ckey_fields(ckey),
-                [_attr.executor_ckey_fields(k)
-                 for k in self._seen_keys],
-                step=self._step - 1)
-        self._seen_keys.add(ckey)
+            from . import trace as _trace
+            ckey = (id(program), program._version,
+                    _feed_signature(feed_arrays), tuple(fetch_names),
+                    bool(is_test), seed, _trace.FUSE_OPTIMIZER_TAIL,
+                    _trace.FUSE_MAX_ELEMS)
+            if not self.donate_state:
+                # only the non-default mode grows the key — the donating
+                # path keeps the historical 8-tuple (bench-contract pin)
+                ckey = ckey + ("nodonate",)
+            fn = self._cache.get(ckey) if use_program_cache else None
+            # first-run (compile) detection must survive
+            # use_program_cache=False
+            first_run = ckey not in self._seen_keys
+            run_span.set(compile_run=first_run)
+            if first_run and tm_on and self._seen_keys:
+                # a NEW compile key while others are cached: diff it
+                # against the nearest seen neighbor and say which
+                # component busted the cache (tpuscope recompile
+                # explainer)
+                from ..telemetry import attribution as _attr
+                self.last_recompile = _attr.explain_recompile(
+                    "executor", _attr.executor_ckey_fields(ckey),
+                    [_attr.executor_ckey_fields(k)
+                     for k in self._seen_keys],
+                    step=self._step - 1)
+            self._seen_keys.add(ckey)
 
-        step_dev = self._step_counters.get(dev)
-        if step_dev is None:
-            # uncommitted on purpose: a device_put-committed counter
-            # would commit every jit OUTPUT (params included) to one
-            # device, poisoning later mesh-sharded use of the scope
-            # (e.g. startup → PipelineTrainer over a pp mesh)
-            step_dev = jnp.asarray(self._step - 1, jnp.int32)
-            self._step_counter_vals[dev] = self._step - 1
-        if feed_arrays and not step_dev.committed:
-            step_dev = jax.device_put(step_dev, dev)   # see _commit
+            step_dev = self._step_counters.get(dev)
+            if step_dev is None:
+                # uncommitted on purpose: a device_put-committed counter
+                # would commit every jit OUTPUT (params included) to one
+                # device, poisoning later mesh-sharded use of the scope
+                # (e.g. startup → PipelineTrainer over a pp mesh)
+                step_dev = jnp.asarray(self._step - 1, jnp.int32)
+                self._step_counter_vals[dev] = self._step - 1
+            if feed_arrays and not step_dev.committed:
+                step_dev = jax.device_put(step_dev, dev)   # see _commit
+        # what compiles on a key's first run is this executor's: the
+        # compile log (telemetry.compile_log) puts it down to the program
+        own = _tm.compile_owner(f"executor:{program._version}") \
+            if first_run else _tm.compiles.NO_OWNER
         if fn is None:
             if flight is not None:
                 flight.event("compile", program=program._version,
@@ -527,8 +557,9 @@ class Executor:
                 _tm.counter("executor.compile_count").inc()
                 _tm.gauge("executor.signature_count").set(
                     len(self._seen_keys))
-            with _tm.span("executor.compile", program=program._version,
-                          fetches=len(fetch_names)):
+            with own, _tm.span("executor.compile",
+                               program=program._version,
+                               fetches=len(fetch_names)):
                 # opt-in pre-trace verification gate: pay it once per
                 # compile (cache hits skip it), catching IR defects
                 # before JAX does
@@ -550,6 +581,11 @@ class Executor:
                 fn = jax.jit(stepped,
                              donate_argnums=(0, 2) if self.donate_state
                              else ())
+                # the way from a device trace's op to its named_scope
+                # (telemetry.compiled_text): shapes only, nothing runs
+                _tm.compiles.register_program(
+                    f"executor:{program._version}", fn,
+                    (persist, feed_arrays, step_dev))
                 if tm_on:
                     # AOT-compile here (still inside the compile span)
                     # to capture this ckey's FLOPs from cost_analysis
@@ -581,8 +617,8 @@ class Executor:
             self.diag_snapshot_count += 1
         t0 = time.perf_counter()
         try:
-            with _tm.span("executor.step", step=self._step - 1,
-                          compile_run=first_run):
+            with own, _tm.span("executor.step", step=self._step - 1,
+                               compile_run=first_run):
                 fetches, new_persist, step_dev = fn(persist, feed_arrays,
                                                     step_dev)
         except Exception as e:
@@ -672,8 +708,9 @@ class Executor:
             fetches = [jnp.array(f, copy=True) if n in new_persist
                        else f
                        for n, f in zip(fetch_names, fetches)]
-        for name, val in new_persist.items():
-            scope.set(name, val)
+        with _tm.span("executor.scope_write", persist=len(new_persist)):
+            for name, val in new_persist.items():
+                scope.set(name, val)
 
         rec = {
             "step": self._step - 1, "step_val": step_val,
@@ -697,7 +734,13 @@ class Executor:
             # that older step)
             return pipe.push(PendingStep(pipe, rec,
                                          self._finalize_record))
-        return self._finalize_record(rec)
+        out = self._finalize_record(rec)
+        # the handles of the state that went into the step (donated, or
+        # replaced in the scope) die with this frame; dropping them here
+        # gives that stretch, which follows the read-back, a name
+        with _tm.span("executor.release", handles=len(persist)):
+            del persist, rec
+        return out
 
     def _finalize_record(self, rec):
         """Post-step work — finite checks, NaN diagnosis, numpy

@@ -123,8 +123,9 @@ def _exec_adam_run(env, run, key, is_test, place, block):
         for s in gkey[0]:
             n_elems *= s
         if len(members) >= 2 and n_elems <= FUSE_MAX_ELEMS:
-            _exec_adam_group(env, [op for op, _ in members], is_test,
-                             place)
+            with jax.named_scope("adam"):
+                _exec_adam_group(env, [op for op, _ in members], is_test,
+                                 place)
         else:
             for op, idx in members:
                 exec_op(env, op, idx, key, is_test, place, block)
@@ -249,7 +250,13 @@ def exec_op(env, op, op_idx, base_key, is_test, place, block, program=None):
     ctx = KernelCtx(key=key, is_test=is_test, place=place, accel=accel)
     attrs = dict(op.attrs)
     attrs.setdefault("_op_type", op.type)
-    outs = kern(ctx, ins, attrs)
+    # the op type names the scope of everything the kernel emits, so a
+    # device trace can say which Fluid op a fusion belongs to; inside
+    # value_and_grad JAX's name stack adds the phase by itself:
+    # jvp(<op>) forward, transpose(jvp(<op>)) backward, the bare op
+    # type in the optimizer tail. Metadata only: the module is the same.
+    with jax.named_scope(op.type):
+        outs = kern(ctx, ins, attrs)
     for slot, names in op.outputs.items():
         vals = outs.get(slot)
         if vals is None:

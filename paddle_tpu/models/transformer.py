@@ -9,6 +9,7 @@ hot path, bf16-ready (normalizations compute in fp32).
 import numpy as np
 
 from .. import layers
+from .. import telemetry as _tm
 
 __all__ = ["transformer", "build_program", "build_infer_program",
            "greedy_decode", "convert_qkv_checkpoint",
@@ -786,11 +787,16 @@ class IncrementalDecoder:
             raise ValueError(f"src padded to {Ts}, decoder built for "
                              f"src_max_len={self.src_max_len}")
         fn = self._prefill_jit.get(rows)
+        own = _tm.compiles.NO_OWNER
         if fn is None:
             fn = self._get_or_build("prefill", rows)
             self._prefill_jit[rows] = fn
-        return fn(self.params, jnp.asarray(src.astype(np.int32)),
-                  jnp.asarray(np.asarray(src_len).astype(np.int32)))
+            # a bucket's first call compiles: the compile log puts it
+            # down to the bucket
+            own = _tm.compile_owner(f"decode.prefill:{rows}")
+        with own:
+            return fn(self.params, jnp.asarray(src.astype(np.int32)),
+                      jnp.asarray(np.asarray(src_len).astype(np.int32)))
 
     def write_slots(self, state, prefill_out, slots):
         """Scatter `len(slots)` prefilled rows into the slot state
@@ -798,10 +804,15 @@ class IncrementalDecoder:
         import jax.numpy as jnp
         ck, cv, src_bias = prefill_out
         n = len(slots)
-        idx = jnp.asarray(np.asarray(slots, np.int32))
-        state["ck"] = state["ck"].at[:, idx].set(ck[:, :n])
-        state["cv"] = state["cv"].at[:, idx].set(cv[:, :n])
-        state["src_bias"] = state["src_bias"].at[idx].set(src_bias[:n])
+        # eager slices and scatters: each new row count compiles its
+        # own small programs, which the compile log counts under this
+        # owner (telemetry.compile_log)
+        with _tm.compile_owner("decode.write_slots"):
+            idx = jnp.asarray(np.asarray(slots, np.int32))
+            state["ck"] = state["ck"].at[:, idx].set(ck[:, :n])
+            state["cv"] = state["cv"].at[:, idx].set(cv[:, :n])
+            state["src_bias"] = state["src_bias"].at[idx].set(
+                src_bias[:n])
         return state
 
     def step(self, state, ids, pos, seed=0):
@@ -812,23 +823,23 @@ class IncrementalDecoder:
         ignores — the price of a static shape, and exactly one
         compiled executable."""
         import jax.numpy as jnp
+        own = _tm.compiles.NO_OWNER
         if self._step_jit is None:
             self._step_jit = self._get_or_build("step")
+            own = _tm.compile_owner("decode.step")   # compiles below
         feed = (jnp.asarray(np.asarray(ids, np.int32)),
                 jnp.asarray(np.asarray(pos, np.int32)),
                 jnp.asarray(np.uint32(seed)))
-        if self.kv_quant == "int8":
+        # the self-attention caches go in donated and come back first
+        caches = ("kc_q", "kc_s", "vc_q", "vc_s") \
+            if self.kv_quant == "int8" else ("kc", "vc")
+        with own:
             out = self._step_jit(
-                self.params, state["kc_q"], state["kc_s"],
-                state["vc_q"], state["vc_s"], state["ck"],
+                self.params, *(state[k] for k in caches), state["ck"],
                 state["cv"], state["src_bias"], *feed)
-            (state["kc_q"], state["kc_s"], state["vc_q"],
-             state["vc_s"], nxt) = out[:5]
-        else:
-            out = self._step_jit(
-                self.params, state["kc"], state["vc"], state["ck"],
-                state["cv"], state["src_bias"], *feed)
-            state["kc"], state["vc"], nxt = out[:3]
+        for k, v in zip(caches, out):
+            state[k] = v
+        nxt = out[len(caches)]
         if self.return_logits:
             self.last_logits = np.asarray(out[-1])
         return np.asarray(nxt)

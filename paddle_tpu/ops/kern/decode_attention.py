@@ -204,6 +204,7 @@ def _call(kernel, q, caches, pos, bt, interpret):
         out_shape=jax.ShapeDtypeStruct((S, H, Dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
+        name="decode_attention",
         interpret=interpret,
     )(pos.astype(jnp.int32), q, *caches)
 

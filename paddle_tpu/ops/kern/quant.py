@@ -113,6 +113,7 @@ def quantize_int8_pallas(flat, block_size=256, block_rows=None,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
+        name="quant_int8",
         interpret=interpret,
     )(x2)
     return q, s_rep[:, :1]

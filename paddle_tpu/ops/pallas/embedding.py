@@ -130,6 +130,7 @@ def _fwd(table, inv, weights, pool, block_rows, interpret):
         in_specs=in_specs,
         out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), table.dtype),
+        name="embedding_lookup",
         interpret=interpret,
     )(*args)
 

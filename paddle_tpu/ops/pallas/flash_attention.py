@@ -329,6 +329,7 @@ def _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_fwd",
         interpret=interpret,
     )(q, k, v, bias)
     return out, lse
@@ -462,6 +463,7 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_dq",
         interpret=interpret,
     )(q, k, v, bias, g, lse, delta)
 
@@ -499,6 +501,7 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_attention_dkv",
         interpret=interpret,
     )(q, k, v, bias, g, lse, delta)
     if not has_bias:

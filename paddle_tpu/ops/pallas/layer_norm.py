@@ -164,6 +164,7 @@ def _fwd(x, scale, bias, eps, block_rows, interpret):
         ],
         out_specs=pl.BlockSpec(block, imap),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        name="layer_norm_fwd",
         interpret=interpret,
     )(x, scale.reshape(sshape), bias.reshape(sshape))
 
@@ -197,6 +198,7 @@ def _bwd_vjp(eps, block_rows, interpret, res, dy):
             jax.ShapeDtypeStruct(sshape, jnp.float32),
             jax.ShapeDtypeStruct(sshape, jnp.float32),
         ],
+        name="layer_norm_bwd",
         interpret=interpret,
     )(dy, x, scale.reshape(sshape))
     return (dx, dscale.reshape(C).astype(scale.dtype),

@@ -460,6 +460,14 @@ class ParallelExecutor:
     def run(self, fetch_list=None, feed=None, feed_dict=None,
             return_numpy=True, is_test=False, async_steps=None,
             validate=None):
+        # the parent of pexe.step and pexe.pending_wait
+        with _tm.span("pexe.run", step=self._step,
+                      program=self.program._version):
+            return self._run(fetch_list, feed, feed_dict, return_numpy,
+                             is_test, async_steps, validate)
+
+    def _run(self, fetch_list, feed, feed_dict, return_numpy, is_test,
+             async_steps, validate):
         from ..core.executor import resolve_async_steps
         k_async = resolve_async_steps(async_steps, self.async_steps)
         feed = dict(feed or feed_dict or {})
@@ -564,6 +572,10 @@ class ParallelExecutor:
         if engine is not None:
             ckey = ckey + (engine.key(),)
         fn = self._cache.get(ckey)
+        # a new key compiles on its first call: the compile log puts
+        # that down to this program, as Executor.run does
+        own = _tm.compile_owner(f"executor:{program._version}") \
+            if fn is None else _tm.compiles.NO_OWNER
         if fn is None:
             # opt-in pre-trace verification gate (same tri-state as
             # Executor.run: validate= arg > PADDLE_TPU_VALIDATE env):
@@ -626,11 +638,14 @@ class ParallelExecutor:
                                   self._replicated),
                     donate_argnums=(0,))
                 self._cache[ckey] = fn
+                _tm.compiles.register_program(
+                    f"executor:{program._version}", fn,
+                    (persist, feed_arrays, key))
         elif tm_on:
             _tm.counter("pexe.cache_hit_count").inc()
 
-        with _tm.span("pexe.step", step=self._step - 1,
-                      devices=self.device_count):
+        with own, _tm.span("pexe.step", step=self._step - 1,
+                           devices=self.device_count):
             try:
                 fetches, new_persist = fn(persist, feed_arrays, key)
             except Exception as e:

@@ -54,14 +54,13 @@ def profiler(state="All", sorted_key="total", profile_path=None,
 
 @contextlib.contextmanager
 def record_event(name):
-    """Host-side timing + device annotation (jax named scope). With
-    telemetry enabled the same region is also a telemetry span, so
-    profiler annotations land on the unified Chrome-trace timeline
-    next to the executor's own spans instead of only in _records."""
+    """Host-side timing of a region, as one telemetry span: under a
+    running profiler session it is the `pt/<name>` event of the trace,
+    beside the executor's own spans and on the clock of the device's
+    ops; with telemetry enabled it also joins the span ring."""
     t0 = time.perf_counter()
     try:
-        with _tm.span(name, cat="profiler"), \
-                jax.profiler.TraceAnnotation(name):
+        with _tm.span(name, cat="profiler"):
             yield
     finally:
         dt = time.perf_counter() - t0
@@ -84,175 +83,32 @@ def summary(sorted_key="total"):
 
 
 def device_op_times(trace_dir, family=True):
-    """Parse the xplane.pb trace under `trace_dir` and return
-    {op_name: total_device_seconds} aggregated over the device plane's
-    'XLA Ops' lines: device-side event durations, free of host-side
-    dispatch noise. `family=True` collapses fusion instances
-    ('fusion.123' → 'fusion') for a readable breakdown.
-
-    The xplane proto has moved between TF releases
-    (tensorflow.core.profiler → tensorflow.tsl.profiler → standalone
-    tsl); try every known home, then fall back to a dependency-free
-    wire-format decoder of the few fields this summary needs."""
+    """{op_name: total_device_seconds} over the "XLA Ops" lines of the
+    device planes in the xplane files under `trace_dir`: device-side
+    event durations, free of host-side dispatch noise. An op is named
+    by its HLO instruction (the event's name is the whole HLO line);
+    `family=True` collapses instances ('fusion.123' -> 'fusion') for a
+    readable breakdown. Sums only: for busy time and gaps read the
+    events' starts as well (chipbench/trace.py does)."""
     import glob
-    import os
-    os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION",
-                          "python")
-    xplane_pb2 = _find_xplane_pb2()
-
+    import re
+    from jax.profiler import ProfileData
+    instr = re.compile(r"%?([\w.\-]+)")
     out = defaultdict(float)
     for path in glob.glob(trace_dir + "/**/*.xplane.pb", recursive=True):
-        with open(path, "rb") as f:
-            data = f.read()
-        if xplane_pb2 is not None:
-            space = xplane_pb2.XSpace()
-            space.ParseFromString(data)
-            # filter before materializing: host planes can carry 100k+
-            # python-trace events this summary would only discard
-            planes = [
-                (plane.name,
-                 {mid: m.name for mid, m in plane.event_metadata.items()},
-                 [(line.name,
-                   [(ev.metadata_id, ev.duration_ps)
-                    for ev in line.events])
-                  for line in plane.lines if "XLA Ops" in line.name])
-                for plane in space.planes
-                if "TPU" in plane.name or "/device:" in plane.name]
-        else:
-            planes = _decode_xspace_minimal(data)
-        for pname, ev_meta, lines in planes:
-            if "TPU" not in pname and "/device:" not in pname:
+        for plane in ProfileData.from_file(path).planes:
+            if "TPU" not in plane.name and "/device:" not in plane.name:
                 continue
-            for lname, events in lines:
-                if "XLA Ops" not in lname:
+            for line in plane.lines:
+                if "XLA Ops" not in line.name:
                     continue
-                for metadata_id, duration_ps in events:
-                    nm = ev_meta.get(metadata_id, str(metadata_id))
+                for ev in line.events:
+                    m = instr.match(ev.name)
+                    nm = m.group(1) if m else ev.name
                     if family:
                         nm = nm.split(".")[0].rstrip("0123456789")
-                    out[nm] += duration_ps * 1e-12
+                    out[nm] += ev.duration_ns * 1e-9
     return dict(out)
-
-
-# every home the TF xplane proto has had across releases; the unit
-# test imports this so its cross-check can never drift from production
-_XPLANE_PB2_CANDIDATES = (
-    "tensorflow.core.profiler.protobuf.xplane_pb2",
-    "tensorflow.tsl.profiler.protobuf.xplane_pb2",
-    "tsl.profiler.protobuf.xplane_pb2",
-)
-
-
-def _find_xplane_pb2():
-    import importlib
-    for mod in _XPLANE_PB2_CANDIDATES:
-        try:
-            return importlib.import_module(mod)
-        except Exception:
-            continue
-    return None
-
-
-def _pb_fields(buf):
-    """Yield (field_number, wire_type, value) over a protobuf message.
-    Values: varint int for wire type 0, bytes for type 2; types 1/5
-    (fixed64/32) are skipped with correct framing; groups unsupported
-    (absent from the xplane schema). Truncated input raises (a partial
-    decode would silently understate device time downstream)."""
-    i, n = 0, len(buf)
-    while i < n:
-        tag = 0
-        shift = 0
-        while True:
-            b = buf[i]
-            i += 1
-            tag |= (b & 0x7F) << shift
-            if not b & 0x80:
-                break
-            shift += 7
-        field, wtype = tag >> 3, tag & 7
-        if wtype == 0:
-            val = 0
-            shift = 0
-            while True:
-                b = buf[i]
-                i += 1
-                val |= (b & 0x7F) << shift
-                if not b & 0x80:
-                    break
-                shift += 7
-            yield field, wtype, val
-        elif wtype == 2:
-            ln = 0
-            shift = 0
-            while True:
-                b = buf[i]
-                i += 1
-                ln |= (b & 0x7F) << shift
-                if not b & 0x80:
-                    break
-                shift += 7
-            if i + ln > n:
-                raise ValueError(
-                    f"truncated length-delimited field {field}: "
-                    f"declared {ln} bytes, {n - i} remain")
-            yield field, wtype, buf[i:i + ln]
-            i += ln
-        elif wtype == 1:
-            if i + 8 > n:
-                raise ValueError("truncated fixed64 field")
-            i += 8
-        elif wtype == 5:
-            if i + 4 > n:
-                raise ValueError("truncated fixed32 field")
-            i += 4
-        else:
-            raise ValueError(f"unsupported wire type {wtype}")
-
-
-def _decode_xspace_minimal(data):
-    """Hand-rolled XSpace decode (tsl/profiler/protobuf/xplane.proto):
-    XSpace.planes=1; XPlane{name=2, lines=3, event_metadata=4(map)};
-    XLine{name=2, events=4}; XEvent{metadata_id=1, duration_ps=3};
-    XEventMetadata{id=1, name=2}. Returns the same
-    [(plane_name, {mid: name}, [(line_name, [(mid, dur_ps)])])] shape
-    the protobuf path produces."""
-    planes = []
-    for f, w, v in _pb_fields(data):
-        if f != 1 or w != 2:
-            continue
-        pname, ev_meta, lines = "", {}, []
-        for pf, pw, pv in _pb_fields(v):
-            if pf == 2 and pw == 2:
-                pname = pv.decode("utf-8", "replace")
-            elif pf == 3 and pw == 2:  # XLine
-                lname, events = "", []
-                for lf, lw, lv in _pb_fields(pv):
-                    if lf == 2 and lw == 2:
-                        lname = lv.decode("utf-8", "replace")
-                    elif lf == 4 and lw == 2:  # XEvent
-                        mid = dur = 0
-                        for ef, ew, evv in _pb_fields(lv):
-                            if ef == 1 and ew == 0:
-                                mid = evv
-                            elif ef == 3 and ew == 0:
-                                dur = evv
-                        events.append((mid, dur))
-                lines.append((lname, events))
-            elif pf == 4 and pw == 2:  # map<int64, XEventMetadata>
-                mid, mname = 0, ""
-                for mf, mw, mv in _pb_fields(pv):
-                    if mf == 1 and mw == 0:
-                        mid = mv
-                    elif mf == 2 and mw == 2:
-                        for ef, ew, evv in _pb_fields(mv):
-                            if ef == 1 and ew == 0:
-                                mid = evv
-                            elif ef == 2 and ew == 2:
-                                mname = evv.decode("utf-8", "replace")
-                ev_meta[mid] = mname
-        planes.append((pname, ev_meta, lines))
-    return planes
 
 
 def profile_step_fn(fn, steps=10, trace_dir=None):
@@ -285,9 +141,6 @@ def profile_step_fn(fn, steps=10, trace_dir=None):
             f"no device-plane 'XLA Ops' events found in {trace_dir}; "
             "trace layout unrecognized for this backend")
     if _tm.enabled():
-        # device op times join the host spans on one timeline (per-step
-        # durations, laid back-to-back on a synthetic device track)
-        _tm.merge_device_ops(ops, scale=steps)
         _tm.gauge("profiler.device_step_seconds").set(total / steps)
     return total / steps, {k: v / steps for k, v in ops.items()}
 

@@ -281,7 +281,8 @@ class DecodeEngine:
                 bucket - n)
             _tm.gauge("serving.decode.compile_count").set(
                 self.compile_count)
-        return self.decoder.write_slots(state, out, slots)
+        with _tm.span("serving.decode.write_slots", rows=n):
+            return self.decoder.write_slots(state, out, slots)
 
     def _handoff(self, out, requests=()):
         """Move prefilled KV state (ck, cv, src_bias) from the prefill

@@ -88,14 +88,19 @@ class DecodeResult:
     """What a decode future resolves to."""
 
     __slots__ = ("tokens", "finish_reason", "tenant", "ttft_s",
-                 "decode_s")
+                 "decode_s", "token_t")
 
-    def __init__(self, tokens, finish_reason, tenant, ttft_s, decode_s):
+    def __init__(self, tokens, finish_reason, tenant, ttft_s, decode_s,
+                 token_t=None):
         self.tokens = tokens                # np.int32 [n_generated]
         self.finish_reason = finish_reason  # "eos" | "length"
         self.tenant = tenant
         self.ttft_s = ttft_s
         self.decode_s = decode_s
+        # time.monotonic() at which each token was emitted, np.float64
+        # [n_generated]: np.diff gives every gap between tokens, so one
+        # stalled iteration shows as itself and is not averaged away
+        self.token_t = token_t
 
     def __repr__(self):
         return (f"DecodeResult({len(self.tokens)} tokens, "
@@ -234,6 +239,12 @@ class ContinuousScheduler:
         slots stepped (0 = nothing to do). Single-threaded by
         contract: either the started loop thread calls this, or a
         test drives it by hand — never both."""
+        with _tm.span("serving.sched.iteration",
+                      active=self.pool.active_count(),
+                      queued=self._queued) as it_span:
+            return self._run_iteration(it_span)
+
+    def _run_iteration(self, it_span):
         now = time.monotonic()
         self._retire_deadlines(now)
         self._drop_expired_queued(now)
@@ -277,7 +288,7 @@ class ContinuousScheduler:
                          "point": "serving.request"},
                         f"poisoned request in slot {slot.index} of "
                         f"{self.name}")
-        self._admit()
+        it_span.set(admitted=self._admit())
         return self._step_active()
 
     def _retire_deadlines(self, now):
@@ -321,7 +332,8 @@ class ContinuousScheduler:
 
     def _admit(self):
         """Fill free slots from the queues by WFQ; preempt if allowed
-        and somebody is starving below their fair share."""
+        and somebody is starving below their fair share. Returns the
+        number of requests admitted."""
         batch, slots = [], []
         while True:
             with self._cond:
@@ -372,6 +384,7 @@ class ContinuousScheduler:
                 _tm.counter("serving.decode.admitted").inc(len(batch))
                 _tm.gauge("serving.decode.queue_depth").set(
                     self._queued)
+        return len(batch)
 
     def _pick_preemption(self, queued, held):
         starved = self.qos.pick_tenant(queued, held)
@@ -405,8 +418,10 @@ class ContinuousScheduler:
                 _tm.gauge("serving.decode.slot_occupancy").set(0.0)
             return 0
         self._iteration += 1
-        nxt = self.engine.step(self.state, self._ids, self._pos,
-                               seed=self._iteration)
+        with _tm.span("serving.decode.step", slots=self.pool.num_slots,
+                      active=len(active)):
+            nxt = self.engine.step(self.state, self._ids, self._pos,
+                                   seed=self._iteration)
         now = time.monotonic()
         eos = self.config.eos
         trace = _tm.reqtrace_enabled()
@@ -432,6 +447,7 @@ class ContinuousScheduler:
                         replica=self.replica_index, slot=slot.index,
                         ttft_ms=round((now - req.enqueue_t) * 1e3, 3))
             slot.tokens.append(tok)
+            slot.token_t.append(now)
             self.tokens_generated += 1
             if _tm.enabled():
                 _tm.counter("serving.decode.tokens_total").inc()
@@ -456,7 +472,8 @@ class ContinuousScheduler:
             finish_reason=reason, tenant=req.tenant,
             ttft_s=(slot.first_token_t - req.enqueue_t
                     if slot.first_token_t else None),
-            decode_s=now - slot.joined_t))
+            decode_s=now - slot.joined_t,
+            token_t=np.asarray(slot.token_t, np.float64)))
         self._finish_slot(slot, delivered=True, reason=reason)
 
     def _finish_slot(self, slot, delivered, reason):

@@ -20,13 +20,14 @@ __all__ = ["Slot", "SlotPool"]
 class Slot:
     """One row of the decode batch, bound to at most one request."""
 
-    __slots__ = ("index", "request", "tokens", "joined_iter",
+    __slots__ = ("index", "request", "tokens", "token_t", "joined_iter",
                  "joined_t", "first_token_t")
 
     def __init__(self, index):
         self.index = index
         self.request = None
         self.tokens = None          # generated token ids (host list)
+        self.token_t = None         # time.monotonic() of each, same length
         self.joined_iter = -1
         self.joined_t = 0.0
         self.first_token_t = None
@@ -38,13 +39,14 @@ class Slot:
     def bind(self, request, iteration):
         self.request = request
         self.tokens = []
+        self.token_t = []
         self.joined_iter = iteration
         self.joined_t = time.monotonic()
         self.first_token_t = None
 
     def clear(self):
         self.request = None
-        self.tokens = None
+        self.tokens = self.token_t = None
         self.joined_iter = -1
         self.first_token_t = None
 

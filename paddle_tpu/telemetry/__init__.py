@@ -13,14 +13,26 @@ Off by default. `PADDLE_TPU_TELEMETRY=1` (or `enable()`) turns it on;
 disabled mode is the contract the hot paths are built around: every
 instrumented site is gated on one flag check, no metric is ever
 registered, and `snapshot()` stays `{}` (pinned by
-tests/test_bench_contract.py).
+tests/test_bench_contract.py). Two things do not wait for the flag,
+because something else already gates them: `span()` always opens a
+`jax.profiler.TraceAnnotation` (`pt/<name>`), which records only while
+a profiler session runs, and `compile_log()` gets a record only when
+something compiles.
 
 Surfaces
 --------
 - `snapshot()` — plain dict of every metric
 - `prometheus_text()` — text exposition format
-- `chrome_trace()` / `write_chrome_trace(path)` — trace-event JSON;
-  `merge_device_ops(profiler.device_op_times(dir))` adds device time
+- `span(name, **counts)` — the one span primitive: into the profiler's
+  trace (host spans and device ops in one file, on one clock) whenever
+  a session runs, and into the ring below when telemetry is enabled
+- `chrome_trace()` / `write_chrome_trace(path)` — the ring as
+  trace-event JSON
+- `compile_log()` — one record per JAX compile event (trace, lower,
+  backend compile, persistent-cache hit/miss/load) with the layer that
+  owned it (`compile_owner(name)` marks a stretch);
+  `compiled_text(owner)` is that layer's optimized HLO, through which
+  a device trace's ops are joined to their `jax.named_scope`
 - `flush()` — log a summary; with `PADDLE_TPU_TELEMETRY_DIR=<dir>`
   also write metrics.json / metrics.prom / trace.json there
 - `fleet` — multi-rank layer: rank labels on every export, a per-rank
@@ -39,19 +51,20 @@ import os
 from . import registry as _registry
 from . import spans as _spans
 from . import memory as _memory
+from . import compiles
 from . import fleet
 from .registry import (Counter, Gauge, Histogram, counter, gauge,
                        histogram, prometheus_text,
                        DEFAULT_TIME_BUCKETS)
 from .spans import (span, iter_spans, chrome_trace, write_chrome_trace,
-                    merge_device_ops, SpanRecord, append_span, now_us,
-                    instant_event)
+                    SpanRecord, append_span, now_us, instant_event)
+from .compiles import compile_log, compiled_text, owned as compile_owner
 from .memory import device_memory_supported, sample_device_memory
 
 __all__ = ["enabled", "enable", "disable", "counter", "gauge",
            "histogram", "span", "snapshot", "prometheus_text",
-           "chrome_trace", "write_chrome_trace", "merge_device_ops",
-           "iter_spans", "sample_device_memory",
+           "chrome_trace", "write_chrome_trace", "compile_log",
+           "compile_owner", "compiled_text", "compiles", "iter_spans", "sample_device_memory",
            "device_memory_supported", "reset", "flush", "fleet",
            "append_span", "now_us", "instant_event", "Counter",
            "Gauge", "Histogram", "SpanRecord", "DEFAULT_TIME_BUCKETS",
@@ -157,8 +170,8 @@ def snapshot():
 
 
 def reset():
-    """Drop all metrics, spans, and merged device events (not the
-    enabled flag). Used by tpustat to scope metrics to the steady-state
+    """Drop all metrics and spans (not the enabled flag, and not the
+    compile log: what was compiled stays compiled). Used by tpustat to scope metrics to the steady-state
     loop, and by tests."""
     _registry.reset_metrics()
     _spans.clear_spans()

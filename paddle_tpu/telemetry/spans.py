@@ -1,16 +1,18 @@
-"""Host-side span tracer with Chrome trace-event export.
+"""The one span primitive, and the ring's Chrome trace-event export.
 
-Spans nest (a thread-local depth counter tags each record) and land in
-a bounded process-global ring; `chrome_trace()` renders them as
-complete-duration ("X") events that load directly in chrome://tracing
-or Perfetto. Device op durations from `profiler.device_op_times()`
-merge onto the same timeline via `merge_device_ops` — the xplane
-decode yields durations only, so device events are laid out
-back-to-back on their own synthetic track starting at the host
-timeline origin.
+`span(name, **args)` always opens a `jax.profiler.TraceAnnotation`
+named `pt/<name>`: while a profiler session runs (`profiler.
+start_profiler`, a benchmark's traced run) the span lands in the
+profiler's own xplane file, on the clock of the device's ops, with its
+arguments as the event's stats; with no session the annotation records
+nothing. With telemetry enabled the same span is also appended to a
+bounded process-global ring (a thread-local depth counter tags each
+record), which `chrome_trace()` renders as complete-duration ("X")
+events for chrome://tracing or Perfetto.
 
-Timestamps are perf_counter_ns relative to this module's import, in
-microseconds (the trace-event format's native unit).
+Ring timestamps are perf_counter_ns relative to this module's import,
+in microseconds (the trace-event format's native unit); the profiler's
+file has its own clock, shared with the device.
 """
 import collections
 import json
@@ -19,7 +21,7 @@ import threading
 import time
 
 __all__ = ["span", "iter_spans", "clear_spans", "chrome_trace",
-           "write_chrome_trace", "merge_device_ops", "SpanRecord",
+           "write_chrome_trace", "SpanRecord", "TRACE_PREFIX",
            "now_us", "append_span", "instant_event", "counter_event"]
 
 _EPOCH_NS = time.perf_counter_ns()
@@ -29,8 +31,11 @@ SpanRecord = collections.namedtuple(
     "SpanRecord", ["name", "cat", "ts_us", "dur_us", "tid", "depth",
                    "args"])
 
+# every span's name in the profiler's trace starts with this, so that a
+# reader tells the program's spans from JAX's and the benchmark's own
+TRACE_PREFIX = "pt/"
+
 _spans = collections.deque(maxlen=_MAX_SPANS)
-_device_events = []          # laid-out events from merge_device_ops
 _lock = threading.Lock()
 _tls = threading.local()
 
@@ -79,43 +84,57 @@ def counter_event(name, values, ts_us=None, track="memory"):
                        tid=track, args=dict(values))
 
 
+_annotation = None
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation, imported on first use: this module
+    is pulled in during package init and by jax-free tools."""
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation
+
+
 class _Span:
-    __slots__ = ("name", "cat", "args", "_t0")
+    """A TraceAnnotation that also lands in the ring when telemetry is
+    on. `set(**args)` adds counts known only once the span is open
+    (whether this run compiled); they join the event's stats."""
+    __slots__ = ("name", "cat", "args", "_ann", "_ring", "_t0")
 
     def __init__(self, name, cat, args):
         self.name = name
         self.cat = cat
         self.args = args
+        self._ann = (_annotation or _trace_annotation())(
+            TRACE_PREFIX + name, **args)
+        self._ring = _span_enabled()
+
+    def set(self, **args):
+        self._ann.set_metadata(**args)
+        if self._ring:
+            self.args.update(args)
 
     def __enter__(self):
-        _tls.depth = depth = getattr(_tls, "depth", 0)
-        _tls.depth = depth + 1
-        self._t0 = _now_us()
+        self._ann.__enter__()
+        if self._ring:
+            _tls.depth = depth = getattr(_tls, "depth", 0)
+            _tls.depth = depth + 1
+            self._t0 = _now_us()
         return self
 
     def __exit__(self, *exc):
-        t1 = _now_us()
-        _tls.depth -= 1
-        rec = SpanRecord(self.name, self.cat, self._t0, t1 - self._t0,
-                         threading.get_ident(), _tls.depth,
-                         self.args or None)
-        with _lock:
-            _spans.append(rec)
+        if self._ring:
+            t1 = _now_us()
+            _tls.depth -= 1
+            rec = SpanRecord(self.name, self.cat, self._t0,
+                             t1 - self._t0, threading.get_ident(),
+                             _tls.depth, self.args or None)
+            with _lock:
+                _spans.append(rec)
+        self._ann.__exit__(*exc)
         return False
-
-
-class _NullSpan:
-    """Singleton no-op context manager for the disabled path."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
 
 
 def _span_enabled():
@@ -125,10 +144,11 @@ def _span_enabled():
 
 
 def span(name, cat="host", **args):
-    """Context manager timing a host-side region. No-op (a shared
-    singleton, no allocation) when telemetry is disabled."""
-    if not _span_enabled():
-        return _NULL_SPAN
+    """Context manager around a host-side region: a `pt/<name>`
+    TraceAnnotation with `args` as its stats (recorded only while a
+    profiler session runs), and a ring record when telemetry is
+    enabled. Pass counts that are already computed; nothing is
+    formatted here."""
     return _Span(name, cat, args)
 
 
@@ -140,31 +160,6 @@ def iter_spans():
 def clear_spans():
     with _lock:
         _spans.clear()
-        del _device_events[:]
-
-
-def merge_device_ops(op_times, origin_us=None, track="device ops",
-                     scale=1.0):
-    """Lay `{op_name: seconds}` (profiler.device_op_times output) onto
-    the trace as back-to-back X events on a synthetic device track.
-    `scale` divides durations (pass `steps` to show per-step time);
-    `origin_us` anchors the track (default: first host span, else 0).
-    Returns the number of events added."""
-    if origin_us is None:
-        with _lock:
-            origin_us = min((s.ts_us for s in _spans), default=0.0)
-    t = float(origin_us)
-    events = []
-    for name, secs in sorted(op_times.items(), key=lambda kv: -kv[1]):
-        dur = secs * 1e6 / scale
-        events.append({"name": name, "cat": "device", "ph": "X",
-                       "ts": t, "dur": dur, "pid": os.getpid(),
-                       "tid": track,
-                       "args": {"total_s": secs, "scale": scale}})
-        t += dur
-    with _lock:
-        _device_events.extend(events)
-    return len(events)
 
 
 def chrome_trace():
@@ -174,7 +169,6 @@ def chrome_trace():
     pid = os.getpid()
     with _lock:
         spans = list(_spans)
-        device = list(_device_events)
     events = []
     tids = set()
     for s in spans:
@@ -204,7 +198,6 @@ def chrome_trace():
         name = f"host thread {tid}" if isinstance(tid, int) else str(tid)
         events.append({"name": "thread_name", "ph": "M", "pid": pid,
                        "tid": tid, "args": {"name": name}})
-    events.extend(device)
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
