@@ -12,6 +12,7 @@ minus the active() backend gate — fn stays self-gating (dispatch
 correctness never depends on a probe), the probe exists so OTHER
 subsystems can ask "would this kernel take these shapes?" statically.
 """
+import jax
 import jax.numpy as jnp
 
 from ..pallas import flash_attention as fa
@@ -102,17 +103,36 @@ register(KernelSpec(
 
 
 # -------------------------------------------------------- flash_attention
+def _flash_bhtd(q, k, v, layout="bhtd"):
+    """Shape structs of q, k, v as the tiled kernel sees them."""
+    if layout != "bthd":
+        return q, k, v
+
+    def swap(x):
+        B, T, H, D = x.shape
+        return jax.ShapeDtypeStruct((B, H, T, D), x.dtype)
+    return swap(q), swap(k), swap(v)
+
+
 def _flash_probe(q, k, v, bias=None, causal=False, scale=None,
                  with_lse=False, causal_offset=0, *, interpret=False,
-                 **kw):
+                 layout="bhtd", **kw):
+    """try_flash's own tests, less the backend gate: the short kernel's
+    pick, then the tiled kernel's gate and shapes."""
     if getattr(q, "ndim", 0) != 4:
         return False
-    if not interpret and k.shape[2] < fa.MIN_SEQ_LEN:
+    if fa.picks_short(q, k, v, bias, with_lse, causal_offset, layout,
+                      interpret):
+        return True
+    if not interpret \
+            and fa._tiled_dims(q, k, layout)[1] < fa.tiled_min_len(
+                with_lse, layout):
         return False
-    return fa.supports(q, k, v, bias=bias)
+    return fa.supports(*_flash_bhtd(q, k, v, layout), bias=bias)
 
 
-def _flash_space(q, k, v, *a, **kw):
+def _flash_space(q, k, v, *a, layout="bhtd", **kw):
+    q, k, v = _flash_bhtd(q, k, v, layout)
     T, S = q.shape[2], k.shape[2]
     D, DV = q.shape[-1], v.shape[-1]
     out = []
@@ -125,10 +145,11 @@ def _flash_space(q, k, v, *a, **kw):
     return out
 
 
-def _flash_config_ok(cfg, q, k, v, *a, **kw):
+def _flash_config_ok(cfg, q, k, v, *a, layout="bhtd", **kw):
     bq, bk = cfg.get("block_q"), cfg.get("block_k")
     if bq is None and bk is None:
         return not cfg
+    q, k, v = _flash_bhtd(q, k, v, layout)
     T, S = q.shape[2], k.shape[2]
     return fa._choose_blocks(T, S, q.shape[-1], v.shape[-1],
                              bq, bk) == (bq, bk)
@@ -148,12 +169,14 @@ register(KernelSpec(
     probe=_flash_probe,
     tol=(2e-5, 2e-5),
     op_types=("flash_attention",),
-    signature=lambda q, k, v, *a, **kw: (_shape(q) + (k.shape[2],)
-                                         + (v.shape[-1],)),
+    signature=lambda q, k, v, *a, layout="bhtd", **kw: (
+        _shape(q) + (fa._tiled_dims(q, k, layout)[1], v.shape[-1])
+        + ((layout,) if layout != "bhtd" else ())),
     tune_space=_flash_space,
     config_ok=_flash_config_ok,
     example=_flash_example,
-    note="tiled online-softmax attention, fwd+bwd (custom_vjp)",
+    note="fused attention, fwd+bwd (custom_vjp): one-tile kernel on "
+         "[B,T,H*D] for short sequences, tiled online-softmax for long",
 ))
 
 

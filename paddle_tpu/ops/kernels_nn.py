@@ -907,37 +907,32 @@ def _sampled_softmax_ce(ctx, ins, attrs):
 
 @kernel("flash_attention")
 def _flash_attention(ctx, ins, attrs):
-    """Flash attention: Pallas TPU kernel when available, jnp fallback.
+    """Flash attention: a Pallas TPU kernel where try_flash picks one,
+    else the jnp composition in _sdpa.
 
     Replaces the reference's unfused softmax(QK^T)V (cuDNN path) with a
-    tiled online-softmax kernel — no [T,T] HBM materialization.
+    fused kernel: no [T,S] scores or weights in HBM.
 
     layout attr: "bhtd" (default) or "bthd". bthd skips the head
-    split/merge transposes entirely — the dots contract over a middle
-    batch dim (profiled ~1.4 ms/step of pure copies on the transformer
-    bench); the Pallas kernel still wants bhtd, so the dispatch
-    transposes lazily (DCE'd when the kernel doesn't run — below its
-    seq-length crossover the XLA path is the fast one anyway)."""
+    split/merge transposes entirely: the short-sequence kernel reads
+    and writes [B, T, H*D] as the model keeps it, and _sdpa's dots
+    contract with H as a middle batch dim. Only the tiled
+    long-sequence kernel still wants bhtd; try_flash transposes for it
+    where it picks it."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     mask = _opt(ins, "Mask")
     causal = attrs.get("causal", False)
     scale = attrs.get("scale", None) or (1.0 / np.sqrt(q.shape[-1]))
-    bthd = attrs.get("layout", "bhtd") == "bthd"
-    # Shared dispatch policy (perf gate + supports) lives in try_flash,
-    # reached through the kern registry seam — explicit gating, no
-    # silent exception fallback (VERDICT r1 weak #2)
+    # The one dispatch policy (which kernel, from the shapes, the layout
+    # and the dtype; None = the composition) lives in try_flash, reached
+    # through the kern registry seam: explicit gating, no silent
+    # exception fallback (VERDICT r1 weak #2)
     fused = ctx.accel("flash_attention")
-    if fused is not None and bthd:
-        out = fused(q.swapaxes(1, 2), k.swapaxes(1, 2),
-                    v.swapaxes(1, 2), bias=mask, causal=causal,
-                    scale=scale)
-        if out is not None:
-            return {"Out": [out.swapaxes(1, 2)],
-                    "Weights": [jnp.zeros((0,), q.dtype)]}
-    elif fused is not None:
-        out = fused(q, k, v, bias=mask, causal=causal, scale=scale)
+    if fused is not None:
+        out = fused(q, k, v, bias=mask, causal=causal, scale=scale,
+                    layout=attrs.get("layout", "bhtd"))
         if out is not None:
             return {"Out": [out], "Weights": [jnp.zeros((0,), q.dtype)]}
-    # below the kernel's seq-length crossover: the fused-XLA path IS the
-    # fast path; one implementation lives in _sdpa
+    # no kernel wins at this shape (the measured table is in PERF.md
+    # section 6, PR 28), or none can lower here: one composition, in _sdpa
     return _sdpa(ctx, ins, attrs)

@@ -1,19 +1,41 @@
-"""Pallas flash-attention kernel for TPU — forward AND backward.
+"""Pallas flash-attention kernels for TPU, forward AND backward: one for
+long sequences, one for short ones. try_flash picks between them and
+XLA's composition (ops/kernels_nn.py::_sdpa) from the shapes, the layout
+and the dtype of its arguments, by crossovers measured on the chip (the
+table above SHORT_MIN_SEQ_LEN).
 
-Tiled online-softmax attention (FlashAttention algorithm) written as
-pipelined Pallas TPU kernels: the grid is (batch*heads, q_blocks,
-k_blocks) with the k dimension innermost and marked "arbitrary", so
-Mosaic double-buffers the K/V block DMAs against the MXU matmuls.
-Online-softmax state (m, l, acc) lives in VMEM scratch that persists
-across the k iterations of one q block; outputs are flushed on the last
-k step. No [T,S] score matrix ever hits HBM. The backward pass is the
-standard flash recomputation: forward saves only the per-row logsumexp;
-dq / dk+dv kernels rebuild the probabilities block-wise with the same
-pipelined grid structure. This replaces the reference's unfused
+**The tiled kernel** (flash_attention, flash_attention_with_lse; arrays
+[B, H, T, D]). Online-softmax attention (FlashAttention algorithm)
+written as pipelined Pallas TPU kernels: the grid is (batch*heads,
+q_blocks, k_blocks) with the k dimension innermost and marked
+"arbitrary", so Mosaic double-buffers the K/V block DMAs against the
+MXU matmuls. Online-softmax state (m, l, acc) lives in VMEM scratch that
+persists across the k iterations of one q block; outputs are flushed on
+the last k step. No [T,S] score matrix ever hits HBM. The backward pass
+is the standard flash recomputation: forward saves only the per-row
+logsumexp; dq / dk+dv kernels rebuild the probabilities block-wise with
+the same pipelined grid structure. This replaces the reference's unfused
 softmax(QK^T)V composition
 (python/paddle/fluid/nets.py:scaled_dot_product_attention) as the
 long-sequence attention path, and is registered through jax.custom_vjp
 so it stays on the training path under jax.value_and_grad.
+
+**The short-sequence kernel** (flash_attention_bthd; arrays [B, T, H, D]
+= [B, T, H*D], the layout the model keeps, so no transpose on either
+side and no 64-wide minor dimension anywhere). Where the whole key axis
+fits one block there is nothing to tile and nothing online: a grid step
+takes one batch row, walks the heads inside the body one 128-lane
+group at a time (two heads at D = 64, picked by lane masks so that every
+product is 128 lanes wide), and computes plain max / exp / sum in
+float32 on [256, S] scores that never leave VMEM. ONE backward kernel
+recomputes p once and gives dq, dk and dv (the tiled kernel rebuilds p
+twice). Residuals: out and the per-row logsumexp [B, H, T]. p is rounded
+to the operands' dtype only as the operand of the second matmul, as
+_sdpa does; the scores themselves stay float32 (_sdpa rounds them to
+bf16), so it is at least as exact as the composition it replaces. At the
+transformer-base training shape ([128, 256, 8 x 64] bf16, v5e) it takes
+1.06 ms forward + backward against the composition's 2.96 and the tiled
+kernel's 4.90 (PERF.md section 6, PR 28).
 
 Supported extras (covers the flagship transformer end-to-end):
 - `bias`: additive key-padding bias of shape [B, S] (the [B,1,1,S]
@@ -39,15 +61,20 @@ Supported extras (covers the flagship transformer end-to-end):
   zero. Do not read fully-masked rows from the plain `flash_attention`
   output.
 
-Block sizes default to 1024x2048 (tuned on v5e; clamped to a VMEM
-budget per head dim — see _choose_blocks).
+The tiled kernel's block sizes default to 1024x2048 (tuned on v5e;
+clamped to a VMEM budget per head dim, see _choose_blocks).
 
-When to use which path: XLA's fused attention is faster below ~4k
-sequence length (the [T,S] tile still fits the fusion's working set);
-the Pallas kernel wins on memory and bandwidth as S grows — 2x at 8192,
-and it is the only path that compiles at >=16384 (the unfused scores no
-longer fit HBM). The op dispatch in ops/kernels_nn.py gates on
-MIN_SEQ_LEN; interpret mode (CPU tests) bypasses the gate.
+When to use which path is try_flash's to say, and only its: the short
+kernel for `bthd` arrays with both lengths 256, 384 or 512; the tiled
+kernel for `bthd` arrays from S = 1024 on (it beats the composition
+there forward and forward + backward, 2x at 1024, and from 2048 on it
+is the only path whose backward fits the chip's memory); the
+composition elsewhere. `bhtd` callers (ulysses, ring attention) were
+not measured and keep their gate of 4096: the "XLA's fused attention is
+faster below ~4k" it came with was a reading from before the direct
+runtime with no shape stated, and the table above SHORT_MIN_SEQ_LEN
+replaces it for the op's path only. Interpret mode (CPU tests) bypasses
+the performance gates.
 
 Measured regime note (v5e, D=64, T=32k causal): ~0.2 attn-MFU fwd+bwd
 with the default 1024x2048 blocks — a swept optimum (512/256-row and
@@ -87,16 +114,47 @@ except Exception:  # pragma: no cover
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "flash_attention_reference", "try_flash", "STATS", "set_mode",
-           "set_softmax_dtype", "active", "MIN_SEQ_LEN"]
+           "set_softmax_dtype", "active", "MIN_SEQ_LEN",
+           "MIN_SEQ_LEN_BTHD", "SHORT_MIN_SEQ_LEN", "SHORT_MAX_SEQ_LEN",
+           "flash_attention_bthd", "supports_short", "picks_short",
+           "tiled_min_len"]
 
 _NEG_INF = -1e30
 
-# Below this key length the unfused XLA path is measurably faster on
-# v5e (scores tile fits in the fusion working set; kernel grid overhead
-# dominates); at 4096 the two are at parity and beyond it the Pallas
-# kernel wins (2x at 8192; XLA fails to compile at >=16384). The op
-# dispatch uses the Pallas path only for S >= this.
+# The crossovers the chip showed (v5e, B*H = 1024, D = 64, bf16, bias on,
+# causal on and off, arrays in the op's `bthd` layout, S = T; the table
+# and the command are in PERF.md section 6, PR 28). ms forward + backward
+# (forward alone), short kernel / XLA's composition / tiled kernel:
+#   S =  128    0.60 (0.21) /  0.58 (0.28) /  2.31 (0.90)
+#   S =  256    1.06 (0.35) /  2.96 (0.83) /  4.90 (2.02)
+#   S =  384    1.84 (0.68) /  7.18 (1.58) /  6.68 (2.99)
+#   S =  512    3.64 (0.98) / 12.15 (3.07) /  8.73 (3.83)
+#   S = 1024   15.16 (3.49) / 47.42 (11.9) / 25.10 (10.0)
+#   S = 2048    not compiled / out of memory (30.3) / 77.19 (26.9)
+# The short kernel (whole key axis in one block, `bthd`) is picked where
+# it was measured to win: both lengths in [SHORT_MIN_SEQ_LEN,
+# SHORT_MAX_SEQ_LEN] and multiples of 128 (Mosaic has compiled it at no
+# other length; the ragged lengths of the tests run in interpret mode
+# only). At 128 it only ties; at 1024 it still wins but its unrolled
+# tiles take 33 s to compile a kernel, so the tiled kernel keeps that
+# length.
+SHORT_MIN_SEQ_LEN = 256
+SHORT_MAX_SEQ_LEN = 512
+
+# `bhtd` callers (parallel/ulysses.py, parallel/ring_attention.py and its
+# `with_lse`) keep the gate they had: no cell measures them, nothing was
+# timed at their [B, H/sp, T, D] shapes or at a small B*H, and PR 28 did
+# not change what they run. The "XLA is faster below ~4k" it came with
+# was a reading from before the direct runtime, with no shape stated.
 MIN_SEQ_LEN = 4096
+
+# The op's `bthd` path, the one the table measured (B*H = 1024, the
+# transposes try_flash makes for the tiled kernel included): from this
+# key length on the tiled kernel beats the composition forward and
+# forward + backward. At 512 it wins only with the backward (8.7 against
+# 12.1 ms) and loses the forward alone (3.8 against 3.1), which
+# try_flash cannot tell apart.
+MIN_SEQ_LEN_BTHD = 1024
 
 # Trace-time evidence that the Pallas path (not the jnp fallback) was
 # selected — tests assert on this (VERDICT r1: the kernel must demonstrably
@@ -603,8 +661,371 @@ def flash_attention_with_lse(q, k, v, bias=None, causal=False, scale=None,
 
 
 # ---------------------------------------------------------------------------
+# short sequences, the op's own [B, T, H*D] layout: one tile, no online
+# softmax, one backward kernel
+# ---------------------------------------------------------------------------
+# The whole key axis is one block and the [tq, S] scores of one head live
+# in VMEM only. A block is over [B, T, H*D] (lane-dense: no 64-wide minor
+# dimension, no swapaxes around the call); the heads are walked inside
+# the body, one 128-lane group at a time (two heads at D = 64). A head
+# inside its group is picked by a lane mask on ONE operand of each
+# product (the contraction then runs over 128 lanes, the other head's
+# contributing zeros) and by a lane select on the [*, 128] result, so no
+# 64-lane slice, shift or concat is ever made.
+_SHORT_FWD_ROWS = 256         # rows of q per scores tile, forward
+_SHORT_BWD_ROWS = 256         # rows of q and of k per tile, backward
+_SHORT_BWD_KEYS = 256
+# VMEM of one grid step: under _SHORT_VMEM_FREE the kernels ask for
+# nothing and live in the compiler's own 16 MiB of scoped VMEM (the rest
+# is XLA's, which keeps neighbouring ops' operands there); past it they
+# ask for what they need, up to _SHORT_VMEM_MAX.
+_SHORT_VMEM_FREE = 12 * 1024 * 1024
+_SHORT_VMEM_MAX = 96 * 1024 * 1024
+
+
+def _short_group(H, D):
+    """Lanes of one head group: the whole H*D where that is under a
+    vreg's 128 lanes, else 128 (D | 128) or D (128 | D); 0 = no legal
+    grouping."""
+    if H * D <= 128:
+        return H * D
+    if 128 % D == 0:
+        return 128
+    if D % 128 == 0:
+        return D
+    return 0
+
+
+def _lane_mask(width, d, a):
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    return (lane >= a * d) & (lane < (a + 1) * d)
+
+
+def _short_add(b_ref, r0, tq, k0, tk, causal, offset, has_bias):
+    """The additive [tq, tk] (or [1, tk]) float32 term of the batch row's
+    scores tile at (r0, k0), shared by its heads: key-padding bias and
+    causal mask."""
+    add = b_ref[:, k0:k0 + tk].astype(jnp.float32) if has_bias \
+        else None                                              # [1, tk]
+    if causal:
+        q_pos = r0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
+        k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
+        keep = q_pos + offset >= k_pos
+        add = jnp.where(keep, 0.0 if add is None else add, _NEG_INF)
+    return add
+
+
+def _pick_lanes(masks, parts):
+    """One [*, W] array whose head-a lanes come from parts[a]."""
+    out = parts[-1]
+    for a in range(len(parts) - 2, -1, -1):
+        out = jnp.where(masks[a], parts[a], out)
+    return out
+
+
+def _short_fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref, *,
+                      causal, scale, offset, d, tq, has_bias):
+    """One grid step = one batch row. q_ref/o_ref [T, H*D]; k_ref/v_ref
+    [S, H*D]; b_ref [1, S]; lse_ref [H, T]. The float32 scores of one
+    head are made tq rows at a time, [tq, S], and never leave VMEM. The
+    per-row logsumexp of a head is a column; the columns of all heads
+    are gathered lane by lane into one [T, 128] array and turned into
+    lse_ref's rows by ONE transpose (a relayout per head cost 40% of
+    the kernel)."""
+    T, HD = q_ref.shape
+    S = k_ref.shape[0]
+    H = HD // d
+    W = _short_group(H, d)
+    per = W // d
+    masks = [_lane_mask(W, d, a) for a in range(per)]
+    batched_lse = T % 128 == 0 and H <= 128
+    hlane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    cols = []
+    for c in range(T // tq):
+        r0 = c * tq
+        add = _short_add(b_ref, r0, tq, 0, S, causal, offset, has_bias)
+        col = jnp.zeros((tq, 128), jnp.float32)
+        for g in range(HD // W):
+            lanes = slice(g * W, (g + 1) * W)
+            q2 = q_ref[r0:r0 + tq, lanes]
+            k2 = k_ref[:, lanes]
+            v2 = v_ref[:, lanes]
+            outs = []
+            for a in range(per):
+                qa = q2 if per == 1 else jnp.where(
+                    masks[a], q2, jnp.zeros_like(q2))
+                s = _dot_t(qa, k2) * scale                   # [tq, S] f32
+                if add is not None:
+                    s = s + add
+                m = jnp.max(s, axis=-1, keepdims=True)
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=-1, keepdims=True)
+                outs.append(_dot(p.astype(v2.dtype), v2) / l)
+                lse = m + jnp.log(l)                         # [tq, 1]
+                if batched_lse:
+                    col = jnp.where(hlane == g * per + a, lse, col)
+                else:
+                    lse_ref[g * per + a, r0:r0 + tq] = lse[:, 0]
+            o_ref[r0:r0 + tq, lanes] = _pick_lanes(
+                masks, outs).astype(o_ref.dtype)
+        cols.append(col)
+    if batched_lse:
+        rows = (cols[0] if len(cols) == 1
+                else jnp.concatenate(cols, axis=0)).T        # [128, T]
+        lse_ref[...] = rows[:H]
+
+
+def _short_bwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, do_ref, lse_ref,
+                      dq_ref, dk_ref, dv_ref, db_ref=None, *, causal, scale,
+                      offset, d, tq, tk, has_bias):
+    """dq, dk, dv from ONE recomputation of p, in [tq, tk] tiles (given
+    lse and delta the backward is pointwise in the scores, so keys tile
+    too). Blocks as the forward's; delta = rowsum(dO * O) is taken here,
+    per head, from the lanes the head owns. db_ref [1, S] (float32) is
+    there only where the bias itself is being differentiated: the column
+    sums of ds over the batch row's heads and queries."""
+    T, HD = q_ref.shape
+    S = k_ref.shape[0]
+    W = _short_group(HD // d, d)
+    per = W // d
+    masks = [_lane_mask(W, d, a) for a in range(per)]
+    f32 = jnp.float32
+    nq, nk = T // tq, S // tk
+
+    def pick(x, a):
+        return x if per == 1 else jnp.where(masks[a], x, jnp.zeros_like(x))
+
+    dbs = [jnp.zeros((1, tk), f32) for _ in range(nk)]
+    for g in range(HD // W):
+        lanes = slice(g * W, (g + 1) * W)
+        dqs = [None] * nq
+        for kc in range(nk):
+            keys = slice(kc * tk, (kc + 1) * tk)
+            k2 = k_ref[keys, lanes]
+            v2 = v_ref[keys, lanes]
+            dk2 = dv2 = None
+            for qc in range(nq):
+                rows = slice(qc * tq, (qc + 1) * tq)
+                add = _short_add(b_ref, qc * tq, tq, kc * tk, tk, causal,
+                                 offset, has_bias)
+                q2 = q_ref[rows, lanes]
+                do2 = do_ref[rows, lanes]
+                dd = do2.astype(f32) * o_ref[rows, lanes].astype(f32)
+                dv_h, dk_h, dq_h = [], [], []
+                for a in range(per):
+                    delta = jnp.sum(pick(dd, a), axis=-1,
+                                    keepdims=True)               # [tq, 1]
+                    lse = lse_ref[g * per + a, rows][:, None]
+                    s = _dot_t(pick(q2, a), k2) * scale
+                    if add is not None:
+                        s = s + add
+                    p = jnp.exp(s - lse)                         # [tq, tk]
+                    dp = _dot_t(pick(do2, a), v2)
+                    ds = p * (dp - delta)
+                    if db_ref is not None:
+                        dbs[kc] = dbs[kc] + jnp.sum(ds, axis=0,
+                                                    keepdims=True)
+                    ds = ds.astype(q2.dtype)
+                    dv_h.append(_dot(p.astype(do2.dtype).T, do2))
+                    dk_h.append(_dot(ds.T, q2))                  # [tk, W]
+                    dq_h.append(_dot(ds, k2))                    # [tq, W]
+                dv_t = _pick_lanes(masks, dv_h)
+                dk_t = _pick_lanes(masks, dk_h)
+                dq_t = _pick_lanes(masks, dq_h)
+                dv2 = dv_t if dv2 is None else dv2 + dv_t
+                dk2 = dk_t if dk2 is None else dk2 + dk_t
+                dqs[qc] = dq_t if dqs[qc] is None else dqs[qc] + dq_t
+            dk_ref[keys, lanes] = (dk2 * scale).astype(dk_ref.dtype)
+            dv_ref[keys, lanes] = dv2.astype(dv_ref.dtype)
+        for qc in range(nq):
+            dq_ref[qc * tq:(qc + 1) * tq, lanes] = (
+                dqs[qc] * scale).astype(dq_ref.dtype)
+    if db_ref is not None:
+        db_ref[...] = dbs[0] if nk == 1 else jnp.concatenate(dbs, axis=1)
+
+
+def _chunk(n, pref):
+    """The rows of one scores tile along an axis of n: `pref` where it
+    divides n, else the whole axis."""
+    return pref if n % pref == 0 else n
+
+
+def _short_vmem(T, S, HD, itemsize):
+    """Bytes of scoped VMEM one grid step (a batch row) needs, the larger
+    of the two kernels' (read off compiles for a described v5e at
+    T = S = 256 to 1024, H*D = 512, bf16: 4.0 to 42.2 MiB forward, 5.1
+    to 9.5 backward). Forward: four blocks, double-buffered, and eight
+    float32 [T, S] arrays (the row chunks' temporaries are not reused
+    from chunk to chunk). Backward: eight blocks, double-buffered, and
+    some eight [tq, tk] tiles."""
+    fwd = 2 * (2 * T + 2 * S) * HD * itemsize + 8 * 4 * T * S
+    bwd = 2 * (5 * T + 3 * S) * HD * itemsize + 8 * 4 * _chunk(
+        T, _SHORT_BWD_ROWS) * _chunk(S, _SHORT_BWD_KEYS)
+    return max(fwd, bwd)
+
+
+def _short_params(T, S, HD, itemsize):
+    """One batch row a grid step (2, 4 or 8 rows a step read the same
+    time on the chip); VMEM asked for only past _SHORT_VMEM_FREE, half
+    as much again as the step needs."""
+    need = _short_vmem(T, S, HD, itemsize)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=None if need <= _SHORT_VMEM_FREE
+        else 3 * need // 2)
+
+
+def _row_spec(*shape):
+    """BlockSpec of one batch row of a [B, *shape] array."""
+    return pl.BlockSpec((None,) + shape, lambda i: (i,) + (0,) * len(shape))
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def _short_fwd_call(q, k, v, bias, n_heads, causal, scale, interpret,
+                    has_bias):
+    """q [B, T, H*D]; k/v [B, S, H*D]; bias [B, 1, S] float32. Returns
+    (out [B, T, H*D], lse [B, H, T]). Jitted so that a model's many
+    attentions of one shape trace and lower the unrolled body once."""
+    B, T, HD = q.shape
+    S = k.shape[1]
+    return pl.pallas_call(
+        functools.partial(_short_fwd_kernel, causal=causal, scale=scale,
+                          offset=S - T, d=HD // n_heads,
+                          tq=_chunk(T, _SHORT_FWD_ROWS),
+                          has_bias=has_bias),
+        grid=(B,),
+        in_specs=[_row_spec(T, HD), _row_spec(S, HD), _row_spec(S, HD),
+                  _row_spec(1, S)],
+        out_specs=[_row_spec(T, HD), _row_spec(n_heads, T)],
+        out_shape=[jax.ShapeDtypeStruct((B, T, HD), q.dtype),
+                   jax.ShapeDtypeStruct((B, n_heads, T), jnp.float32)],
+        compiler_params=_short_params(T, S, HD, q.dtype.itemsize),
+        name="flash_attention_short_fwd",
+        interpret=interpret,
+    )(q, k, v, bias)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
+def _short_bwd_call(res, g, n_heads, causal, scale, interpret, has_bias):
+    """(dq, dk, dv, db): db [B, 1, S] float32 where the residuals say the
+    bias was being differentiated (`want_db` is not None), else None."""
+    q, k, v, bias, out, lse, want_db = res
+    B, T, HD = q.shape
+    S = k.shape[1]
+    t, s = _row_spec(T, HD), _row_spec(S, HD)
+    out_specs = [t, s, s]
+    out_shape = [jax.ShapeDtypeStruct((B, T, HD), q.dtype),
+                 jax.ShapeDtypeStruct((B, S, HD), k.dtype),
+                 jax.ShapeDtypeStruct((B, S, HD), v.dtype)]
+    if want_db is not None:
+        out_specs.append(_row_spec(1, S))
+        out_shape.append(jax.ShapeDtypeStruct((B, 1, S), jnp.float32))
+    outs = pl.pallas_call(
+        functools.partial(_short_bwd_kernel, causal=causal, scale=scale,
+                          offset=S - T, d=HD // n_heads,
+                          tq=_chunk(T, _SHORT_BWD_ROWS),
+                          tk=_chunk(S, _SHORT_BWD_KEYS),
+                          has_bias=has_bias),
+        grid=(B,),
+        in_specs=[t, s, s, _row_spec(1, S), t, t, _row_spec(n_heads, T)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=_short_params(T, S, HD, q.dtype.itemsize),
+        name="flash_attention_short_bwd",
+        interpret=interpret,
+    )(q, k, v, bias, out, g, lse)
+    return tuple(outs) if want_db is not None else tuple(outs) + (None,)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_short(q, k, v, bias, n_heads, causal, scale, interpret,
+                 has_bias):
+    return _short_fwd_call(q, k, v, bias, n_heads, causal, scale,
+                           interpret, has_bias)[0]
+
+
+def _flash_short_fwd(q, k, v, bias, n_heads, causal, scale, interpret,
+                     has_bias):
+    # symbolic_zeros: each primal says whether it is being differentiated.
+    # The model's bias comes from integer lengths and is not; only a
+    # learnable bias pays for the db output.
+    want_db = jnp.zeros((0,)) if has_bias and bias.perturbed else None
+    q, k, v, bias = q.value, k.value, v.value, bias.value
+    out, lse = _short_fwd_call(q, k, v, bias, n_heads, causal, scale,
+                               interpret, has_bias)
+    return out, (q, k, v, bias, out, lse, want_db)
+
+
+def _flash_short_bwd(n_heads, causal, scale, interpret, has_bias, res, g):
+    if isinstance(g, jax.custom_derivatives.SymbolicZero):
+        g = jnp.zeros(g.shape, g.dtype)
+    dq, dk, dv, db = _short_bwd_call(res, g, n_heads, causal, scale,
+                                     interpret, has_bias)
+    return dq, dk, dv, jnp.zeros_like(res[3]) if db is None else db
+
+
+_flash_short.defvjp(_flash_short_fwd, _flash_short_bwd, symbolic_zeros=True)
+
+
+# ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
+def _bias_rows(bias, B, S):
+    """A key-padding bias ([B,S], [B,1,1,S], [1,1,1,S], [1,S]) as the
+    [B, 1, S] float32 the kernels read; None -> zeros (never read)."""
+    if bias is None:
+        return jnp.zeros((B, 1, S), jnp.float32)
+    br = bias.reshape(bias.shape[0], S).astype(jnp.float32)
+    if br.shape[0] == 1 and B > 1:
+        br = jnp.broadcast_to(br, (B, S))
+    return br.reshape(B, 1, S)
+
+
+def _bias_ok(bias, B, S):
+    return bias is None or tuple(bias.shape) in (
+        (B, S), (B, 1, 1, S), (1, 1, 1, S), (1, S))
+
+
+def supports_short(q, k, v, bias=None):
+    """True if the short-sequence kernel can take q [B,T,H,D], k/v
+    [B,S,H,D] (the op's `bthd` layout): one dtype, heads that group into
+    whole vregs, sublane-aligned lengths, a key-padding bias, and the
+    scores tile of one head plus a step's blocks inside the VMEM asked
+    for."""
+    if not _HAS_PALLAS or q.ndim != 4:
+        return False
+    B, T, H, D = q.shape
+    S = k.shape[1]
+    if k.shape != (B, S, H, D) or v.shape != k.shape \
+            or not (q.dtype == k.dtype == v.dtype):
+        return False
+    W = _short_group(H, D)
+    if not W or (H * D) % W:
+        return False
+    if T % 8 or S % 8 or T < 8 or S < 8 or not _bias_ok(bias, B, S):
+        return False
+    return 3 * _short_vmem(T, S, H * D, q.dtype.itemsize) // 2 \
+        <= _SHORT_VMEM_MAX
+
+
+def flash_attention_bthd(q, k, v, bias=None, causal=False, scale=None,
+                         interpret=False):
+    """The short-sequence kernel: q [B,T,H,D], k/v [B,S,H,D] ->
+    [B,T,H,D], no transpose on either side. Differentiable
+    (custom_vjp)."""
+    if not _HAS_PALLAS:
+        raise NotImplementedError("pallas unavailable")
+    STATS["pallas_calls"] += 1
+    B, T, H, D = q.shape
+    S = k.shape[1]
+    scale = float(scale) if scale is not None else D ** -0.5
+    out = _flash_short(q.reshape(B, T, H * D), k.reshape(B, S, H * D),
+                       v.reshape(B, S, H * D), _bias_rows(bias, B, S), H,
+                       bool(causal), scale, bool(interpret),
+                       bias is not None)
+    return out.reshape(B, T, H, D)
+
+
 def supports(q, k, v, bias=None, block_q=DEFAULT_BLOCK_Q,
              block_k=DEFAULT_BLOCK_K):
     """True if (shapes, bias layout) can run on the Pallas path."""
@@ -615,12 +1036,8 @@ def supports(q, k, v, bias=None, block_q=DEFAULT_BLOCK_Q,
     bq, bk = _choose_blocks(T, S, D, v.shape[-1], block_q, block_k)
     if not bq or not bk or T < 8 or S < 8:
         return False
-    if bias is not None:
-        # accept [B,S] or [B,1,1,S] key-padding bias only
-        bshape = tuple(bias.shape)
-        if bshape not in ((B, S), (B, 1, 1, S), (1, 1, 1, S), (1, S)):
-            return False
-    return True
+    # accept [B,S] or [B,1,1,S] key-padding bias only
+    return _bias_ok(bias, B, S)
 
 
 def _prep(q, k, v, bias, scale, block_q, block_k):
@@ -637,14 +1054,7 @@ def _prep(q, k, v, bias, scale, block_q, block_k):
     qr = q.reshape(B * H, T, D)
     kr = k.reshape(B * H, S, D)
     vr = v.reshape(B * H, S, v.shape[-1])
-    if bias is None:
-        br = jnp.zeros((B, 1, S), jnp.float32)
-    else:
-        br = bias.reshape(bias.shape[0], S).astype(jnp.float32)
-        if br.shape[0] == 1 and B > 1:
-            br = jnp.broadcast_to(br, (B, S))
-        br = br.reshape(B, 1, S)
-    return qr, kr, vr, br, H, scale, block_q, block_k
+    return qr, kr, vr, _bias_rows(bias, B, S), H, scale, block_q, block_k
 
 
 def flash_attention(q, k, v, bias=None, causal=False, scale=None,
@@ -667,8 +1077,12 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
 
 
 def flash_attention_reference(q, k, v, bias=None, causal=False, scale=None,
-                              causal_offset=0):
-    """Unfused jnp reference (for tests)."""
+                              causal_offset=0, layout="bhtd"):
+    """Unfused jnp reference (for tests), in the caller's `layout`."""
+    if layout == "bthd":
+        return flash_attention_reference(
+            q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2), bias,
+            causal, scale, causal_offset).swapaxes(1, 2)
     D = q.shape[-1]
     scale = scale if scale is not None else D ** -0.5
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
@@ -684,30 +1098,81 @@ def flash_attention_reference(q, k, v, bias=None, causal=False, scale=None,
     return jnp.einsum("bhqk,bhkd->bhqd", p, v).astype(q.dtype)
 
 
+def _tiled_dims(q, k, layout):
+    """(T, S) of the arrays as the tiled kernel would see them."""
+    return (q.shape[1], k.shape[1]) if layout == "bthd" \
+        else (q.shape[2], k.shape[2])
+
+
+def picks_short(q, k, v, bias=None, with_lse=False, causal_offset=0,
+                layout="bhtd", interpret=False):
+    """try_flash's first choice, as a static test (the kern registry's
+    probe asks it too): `bthd` arrays the short-sequence kernel can take,
+    at lengths where the chip compiled it and it won (the table above
+    SHORT_MIN_SEQ_LEN; interpret mode skips that gate), from a caller
+    that wants neither the lse nor a shifted diagonal."""
+    if layout != "bthd" or with_lse or causal_offset:
+        return False
+    T, S = _tiled_dims(q, k, layout)
+    wins = SHORT_MIN_SEQ_LEN <= min(T, S) \
+        and max(T, S) <= SHORT_MAX_SEQ_LEN and T % 128 == S % 128 == 0
+    return (interpret or wins) and supports_short(q, k, v, bias=bias)
+
+
+def tiled_min_len(with_lse=False, layout="bhtd"):
+    """The key length from which try_flash hands out the tiled kernel."""
+    return MIN_SEQ_LEN_BTHD if layout == "bthd" and not with_lse \
+        else MIN_SEQ_LEN
+
+
 def try_flash(q, k, v, bias=None, causal=False, scale=None, with_lse=False,
-              causal_offset=0, block_q=None, block_k=None):
+              causal_offset=0, block_q=None, block_k=None, layout="bhtd"):
     """THE dispatch policy, in one place (used by ops/kernels_nn.py,
-    parallel/ring_attention.py, parallel/ulysses.py): returns the Pallas
-    result — `out` or `(out, lse)` with `with_lse` — when the kernel is
-    active, profitable (S >= MIN_SEQ_LEN; interpret mode bypasses the
-    perf gate), and the shapes/bias layout are supported; else None and
-    the caller runs its own fused-XLA fallback. block_q/block_k override
-    the default tile preference (the kern autotuner's knob); _prep still
+    parallel/ring_attention.py, parallel/ulysses.py): returns a Pallas
+    kernel's result (`out`, or `(out, lse)` with `with_lse`) in the
+    caller's `layout`, or None and the caller runs its own fused-XLA
+    composition. The choice is a function of what can be seen here
+    (shapes, layout, dtype), by the crossovers the chip showed (the
+    table above SHORT_MIN_SEQ_LEN):
+
+    - `bthd` arrays the short-sequence kernel can take
+      (supports_short) at lengths where it won (picks_short): that
+      kernel, which reads the layout as it is;
+    - else S >= MIN_SEQ_LEN (MIN_SEQ_LEN_BTHD for the op's `bthd` arrays
+      without `with_lse`) and a legal tiling: the tiled online-softmax
+      kernel (on `bhtd`; `bthd` arrays are transposed for it here);
+    - else None.
+
+    Interpret mode (CPU tests) bypasses the performance gates, not the
+    shape tests. `with_lse` and `causal_offset` callers (ring attention)
+    are served by the tiled kernel only. block_q/block_k override its
+    default tile preference (the kern autotuner's knob); _prep still
     re-legalizes them through _choose_blocks."""
     use_pallas, interpret = active()
     if not use_pallas:
         return None
-    if not interpret and k.shape[2] < MIN_SEQ_LEN:
+    bthd = layout == "bthd"
+    if picks_short(q, k, v, bias, with_lse, causal_offset, layout,
+                   interpret):
+        return flash_attention_bthd(q, k, v, bias=bias, causal=causal,
+                                    scale=scale, interpret=interpret)
+    if not interpret \
+            and _tiled_dims(q, k, layout)[1] < tiled_min_len(with_lse,
+                                                             layout):
         return None
+    if bthd:
+        q, k, v = q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2)
     if not supports(q, k, v, bias=bias):
         return None
     if with_lse:
-        return flash_attention_with_lse(q, k, v, bias=bias, causal=causal,
-                                        scale=scale, interpret=interpret,
-                                        block_q=block_q, block_k=block_k,
-                                        causal_offset=causal_offset)
-    return flash_attention(q, k, v, bias=bias, causal=causal, scale=scale,
-                           block_q=block_q or DEFAULT_BLOCK_Q,
-                           block_k=block_k or DEFAULT_BLOCK_K,
-                           interpret=interpret,
-                           causal_offset=causal_offset)
+        out, lse = flash_attention_with_lse(
+            q, k, v, bias=bias, causal=causal, scale=scale,
+            interpret=interpret, block_q=block_q, block_k=block_k,
+            causal_offset=causal_offset)
+        return (out.swapaxes(1, 2) if bthd else out), lse
+    out = flash_attention(q, k, v, bias=bias, causal=causal, scale=scale,
+                          block_q=block_q or DEFAULT_BLOCK_Q,
+                          block_k=block_k or DEFAULT_BLOCK_K,
+                          interpret=interpret,
+                          causal_offset=causal_offset)
+    return out.swapaxes(1, 2) if bthd else out

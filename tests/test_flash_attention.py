@@ -241,3 +241,214 @@ def test_flash_softmax_dtype_global_knob():
     out2 = fa.flash_attention(q, k, v, interpret=True)
     np.testing.assert_allclose(np.asarray(out2), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the short-sequence kernel: the op's own [B, T, H, D] layout, one tile
+# ---------------------------------------------------------------------------
+_SHORT_CASES = {
+    # name: (B, T, S, H, D, causal, with_bias)
+    "self": (2, 256, 256, 8, 64, False, False),
+    "causal_self_bias": (2, 256, 256, 8, 64, True, True),
+    "cross": (2, 256, 128, 8, 64, False, True),
+    "cross_ragged_keys": (2, 128, 200, 8, 64, False, True),
+    "ragged_queries": (1, 72, 72, 4, 32, True, True),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_SHORT_CASES))
+def test_short_kernel_matches_reference(case, dtype):
+    """flash_attention_bthd (interpret mode) against the unfused
+    reference in float32 on the same (rounded) inputs: the output and
+    the gradients of q, k, v; where there is a bias, its gradient too."""
+    B, T, S, H, D, causal, with_bias = _SHORT_CASES[case]
+    dt = jnp.dtype(dtype)
+    rng = np.random.RandomState(31)
+    q = jnp.asarray(rng.randn(B, T, H, D), dt)
+    k = jnp.asarray(rng.randn(B, S, H, D), dt)
+    v = jnp.asarray(rng.randn(B, S, H, D), dt)
+    w = jnp.asarray(rng.randn(B, T, H, D), jnp.float32)
+    bias = _pad_bias(rng, B, S).reshape(B, 1, 1, S) if with_bias else None
+    assert fa.supports_short(q, k, v, bias)
+
+    def short(q, k, v, b):
+        return fa.flash_attention_bthd(q, k, v, bias=b, causal=causal,
+                                       interpret=True)
+
+    def ref(q, k, v, b):
+        f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+        return fa.flash_attention_reference(*f32, bias=b, causal=causal,
+                                            layout="bthd")
+
+    def loss(fn):
+        return lambda q, k, v, b: jnp.sum(
+            fn(q, k, v, b).astype(jnp.float32) * w)
+
+    tol = dict(rtol=2e-5, atol=2e-5) if dtype == "float32" \
+        else dict(rtol=2e-2, atol=2e-2)
+    out = short(q, k, v, bias)
+    assert out.shape == (B, T, H, D) and out.dtype == dt
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref(q, k, v, bias)), **tol)
+    argnums = (0, 1, 2, 3) if with_bias else (0, 1, 2)
+    g = jax.grad(loss(short), argnums=argnums)(q, k, v, bias)
+    gr = jax.grad(loss(ref), argnums=argnums)(q, k, v, bias)
+    gtol = dict(rtol=1e-4, atol=1e-4) if dtype == "float32" \
+        else dict(rtol=3e-2, atol=3e-2)
+    for a, b in zip(g, gr):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), **gtol)
+
+
+def test_try_flash_picks_the_short_kernel_by_layout_and_length():
+    """The one policy: `bthd` arrays at a key length the short kernel
+    takes go to it (no transpose); `bhtd` arrays and `with_lse` callers
+    keep the tiled kernel's gate."""
+    from paddle_tpu.ops.registry import lowering_for
+    sds = jax.ShapeDtypeStruct
+    q = sds((4, 256, 8, 64), jnp.bfloat16)
+    qt = sds((4, 8, 256, 64), jnp.bfloat16)
+
+    def picked(fn, *a):
+        jaxpr = jax.make_jaxpr(fn)(*a)
+        text = str(jaxpr)
+        return [n for n in ("flash_attention_short_fwd",
+                            "flash_attention_fwd") if n in text], \
+            [e.primitive.name for e in jaxpr.jaxpr.eqns]
+
+    with lowering_for("tpu"):
+        names, prims = picked(lambda q, k, v: fa.try_flash(
+            q, k, v, causal=True, layout="bthd"), q, q, q)
+        assert names == ["flash_attention_short_fwd"]
+        assert "transpose" not in prims
+        # bhtd at 256 and a with_lse caller: the tiled kernel's gates,
+        # so XLA's composition
+        assert fa.try_flash(qt, qt, qt) is None
+        assert fa.try_flash(qt, qt, qt, with_lse=True) is None
+        assert fa.try_flash(q, q, q, with_lse=True, layout="bthd") is None
+        # under the measured range (a tie at 128), and at lengths Mosaic
+        # has not compiled the kernel for (264 keys, 320 x 264): the
+        # composition
+        for t, s_ in ((128, 128), (264, 264), (320, 264), (256, 264)):
+            qq = sds((4, t, 8, 64), jnp.bfloat16)
+            kk = sds((4, s_, 8, 64), jnp.bfloat16)
+            assert fa.try_flash(qq, kk, kk, layout="bthd") is None, (t, s_)
+        mid = sds((4, 384, 8, 64), jnp.bfloat16)
+        assert picked(lambda q, k, v: fa.try_flash(
+            q, k, v, layout="bthd"), mid, q, q)[0] \
+            == ["flash_attention_short_fwd"]
+        # past the short kernel's lengths the op's bthd arrays go to the
+        # tiled kernel from the length the chip showed; bhtd callers
+        # (ulysses, ring attention) keep the gate they had
+        assert fa.SHORT_MAX_SEQ_LEN < fa.MIN_SEQ_LEN_BTHD < fa.MIN_SEQ_LEN
+        assert fa.MIN_SEQ_LEN == 4096
+        long_q = sds((1, fa.MIN_SEQ_LEN_BTHD, 8, 64), jnp.bfloat16)
+        names, prims = picked(lambda q, k, v: fa.try_flash(
+            q, k, v, layout="bthd"), long_q, long_q, long_q)
+        assert names == ["flash_attention_fwd"] and "transpose" in prims
+        long_t = sds((1, 8, fa.MIN_SEQ_LEN_BTHD, 64), jnp.bfloat16)
+        assert fa.try_flash(long_t, long_t, long_t) is None
+        assert fa.try_flash(long_t, long_t, long_t, with_lse=True) is None
+        assert fa.try_flash(long_q, long_q, long_q, with_lse=True,
+                            layout="bthd") is None
+        lse_q = sds((1, 8, fa.MIN_SEQ_LEN, 64), jnp.bfloat16)
+        assert picked(lambda q, k, v: fa.try_flash(q, k, v),
+                      lse_q, lse_q, lse_q)[0] == ["flash_attention_fwd"]
+        out, lse = jax.eval_shape(lambda q, k, v: fa.try_flash(
+            q, k, v, with_lse=True), lse_q, lse_q, lse_q)
+        assert lse.shape == lse_q.shape[:3]
+    with lowering_for("cpu"):
+        assert fa.try_flash(q, q, q, layout="bthd") is None
+
+
+def _jaxpr_avals(jaxpr, skip=("pallas_call",)):
+    """Every intermediate's aval in a jaxpr and its sub-jaxprs, a
+    kernel's own body left out."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield var.aval
+        if eqn.primitive.name in skip:
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _jaxpr_avals(sub, skip)
+
+
+def test_train_step_at_the_cells_widths_keeps_no_scores_tensor():
+    """A traced transformer train step at transformer-base's widths (two
+    layers): 3 accepted flash_attention dispatches a layer, none
+    rejected, and no [B, H, T, S] array anywhere in the step's jaxpr,
+    so neither among the forward's outputs nor the backward's
+    residuals."""
+    from paddle_tpu.core.trace import build_step_fn
+    from paddle_tpu.models import transformer as tfm
+    from paddle_tpu.ops import kern
+    from paddle_tpu.ops.registry import lowering_for
+    B, T, L = 2, 256, 2
+    main, startup = pt.Program(), pt.Program()
+    with pt.program_guard(main, startup):
+        with pt.unique_name.guard():
+            cfg = tfm.TransformerConfig(
+                src_vocab=512, trg_vocab=512, max_len=T, d_model=512,
+                d_inner=2048, n_head=8, n_layer=L, dropout=0.0,
+                fused_qkv=True)
+            feeds, avg_cost, tok = tfm.build_program(cfg, maxlen=T)
+            pt.optimizer.Adam(1e-3).minimize(avg_cost)
+    pt.amp.cast_program_to_bf16(main)
+    scope = pt.Scope()
+    with pt.scope_guard(scope):
+        pt.Executor(pt.CPUPlace()).run(startup)
+    persist = {v.name: jax.ShapeDtypeStruct(scope.get(v.name).shape,
+                                            scope.get(v.name).dtype)
+               for v in main.persistable_vars()}
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)      # noqa: E731
+    feed = {"src": i32(B, T), "trg": i32(B, T), "label": i32(B, T),
+            "src_len": i32(B), "trg_len": i32(B)}
+    step = build_step_fn(main, [avg_cost.name], False, None)
+    per = kern.STATS["by_kernel"].setdefault(
+        "flash_attention", {"accepted": 0, "rejected": 0})
+    before = dict(per)
+    with lowering_for("tpu"):
+        jaxpr = jax.make_jaxpr(step)(persist, feed, jax.random.PRNGKey(0))
+    assert per["accepted"] - before["accepted"] == 3 * L
+    assert per["rejected"] == before["rejected"]
+    text = str(jaxpr)
+    assert "flash_attention_short_fwd" in text
+    assert "flash_attention_short_bwd" in text
+    H = cfg.n_head
+    scores = [a.shape for a in _jaxpr_avals(jaxpr.jaxpr)
+              if len(getattr(a, "shape", ())) >= 3
+              and a.shape[-2:] == (T, T) and a.size >= B * H * T * T]
+    assert not scores, scores
+    # the same walk does find them in the composition's step
+    fa.set_mode("off")
+    try:
+        with lowering_for("tpu"):
+            plain = jax.make_jaxpr(
+                build_step_fn(main, [avg_cost.name], False, None))(
+                    persist, feed, jax.random.PRNGKey(0))
+    finally:
+        fa.set_mode("auto")
+    assert any(len(getattr(a, "shape", ())) == 4
+               and a.shape == (B, H, T, T)
+               for a in _jaxpr_avals(plain.jaxpr))
+
+
+def test_bench_attention_tool_refuses_without_a_chip(tmp_path, monkeypatch,
+                                                     capsys):
+    """tools/bench_attention.py (how the crossover table was measured)
+    times nothing off the chip: no interpret-mode fallback, no `ms`
+    line, no file, exit code 2."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "bench_attention.py")
+    spec = importlib.util.spec_from_file_location("bench_attention", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    monkeypatch.chdir(tmp_path)
+    assert tool.main(["[[2, 32, 32, 2, 16]]", "short,sdpa,tiled", "1"]) == 2
+    said = capsys.readouterr()
+    assert "ms" not in said.out and "not a TPU" in said.err
+    assert not (tmp_path / "chiprun_out").exists()

@@ -1,0 +1,120 @@
+"""The main path's Pallas kernels compiled for a described TPU v5e, at the
+benchmark cell's real shape. Nothing runs: the TPU compiler is installed
+here and compiles for a chip that is described and not attached, so what
+Mosaic or XLA:TPU would refuse on the chip is refused here, at no chip
+time (interpret mode cannot see a mis-tiled slice or a VMEM overrun).
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load libtpu, and every xdist worker imports
+every test file. Keep such tests in this one file.
+"""
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.ops.pallas import flash_attention as fa
+
+# the cell nmt_train_1chip: 128 x 256 tokens, 8 heads of 64, bf16
+B, H, D = 128, 8, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described chip's executables cannot be read back from the
+    # persistent cache: keep them out of it, and the run silent
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _instructions(text):
+    """(name, result type, opcode, line) of every HLO instruction."""
+    pat = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\(?[a-z0-9]+\[[^=]*?)"
+                     r" ([\w\-]+)\(", re.M)
+    return [(m.group(1), m.group(2), m.group(3),
+             text[m.start():text.find("\n", m.start())])
+            for m in pat.finditer(text)]
+
+
+def _dims(type_str):
+    return [tuple(int(d) for d in m.split(",") if d)
+            for m in re.findall(r"\[([\d,]*)\]", type_str)]
+
+
+@pytest.mark.parametrize("T,S,causal", [
+    (256, 256, False), (256, 256, True),         # the cell's attentions
+    # the other lengths try_flash hands the kernel on the chip: both
+    # multiples of 128 in [256, 512], equal or not
+    (512, 512, True), (384, 256, False), (256, 512, False)])
+def test_short_attention_compiles_for_v5e_at_the_cells_shape(one_chip, T, S,
+                                                             causal):
+    """Forward and backward of the short-sequence kernel on [128, T,
+    512] x [128, S, 512] bf16 with the key-padding bias [128, 1, 1, S],
+    as the model hands them over (a free reshape of its [B, T, H*D]
+    arrays)."""
+    sds = jax.ShapeDtypeStruct
+    x = sds((B, T, H * D), jnp.bfloat16, sharding=one_chip)
+    y = sds((B, S, H * D), jnp.bfloat16, sharding=one_chip)
+    bias = sds((B, 1, 1, S), jnp.float32, sharding=one_chip)
+    q_, k_ = sds((B, T, H, D), x.dtype), sds((B, S, H, D), x.dtype)
+    assert fa.picks_short(q_, k_, k_, bias, layout="bthd")
+
+    def step(q, k, v, b, g):
+        def attend(q, k, v):
+            return fa.flash_attention_bthd(
+                q.reshape(B, T, H, D), k.reshape(B, S, H, D),
+                v.reshape(B, S, H, D), bias=b, causal=causal).reshape(
+                    B, T, H * D)
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out,) + vjp(g)
+
+    compiled = jax.jit(step).lower(x, y, y, bias, x).compile()
+    text = compiled.as_text()
+    ins = _instructions(text)
+    calls = [i for i in ins if 'custom_call_target="tpu_custom_call"'
+             in i[3]]
+    names = sorted(i[0] for i in calls)
+    assert len(calls) == 2, names
+    assert any("flash_attention_short_fwd" in n for n in names), names
+    assert any("flash_attention_short_bwd" in n for n in names), names
+    # nothing q/k/v-sized is copied into another layout or transposed
+    # around the kernels (a copy-start is XLA prefetching an operand,
+    # layout unchanged, into on-chip memory: not a pass the kernel forced)
+    big = B * min(T, S) * H * D
+    moved = [(i[0], i[1]) for i in ins
+             if i[2] in ("copy", "transpose")
+             and any(_prod(s) >= big for s in _dims(i[1]))]
+    assert not moved, moved
+    # and no operand or result of a kernel is 64 lanes wide
+    for name, rtype, _, line in calls:
+        start = line.index("operand_layout_constraints={")
+        operands = line[start:line.index("}}", start)]
+        shapes = _dims(rtype) + _dims(operands)
+        assert len(shapes) >= 6, (name, shapes)
+        narrow = [s for s in shapes if s and s[-1] == D]
+        assert not narrow, (name, narrow)
+    # the scores never reach HBM: the whole module's temporaries are
+    # less than one [B, H, T, S] bf16 tensor
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * B * H * T * S
+
+
+def _prod(shape):
+    n = 1
+    for d in shape:
+        n *= d
+    return n
