@@ -1,5 +1,10 @@
 """Driver for the training cells: `Executor.run` on one chip.
 
+What is one model's -- how its program is declared, its parameters, its
+batches, what a step counts as, its plain reference -- is behind the
+module that the configuration's `model` key names (`models/<model>.py`;
+no key means `nmt`). Everything that defines the measurement is here, once.
+
 Set-up builds one object -- the program, its executor and its scope --
 puts the benchmark's seeded weights into the scope, drives it through its
 first three steps on three different batches through the very call the
@@ -14,19 +19,18 @@ import time
 
 import numpy as np
 
-from chipbench import correct, counts, loadgen, trace, weights
-from chipbench.reference import nmt as reference
+from chipbench import correct, trace
 
 
-def _norms(tree):
+def _norms(model, tree):
     import jax
-    return jax.jit(reference.tree_norms)(tree)
+    return jax.jit(model.tree_norms)(tree)
 
 
-def _delta_norms(after, before):
+def _delta_norms(model, after, before):
     import jax
     import jax.numpy as jnp
-    return jax.jit(lambda a, b: reference.tree_norms(
+    return jax.jit(lambda a, b: model.tree_norms(
         {k: a[k].astype(jnp.float32) - b[k].astype(jnp.float32)
          for k in a}))(after, before)
 
@@ -39,27 +43,11 @@ def _host(tree):
 class Trainer:
     """The program under test: one compiled step with its state."""
 
-    def __init__(self, ctx):
+    def __init__(self, ctx, model):
         import paddle_tpu as fluid
-        from paddle_tpu.models import transformer as tfm
-        cfg, traffic = ctx.cfg, ctx.traffic
+        cfg = ctx.cfg
         self.fluid = fluid
-        model = tfm.TransformerConfig(
-            src_vocab=cfg["src_vocab"], trg_vocab=cfg["trg_vocab"],
-            max_len=cfg["max_len"], d_model=cfg["d_model"],
-            d_inner=cfg["d_inner"], n_head=cfg["n_head"],
-            n_layer=cfg["n_layer"], dropout=cfg["dropout"],
-            label_smooth_eps=cfg["label_smooth_eps"],
-            fused_qkv=cfg["fused_qkv"])
-        opt = cfg["optimizer"]
-        self.main, startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(self.main, startup):
-            with fluid.unique_name.guard():
-                _feeds, self.loss, _tok = tfm.build_program(
-                    model, maxlen=traffic["src_len"])
-                fluid.optimizer.Adam(
-                    opt["lr"], beta1=opt["beta1"], beta2=opt["beta2"],
-                    epsilon=opt["epsilon"]).minimize(self.loss)
+        self.main, startup, self.loss = model.build(cfg, ctx.traffic, fluid)
         ctx.mark("program declared")
         # a fixed seed for the program's own generator: every parameter the
         # startup program draws is overwritten below by the benchmark's
@@ -77,14 +65,14 @@ class Trainer:
         with fluid.scope_guard(self.scope):
             self.exe.run(startup)
         ctx.mark("startup program run")
-        self.names = [n for n, _, _ in weights.param_specs(cfg)]
+        self.names = [n for n, _, _ in model.param_specs(cfg)]
         declared = {v.name for v in self.main.all_parameters()}
         if declared != set(self.names):
             raise RuntimeError(
                 "the program's parameters are not the benchmark's: "
                 f"{sorted(declared ^ set(self.names))[:6]}")
-        params = weights.make_params(cfg, ctx.seed,
-                                     cfg["precision"]["parameters"])
+        params = model.make_params(cfg, ctx.seed,
+                                   cfg["precision"]["parameters"])
         for name, arr in params.items():
             self.scope.set(name, arr)
         ctx.mark("weights made")
@@ -100,28 +88,29 @@ class Trainer:
         return {n: self.scope.get(n + suffix) for n in self.names}
 
 
-def first_steps(trainer, ctx, batches):
+def first_steps(trainer, ctx, model, batches):
     """Steps 1-3 through the window's own call; what `correct` compares."""
     cfg = ctx.cfg
     seen = {"loss": []}
     seen["loss"].append(trainer.step(batches[0]))
     ctx.mark("first step")
-    m1 = _host(_norms(trainer.state("_moment1_0")))
+    m1 = _host(_norms(model, trainer.state("_moment1_0")))
     b1 = cfg["optimizer"]["beta1"]
     seen["grad_norm"] = {k: v / (1.0 - b1) for k, v in m1.items()}
     for b in batches[1:3]:
         seen["loss"].append(trainer.step(b))
-    before = weights.make_params(cfg, ctx.seed, cfg["precision"]["parameters"])
-    seen["delta_norm"] = _host(_delta_norms(trainer.state(), before))
+    before = model.make_params(cfg, ctx.seed, cfg["precision"]["parameters"])
+    seen["delta_norm"] = _host(_delta_norms(model, trainer.state(), before))
     return seen
 
 
 def run(ctx):
     import jax
     cfg, traffic = ctx.cfg, ctx.traffic
-    batches = loadgen.make_train_batches(traffic, cfg, ctx.seed)
-    trainer = Trainer(ctx)
-    seen = first_steps(trainer, ctx, batches)
+    model = ctx.man.model(cfg.get("model", "nmt"))
+    batches = model.make_batches(traffic, cfg, ctx.seed)
+    trainer = Trainer(ctx, model)
+    seen = first_steps(trainer, ctx, model, batches)
     ctx.mark("three checked steps")
     warm = traffic.get("warm_steps", 2)
     for i in range(warm):
@@ -148,7 +137,7 @@ def run(ctx):
         trace.stop()
         reduced = trace.reduce(trace.read_xplane(ctx.trace_dir), window_s)
     peak = ctx.memory_peak()
-    tokens = steps * traffic["batch_rows"] * traffic["trg_len"]
+    tokens = steps * model.tokens_per_step(traffic)
     ctx.say(f"window {window_s:.3f} s, {steps} steps, "
             f"{tokens / window_s:.1f} target tokens/s, setup {setup_s:.1f} s,"
             f" {compiles} compile request(s) in the window")
@@ -160,9 +149,9 @@ def run(ctx):
     jax.clear_caches()
     t0 = time.perf_counter()
     dtype = cfg["precision"]["parameters"]
-    params = weights.make_params(cfg, ctx.seed, dtype)
+    params = model.make_params(cfg, ctx.seed, dtype)
     block = traffic.get("reference_block_rows", 32)
-    ref = reference.train_steps(params, cfg, batches[:3], cfg["optimizer"],
+    ref = model.reference_steps(params, cfg, batches[:3], cfg["optimizer"],
                                 "float32", block)
     numbers = correct.train_numbers(seen, ref)
     ctx.say(f"reference: 3 steps in {time.perf_counter() - t0:.1f} s; "
@@ -170,17 +159,18 @@ def run(ctx):
     control = None
     if ctx.control:
         control = {}
-        low = reference.train_steps(params, cfg, batches[:3],
+        low = model.reference_steps(params, cfg, batches[:3],
                                     cfg["optimizer"],
                                     cfg["precision"]["control"], block)
         control[cfg["precision"]["control"]] = correct.train_numbers(low, ref)
-        still = reference.train_steps(
+        still = model.reference_steps(
             params, cfg, batches[:3], dict(cfg["optimizer"], lr=0.0),
             "float32", block)
         control["state_unchanged"] = correct.train_numbers(still, ref)
-        half = reference.train_steps(
+        n_rows = len(next(iter(batches[0].values())))
+        half = model.reference_steps(
             params, cfg, batches[:3], cfg["optimizer"], "float32", block,
-            rows=slice(0, traffic["batch_rows"] // 2))
+            rows=slice(0, n_rows // 2))
         control["half_batch"] = correct.train_numbers(half, ref)
         ctx.say(f"control: {control}")
 
@@ -190,9 +180,9 @@ def run(ctx):
              "device_kind": ctx.devices[0].device_kind,
              "on_chip": ctx.on_chip, "memory_peak_bytes": peak,
              "trace": reduced,
-             "step_flops": counts.train_step_flops(
-                 cfg, traffic["batch_rows"], traffic["src_len"],
-                 traffic["trg_len"])}
+             "step_flops": model.step_flops(cfg, traffic),
+             "kernel_work": model.kernel_work(cfg, traffic)
+             if hasattr(model, "kernel_work") else {}}
     return {"attempted": steps, "failed": 0, "numbers": numbers,
             "end_to_end": {"train_tokens_per_s": tokens / window_s,
                            "setup_s": setup_s},

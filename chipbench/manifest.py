@@ -7,8 +7,13 @@ per-layer metric sits in a file of its own under one of `paths`:
     <path>/traffic/<traffic>.json    found by the cell's `traffic`
     <path>/metrics/<base>.py         found by the metric's name up to its
                                      first dot (`x.sat` and `x.steady`
-                                     share the reader `x.py`)
+                                     share the reader `x.py`); a name
+                                     `<kernel>_roofline` with no file of
+                                     its own shares `kernel_roofline.py`
     <path>/entries/<driver>.py       found by the configuration's `driver`
+    <path>/models/<model>.py         found by the configuration's `model`:
+                                     what a driver needs to know of the
+                                     model it trains (entries/train.py)
 
 A later PR adds files and entries to BENCHMARK.json; it edits none.
 """
@@ -20,6 +25,7 @@ import re
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+ROOFLINE = "_roofline"      # `<kernel>_roofline`: a kernel's share of its own
 
 
 class ManifestError(ValueError):
@@ -58,6 +64,12 @@ def load_module(path):
     return _MODULES[path]
 
 
+def four_chip_quota(n_cells):
+    """How many cells may ask for four chips: a quarter of the cells,
+    rounded down, and one always may (the contract's rule)."""
+    return max(1, n_cells // 4)
+
+
 class Manifest:
     def __init__(self, root=None):
         self.root = root or default_root()
@@ -91,12 +103,23 @@ class Manifest:
 
     def reader(self, metric_name):
         base = metric_name.split(".")[0]
-        return load_module(
-            _find(self.root, self.paths, "metrics", base + ".py"))
+        try:
+            path = _find(self.root, self.paths, "metrics", base + ".py")
+        except ManifestError:
+            if not base.endswith(ROOFLINE):
+                raise
+            # every kernel's share of its roofline is read the same way
+            path = _find(self.root, self.paths, "metrics",
+                         "kernel_roofline.py")
+        return load_module(path)
 
     def driver(self, name):
         return load_module(
             _find(self.root, self.paths, "entries", name + ".py"))
+
+    def model(self, name):
+        return load_module(
+            _find(self.root, self.paths, "models", name + ".py"))
 
     # ---- cross-references, as the tests and every run check them
     def validate(self):
@@ -148,6 +171,9 @@ class Manifest:
                                     "more end-to-end metric")
             if not self.cell_per_layer(w["name"]):
                 raise ManifestError(f"{w['name']}: no per-layer metric")
+        four = [w["name"] for w in doc["workloads"] if w["chips"] == 4]
+        if len(four) > four_chip_quota(len(doc["workloads"])):
+            raise ManifestError(f"too many four-chip cells: {four}")
         if used != set(self.configs):
             raise ManifestError("a configuration is used by no cell")
         for c in doc["configs"]:
@@ -155,4 +181,6 @@ class Manifest:
             if not any(c["file"].startswith(p + "/") for p in self.paths):
                 raise ManifestError(f"{c['name']}: file outside paths")
             self.driver(cfg["driver"])
+            if "model" in cfg:
+                self.model(cfg["model"])
         return self

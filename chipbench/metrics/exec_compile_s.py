@@ -10,4 +10,4 @@ def read(facts, name):
     if not facts.get("on_chip"):
         return None
     phases = program_trace.executor_compiles()
-    return phases[name.split(".", 1)[1]] if phases else None
+    return phases[program_trace.part(name)] if phases else None

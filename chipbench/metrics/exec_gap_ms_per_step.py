@@ -2,8 +2,9 @@
 of it put down to the innermost `pt/executor.*` span of the program open at
 that instant: `.feed_put`, `.prepare`, `.step`, `.fetch_readback`,
 `.release`; `.caller` is idle time under no `pt/executor.run` (the
-trainer's own loop). Their sum is `exec_host_ms_per_step` seen from inside;
-what is left of it lies in `executor.run` outside those children."""
+trainer's own loop). Their sum is the window's wall time per step less
+`train_device_step_ms`, but for what lies in `executor.run` outside those
+children."""
 from chipbench import program_trace
 
 
@@ -16,4 +17,4 @@ def read(facts, name):
                                       *tr["window"])
     if idle is None:
         return None
-    return 1e3 * idle.get(name.split(".", 1)[1], 0.0) / facts["steps"]
+    return 1e3 * idle.get(program_trace.part(name), 0.0) / facts["steps"]

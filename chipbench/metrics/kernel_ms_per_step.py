@@ -1,6 +1,7 @@
 """Layer: kernels. Device time per step of the Mosaic kernel of that name
-(`pl.pallas_call(name=...)`): `.layer_norm_fwd`, `.layer_norm_bwd`. Found
-by name, so a second kernel in the step does not disturb it."""
+(`pl.pallas_call(name=...)`): `.layer_norm_fwd`, `.layer_norm_bwd`,
+`.flash_attention_short_fwd`, `.flash_attention_short_bwd`. Found by name,
+so another kernel in the step does not disturb it."""
 from chipbench import program_trace
 
 
@@ -9,5 +10,5 @@ def read(facts, name):
     if not facts.get("on_chip") or not tr or not any(tr["chips"]):
         return None
     secs = program_trace.kernel_seconds(tr["chips"]).get(
-        name.split(".", 1)[1])
+        program_trace.part(name))
     return 1e3 * secs / facts["steps"] if secs else None

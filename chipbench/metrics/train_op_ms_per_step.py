@@ -11,4 +11,4 @@ def read(facts, name):
     got = program_trace.scoped_seconds(__file__)
     if got is None:
         return None
-    return 1e3 * got[1].get(name.split(".", 1)[1], 0.0) / facts["steps"]
+    return 1e3 * got[1].get(program_trace.part(name), 0.0) / facts["steps"]

@@ -36,6 +36,14 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _SCOPE = re.compile(r"^(transpose\()?(jvp\()?([\w.\-]+)\)*$")
 
 
+def part(name):
+    """What a reader takes from its metric's name: the part after the first
+    dot (`train_op_ms_per_step.mul` -> `mul`). A further dot starts a tag
+    the reader does not read; it tells apart two entries of the same thing
+    for different cells (`train_op_ms_per_step.mul.second_model`)."""
+    return name.split(".")[1]
+
+
 # ------------------------------------------------------------ the file
 def find_root(start):
     d = os.path.dirname(os.path.abspath(start))
@@ -137,62 +145,21 @@ def with_parents(spans):
     return [spans[i] + (parent[i],) for i in range(len(spans))]
 
 
-def innermost_timeline(spans, lo, hi, outside="caller"):
-    """[(start, end, name)] covering [lo, hi]: at each instant the entry
-    layer's span that opened last among those open (the innermost), named
-    by what follows its first dot; `outside` where none is open."""
-    ivs = [(s, s + d, n) for n, s, d, *_ in spans
-           if n.startswith(ENTRY) and s + d > lo and s < hi]
-    cuts = sorted({lo, hi} | {t for a, b, _ in ivs for t in (a, b)
-                              if lo < t < hi})
-    ivs.sort()
-    out, j, live = [], 0, []
-    for a, b in zip(cuts, cuts[1:]):
-        while j < len(ivs) and ivs[j][0] <= a:
-            live.append(ivs[j])
-            j += 1
-        live = [x for x in live if x[1] > a]
-        name = max(live)[2].split(".", 1)[1] if live else outside
-        if out and out[-1][2] == name and out[-1][1] == a:
-            out[-1] = (out[-1][0], b, name)
-        else:
-            out.append((a, b, name))
-    return out
-
-
-def idle_intervals(ops, lo, hi):
-    """The stretches of [lo, hi] that no (name, start, dur) op covers."""
-    gaps, end = [], lo
-    for _, s, d in sorted(ops, key=lambda e: e[1]):
-        if s > end:
-            gaps.append((end, min(s, hi)))
-        end = max(end, s + d)
-        if end >= hi:
-            break
-    if hi > end:
-        gaps.append((end, hi))
-    return [(a, b) for a, b in gaps if b > a]
-
-
 def idle_by_span(ops, spans, lo, hi):
     """{span suffix: idle seconds}: every instant of device-idle time
     inside [lo, hi] put down to the innermost entry-layer span open at
-    that instant ("caller": none open; "run": inside the parent but in
-    none of its children). One gap that runs through several spans is
-    split among them. None where the program has no entry-layer span."""
-    if not any(n.startswith(ENTRY) for n, *_ in spans):
+    that instant, named by what follows its first dot ("caller": none
+    open; "run": inside the parent but in none of its children). One gap
+    that runs through several spans is split among them
+    (chipbench/trace.py::name_gaps). None where the program has no
+    entry-layer span."""
+    from chipbench.trace import idle_gaps, name_gaps
+    entry = [(n.split(".", 1)[1], s, d) for n, s, d, *_ in spans
+             if n.startswith(ENTRY)]
+    if not entry:
         return None
-    timeline = innermost_timeline(spans, lo, hi)
-    out, j = defaultdict(float), 0
-    for a, b in idle_intervals(ops, lo, hi):
-        while j < len(timeline) and timeline[j][1] <= a:
-            j += 1
-        k = j
-        while k < len(timeline) and timeline[k][0] < b:
-            s, e, name = timeline[k]
-            out[name] += min(b, e) - max(a, s)
-            k += 1
-    return dict(out)
+    gaps = idle_gaps([(s, d) for _, s, d in ops], lo, hi)
+    return dict(name_gaps(gaps, entry, top=None, outside="caller"))
 
 
 # ----------------------------------------------------------- the scopes

@@ -4,6 +4,7 @@ The arithmetic works on plain lists of (name, start, duration) in seconds,
 so that it can be tested on a small synthetic list; `read_xplane` is the
 one function that touches the profiler's file.
 """
+import bisect
 import glob
 import os
 import re
@@ -42,22 +43,28 @@ def idle_gaps(intervals, t0=None, t1=None):
     return gaps
 
 
-def name_gaps(gaps, spans, top=10):
-    """Attribute each gap to the host span (name, start, duration) that
-    covers most of it (the innermost on a tie), sum by name, longest first.
-    A gap no span overlaps is `unattributed`."""
+def name_gaps(gaps, spans, top=10, outside="unattributed"):
+    """Put every instant of each gap down to the innermost host span
+    (name, start, duration) open at that instant -- of those open, the one
+    that opened last -- and sum by name, longest first (the `top` longest;
+    None: all). A gap that runs through several spans is split among them;
+    what no span covers is `outside`."""
+    spans = sorted((s, s + d, name) for name, s, d in spans)
+    starts = [s for s, _, _ in spans]
+    longest = max((e - s for s, e, _ in spans), default=0.0)
     by = defaultdict(float)
     for gs, gd in gaps:
-        best, best_cover, best_len = "unattributed", 0.0, None
-        for name, ss, sd in spans:
-            cover = min(gs + gd, ss + sd) - max(gs, ss)
-            if cover <= 0:
-                continue
-            if cover > best_cover + 1e-12 or (
-                    abs(cover - best_cover) <= 1e-12
-                    and (best_len is None or sd < best_len)):
-                best, best_cover, best_len = name, cover, sd
-        by[best] += gd
+        ge = gs + gd
+        near = [x for x in spans[bisect.bisect_left(starts, gs - longest):
+                                 bisect.bisect_left(starts, ge)]
+                if x[1] > gs]
+        cuts = sorted({gs, ge} | {t for s, e, _ in near for t in (s, e)
+                                  if gs < t < ge})
+        for a, b in zip(cuts, cuts[1:]):
+            inside = [x for x in near if x[0] <= a and x[1] >= b]
+            name = max(inside, key=lambda x: (x[0], -x[1]))[2] \
+                if inside else outside
+            by[name] += b - a
     return sorted(([k, v] for k, v in by.items()), key=lambda x: -x[1])[:top]
 
 
@@ -92,12 +99,27 @@ def stop():
     jax.profiler.stop_trace()
 
 
-def read_xplane(trace_dir, span_prefix="cb/"):
+SPAN_PREFIXES = ("cb/", "pt/")
+
+
+def span_name(event_name):
+    """A host event's name without its prefix where it is a span of the
+    benchmark (`cb/`) or of the program (`pt/`), else None."""
+    for prefix in SPAN_PREFIXES:
+        if event_name.startswith(prefix):
+            return event_name[len(prefix):]
+    return None
+
+
+def read_xplane(trace_dir):
     """{"chips": [{"ops": [(name, start, dur)], "modules": [...]}],
     "spans": [(name, start, dur)]} in seconds on the file's own clock.
     Device planes are those named /device:TPU:n; `ops` is their "XLA Ops"
     line, `modules` their "XLA Modules" line. `spans` are the host-side
-    TraceAnnotations whose name starts with `span_prefix`."""
+    TraceAnnotations whose name starts with one of SPAN_PREFIXES, the
+    prefix taken off: the benchmark's own `cb/` spans (`window`,
+    `exe.run`) and the program's `pt/` spans (`executor.fetch_readback`),
+    so that an idle gap is named by the innermost thing the host was in."""
     from jax.profiler import ProfileData
     files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
@@ -118,9 +140,9 @@ def read_xplane(trace_dir, span_prefix="cb/"):
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(span_prefix):
-                        spans.append((e.name[len(span_prefix):],
-                                      e.start_ns * 1e-9,
+                    name = span_name(e.name)
+                    if name is not None:
+                        spans.append((name, e.start_ns * 1e-9,
                                       e.duration_ns * 1e-9))
     chips.sort(key=lambda c: c["name"])
     return {"chips": chips, "spans": spans}

@@ -5,6 +5,7 @@ file. All on the CPU; nothing here describes a TPU topology."""
 import io
 import json
 import os
+import re
 import sys
 from contextlib import redirect_stdout
 
@@ -30,6 +31,11 @@ NEW = ([f"exec_gap_ms_per_step.{s}" for s in (
        + [f"exec_compile_s.{s}" for s in (
             "trace", "lower", "backend", "cache_load")]
        + ["exec_compiled_programs"])
+# PR 29's: the attention kernels' times and their shares of the roofline
+ADDED = ["kernel_ms_per_step.flash_attention_short_fwd",
+         "kernel_ms_per_step.flash_attention_short_bwd",
+         "flash_attention_short_fwd_roofline",
+         "flash_attention_short_bwd_roofline"]
 
 # two steps of 10 ms on the device, 2 ms apart, in a window of 30 ms; the
 # host is inside executor.run the whole time but for 0.5 ms between steps
@@ -271,12 +277,51 @@ def test_readers_on_the_synthetic_trace(traced_root):
     assert read("kernel_ms_per_step.layer_norm_bwd") == pytest.approx(1.5)
     # off the chip every one of them, being a time, says nothing
     facts["on_chip"] = False
-    for name in NEW:
+    for name in NEW + ADDED:
         if name != "exec_compiled_programs":
             assert read(name) is None, name
 
 
-@pytest.mark.parametrize("name", NEW)
+def test_a_kernels_share_of_its_roofline(traced_root):
+    """Floor over traced time, by the kernel the metric's name gives; the
+    synthetic trace has layer_norm_fwd for 1 ms and _bwd for 3 ms."""
+    man = manifest.Manifest(traced_root)
+    work = {"layer_norm_fwd": [(0.0, 819e9 * 1e-4, 2)],      # 0.1 ms, twice
+            "layer_norm_bwd": [(197e12 * 5e-4, 1.0, 1),      # MXU-bound
+                               (1.0, 819e9 * 2.5e-4, 4)]}    # HBM-bound
+    facts = {"kind": "train", "on_chip": True, "steps": 2,
+             "device_kind": "TPU v5 lite", "kernel_work": work}
+    read = man.reader("layer_norm_fwd_roofline").read
+    assert read is man.reader("layer_norm_bwd_roofline.a_tag").read
+    # per step 0.2 ms of floor; the kernel took 1 ms over 2 steps
+    assert read(facts, "layer_norm_fwd_roofline") == pytest.approx(
+        100 * 2 * 0.2e-3 / 1e-3)
+    assert read(facts, "layer_norm_bwd_roofline.a_tag") == pytest.approx(
+        100 * 2 * 1.5e-3 / 3e-3)
+    # a kernel that did not run, a model that counts nothing for it, a run
+    # off the chip: nothing, never 0
+    assert read(facts, "flash_attention_short_fwd_roofline") is None
+    assert read(dict(facts, kernel_work={}), "layer_norm_fwd_roofline") \
+        is None
+    assert read({k: v for k, v in facts.items() if k != "kernel_work"},
+                "layer_norm_fwd_roofline") is None
+    assert read(dict(facts, on_chip=False), "layer_norm_fwd_roofline") \
+        is None
+    with pytest.raises(manifest.ManifestError):
+        man.reader("no_such_reader")
+
+
+def test_a_tag_after_the_second_dot_is_not_read(traced_root):
+    man = manifest.Manifest(traced_root)
+    facts = {"kind": "train", "on_chip": True, "steps": 2}
+    for name in ("train_op_ms_per_step.mul", "kernel_ms_per_step."
+                 "layer_norm_bwd", "exec_gap_ms_per_step.feed_put",
+                 "train_phase_ms_per_step.backward"):
+        read = man.reader(name).read
+        assert read(facts, name + ".second_model") == read(facts, name) > 0
+
+
+@pytest.mark.parametrize("name", NEW + ADDED)
 def test_a_new_reader_with_nothing_to_read_returns_nothing(
         tmp_path, monkeypatch, name):
     """No trace file, and a program with no compile log or text (the
@@ -285,7 +330,10 @@ def test_a_new_reader_with_nothing_to_read_returns_nothing(
     root = tiny.make_root(tmp_path)
     monkeypatch.delattr(telemetry, "compile_log")
     monkeypatch.delattr(telemetry, "compiled_text")
-    facts = {"kind": "train", "on_chip": True, "steps": 3}
+    work = {"flash_attention_short_fwd": [(1e9, 1e6, 18)],
+            "flash_attention_short_bwd": [(1e9, 1e6, 18)]}
+    facts = {"kind": "train", "on_chip": True, "steps": 3,
+             "device_kind": "TPU v5 lite", "kernel_work": work}
     assert manifest.Manifest(root).reader(name).read(facts, name) is None
 
 
@@ -301,18 +349,42 @@ def test_a_program_without_text_leaves_the_scoped_metrics_out(
     assert man.reader(name).read(facts, name) == pytest.approx(0.5)
 
 
+def perf_md_layers():
+    """The first column of the table of PERF.md section 3."""
+    with open(os.path.join(REPO, "PERF.md")) as f:
+        text = f.read()
+    section = text[text.index("\n## 3."):text.index("\n## 4.")]
+    rows = re.findall(r"^\| ([^|]+?) \|", section, re.M)
+    return {r for r in rows if r != "Layer" and not r.startswith("---")}
+
+
 def test_the_manifest_validates_with_the_new_entries():
+    """PR 27's 21 entries and PR 29's 4 are there, whatever else is and
+    wherever they stand: a later PR appends entries and cells."""
     man = manifest.Manifest(REPO).validate()
-    doc = man.doc
-    assert [m["name"] for m in doc["per_layer"]][-len(NEW):] == NEW
-    layers = {m["layer"] for m in doc["per_layer"][:7]}
-    for m in doc["per_layer"][-len(NEW):]:
-        assert m["workloads"] == ["nmt_train_1chip"]
+    by_name = {m["name"]: m for m in man.doc["per_layer"]}
+    assert set(NEW + ADDED) <= set(by_name)
+    layers = perf_md_layers()
+    assert {"kernels", "model step", "device"} <= layers
+    for name in NEW + ADDED:
+        m = by_name[name]
+        assert "nmt_train_1chip" in m["workloads"]
+        assert m["moves"] == ("setup_s" if name.startswith("exec_compile")
+                              else "train_tokens_per_s")
+    for m in man.doc["per_layer"]:
         assert m["layer"] in layers, "a layer spelled as PERF.md spells it"
-        assert m["moves"] == ("setup_s" if m["name"].startswith(
-            "exec_compile") else "train_tokens_per_s")
+    for name in ADDED:
+        assert by_name[name]["layer"] == "kernels"
+        assert by_name[name]["source"] == "device_trace"
+    for name in ADDED[2:]:
+        assert (by_name[name]["unit"], by_name[name]["better"]) == (
+            "%", "higher")
+    for gone in ("ln_kernel_ms_per_step", "exec_host_ms_per_step"):
+        assert gone not in by_name
+        with pytest.raises(manifest.ManifestError):
+            man.reader(gone)
     reported = {m["name"] for m in man.cell_per_layer("nmt_train_1chip")}
-    assert set(NEW) <= reported
+    assert set(NEW + ADDED) <= reported
 
 
 def test_cpu_traced_run_reports_the_count_and_no_time(tmp_path):
