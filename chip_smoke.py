@@ -235,6 +235,16 @@ def chip_kernel_cases():
                                 {}, ()),
         "int8_quant": ((f32(1024 * 256).at[:256].set(0.0),),
                        {"block_size": 256}, ()),
+        # 2048 tokens, top-2 of 16 experts of which 4-7 are held here
+        "moe_expert_ffn": ((f32(2048, 256).astype(jnp.bfloat16),
+                            jnp.asarray(rng.randint(0, 16, size=(2048, 2)),
+                                        jnp.int32),
+                            jnp.asarray(rng.uniform(0.2, 0.8, (2048, 2)),
+                                        jnp.float32),
+                            (0.05 * f32(4, 256, 384)).astype(jnp.bfloat16),
+                            (0.05 * f32(4, 256, 384)).astype(jnp.bfloat16),
+                            (0.05 * f32(4, 384, 256)).astype(jnp.bfloat16)),
+                           {"first_expert": 4}, ()),
     }
 
 

@@ -16,6 +16,30 @@ __all__ = ["bf16_guard", "cast_program_to_bf16", "cast_params_to_bf16"]
 # dtype-sensitive ops that must keep fp32 params (norm stats/scales)
 _KEEP_FP32_PARAM_SUFFIX = ("batch_norm", "layer_norm", "group_norm")
 
+# ... and, whatever a variable is called, what it is used for: one that
+# an op reads or writes through one of these slots keeps float32 (the
+# norms' scales and shifts: their kernels compute the statistics in
+# float32; a router's weight and the routing weights it gives: a router
+# that rounds its scores picks other experts). A name can be anything a
+# ParamAttr says.
+_KEEP_FP32_SLOTS = {
+    "batch_norm": ("Scale", "Bias"), "layer_norm": ("Scale", "Bias"),
+    "group_norm": ("Scale", "Bias"), "rms_norm": ("Scale",),
+    "moe_route": ("Weight", "TopkW"),
+}
+
+
+def _fp32_by_use(program):
+    """Names of the variables some op reads or writes through a slot of
+    _KEEP_FP32_SLOTS."""
+    keep = set()
+    for block in program.blocks:
+        for op in block.ops:
+            for slot in _KEEP_FP32_SLOTS.get(op.type, ()):
+                keep.update(op.inputs.get(slot, ()))
+                keep.update(op.outputs.get(slot, ()))
+    return keep
+
 
 def cast_program_to_bf16(program, keep_io_fp32=True):
     """Rewrite var dtypes float32→bfloat16 for Parameters and activations.
@@ -28,11 +52,14 @@ def cast_program_to_bf16(program, keep_io_fp32=True):
     compute in fp32). Returns the modified program (in place, like the
     ref float16 transpiler)."""
     from .core.framework import Parameter
+    by_use = _fp32_by_use(program)
     for block in program.blocks:
         for var in block.vars.values():
             if var.dtype != "float32":
                 continue
             if keep_io_fp32 and var.is_data:
+                continue
+            if var.name in by_use:
                 continue
             if isinstance(var, Parameter):
                 # norm scales stay fp32 (kernels compute stats in fp32)
@@ -100,11 +127,14 @@ def bf16_guard(program=None):
         for n in op.input_names():
             if isinstance(all_vars.get(n), Parameter):
                 touched.add(n)
+    by_use = _fp32_by_use(program)
     for blk in program.blocks:
         for var in blk.vars.values():
             if var.name not in touched or var.dtype != "float32":
                 continue
             if var.is_data:
+                continue
+            if var.name in by_use:
                 continue
             if isinstance(var, Parameter):
                 if any(s in var.name for s in _KEEP_FP32_PARAM_SUFFIX):

@@ -87,6 +87,23 @@ def _fetch_var(name, scope=None, return_numpy=True):
     return as_numpy(val) if return_numpy else val
 
 
+def _count_marked(marks, values):
+    """The read-back values of a program's marked variables
+    (Program.mark_counter) into the telemetry registry. The mark is the
+    gate, as a compile is the compile log's: this does not wait for
+    telemetry.enabled()."""
+    prefixes = set()
+    for (name, (_, kind)), value in zip(marks.items(), values):
+        value = np.asarray(value).reshape(-1)[0].item()
+        if kind == "gauge":
+            _tm.gauge(name).set(value)
+        else:
+            _tm.counter(name).inc(value)
+        prefixes.add(name.split(".")[0])
+    for prefix in prefixes:
+        _tm.counter(prefix + ".steps").inc()
+
+
 def _feed_signature(feed):
     return tuple(sorted((k, tuple(np.shape(v)), str(np.asarray(v).dtype) if not hasattr(v, "dtype") else str(v.dtype))
                         for k, v in feed.items()))
@@ -453,6 +470,13 @@ class Executor:
                     feed.setdefault(n, v)
         fetch_list = list(fetch_list or [])
         fetch_names = [f.name if hasattr(f, "name") else f for f in fetch_list]
+        # values the program marked as counters (Program.mark_counter)
+        # ride behind the caller's fetches and are taken off again after
+        # the read-back (_finalize_record); no mark, nothing added
+        marks = getattr(program, "_device_counters", None)
+        n_fetch = len(fetch_names)
+        if marks:
+            fetch_names = fetch_names + [v for v, _ in marks.values()]
         if is_test is None:
             is_test = getattr(program, "_is_test", False)
 
@@ -720,6 +744,7 @@ class Executor:
             "check": check, "is_test": bool(is_test), "seed": seed,
             "return_numpy": return_numpy, "flight": flight,
             "tm_on": tm_on, "dt": dt, "deferred": k_async > 0,
+            "marks": marks, "n_fetch": n_fetch,
         }
         if k_async > 0:
             from .pipeline_exec import PendingStep, StepWindow
@@ -789,21 +814,28 @@ class Executor:
                     rec["pre_state"], fetch_names, rec["is_test"],
                     rec["seed"], rec["step_val"], detail=detail)
 
-        if rec["return_numpy"]:
+        marked = ()
+        if rec["marks"]:
+            n = rec["n_fetch"]
+            fetches, marked = fetches[:n], fetches[n:]
+        if rec["return_numpy"] or marked:
             t_rb = time.perf_counter()
-            with _tm.span("executor.fetch_readback", n=len(fetches),
-                          step=rec["step"]):
-                out = [np.asarray(f) for f in fetches]
+            with _tm.span("executor.fetch_readback",
+                          n=len(fetches) + len(marked), step=rec["step"]):
+                if rec["return_numpy"]:
+                    fetches = [np.asarray(f) for f in fetches]
+                marked = [np.asarray(f) for f in marked]
             if tm_on:
                 _tm.histogram("executor.fetch_readback_seconds").observe(
                     time.perf_counter() - t_rb)
-            if flight is not None and out \
-                    and getattr(out[0], "size", 0) == 1 \
-                    and np.asarray(out[0]).dtype.kind in "fV":
+            if marked:
+                _count_marked(rec["marks"], marked)
+            if rec["return_numpy"] and flight is not None and fetches \
+                    and getattr(fetches[0], "size", 0) == 1 \
+                    and np.asarray(fetches[0]).dtype.kind in "fV":
                 flight.annotate(
-                    loss=float(np.asarray(out[0]).astype(
+                    loss=float(np.asarray(fetches[0]).astype(
                         np.float32).ravel()[0]))
-            return out
         return fetches
 
     def _scan_oom_hook(self, e, steps):

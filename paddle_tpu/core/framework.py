@@ -196,6 +196,7 @@ class Program:
         self._backward_sections = []   # filled by core/backward.py
         self._lr_schedulers = []
         self._is_test = False
+        self._device_counters = {}     # see mark_counter
 
     # -- structure ---------------------------------------------------------
     def global_block(self):
@@ -219,6 +220,20 @@ class Program:
 
     def all_parameters(self):
         return self.global_block().all_parameters()
+
+    def mark_counter(self, var, name, kind="counter"):
+        """Mark a scalar the step computes on the device (an expert
+        layer's load, say) as telemetry metric `name`: every
+        `Executor.run` of this program brings it back with the fetches,
+        inside `executor.fetch_readback`, and adds it to
+        `telemetry.counter(name)` (`kind="gauge"`: sets
+        `telemetry.gauge(name)`), counting the runs in
+        `telemetry.counter("<prefix>.steps")`, the prefix being the
+        name up to its first dot. A program with no mark pays nothing."""
+        if kind not in ("counter", "gauge"):
+            raise ValueError(f"kind {kind!r}: 'counter' or 'gauge'")
+        self._device_counters[name] = (var.name, kind)
+        self._bump_version()
 
     def list_vars(self):
         for b in self.blocks:
@@ -256,6 +271,7 @@ class Program:
         p.random_seed = self.random_seed
         p._lr_schedulers = list(self._lr_schedulers)
         p._is_test = for_test or self._is_test
+        p._device_counters = dict(self._device_counters)
         for b in self.blocks:
             nb = Block(p, b.idx, b.parent_idx)
             for name, v in b.vars.items():

@@ -37,6 +37,8 @@ __all__ = [
     "swish", "hard_swish", "image_resize", "image_resize_short", "resize_bilinear",
     "resize_nearest", "grid_sampler", "affine_channel", "shuffle_channel",
     "scaled_dot_product_attention", "multi_head_attention",
+    "flash_attention", "rms_norm", "rotary_embedding", "short_conv",
+    "swiglu", "moe_route", "moe_expert_ffn",
     "add_position_encoding", "lod_reset", "im2sequence",
     "logsumexp", "bilinear_tensor_product", "isfinite", "cos_sim",
     "unique_with_counts_stub", "maxout", "pixel_shuffle",
@@ -1389,6 +1391,29 @@ def scaled_dot_product_attention(queries, keys, values, num_heads=1,
     return out
 
 
+def flash_attention(q, k, v, attn_bias=None, causal=False, scale=None,
+                    use_flash=True, name=None):
+    """The attention itself over heads kept as [B, T, H, Dh] (the
+    `bthd` layout of the `flash_attention` op): softmax(q k^T * scale +
+    attn_bias) v -> [B, T, H, Dv]. k and v may have fewer heads than q
+    (grouped-query attention: H / KVH query heads that follow one another
+    share each key-value head)."""
+    helper = LayerHelper("multi_head_attention", name=name)
+    out = helper.create_variable_for_type_inference(
+        q.dtype, tuple(q.shape[:3]) + (v.shape[3],))
+    wshape = (q.shape[0], q.shape[2], q.shape[1], k.shape[1])
+    wvar = helper.create_variable_for_type_inference(q.dtype, wshape, True)
+    ins = {"Q": [q], "K": [k], "V": [v]}
+    if attn_bias is not None:
+        ins["Mask"] = [attn_bias]
+    helper.append_op("flash_attention" if use_flash else "scaled_dot_product_attention",
+                     ins, {"Out": [out], "Weights": [wvar]},
+                     {"causal": causal,
+                      "scale": scale or int(q.shape[3]) ** -0.5,
+                      "layout": "bthd"})
+    return out
+
+
 def multi_head_attention(queries, keys, values, attn_bias=None, d_key=64,
                          d_value=64, d_model=512, n_head=8, dropout_rate=0.0,
                          causal=False, param_attr=None, name=None,
@@ -1457,23 +1482,116 @@ def multi_head_attention(queries, keys, values, attn_bias=None, d_key=64,
     q = reshape(q, [0, 0, n_head, d_key])
     k = reshape(k, [0, 0, n_head, d_key])
     v = reshape(v, [0, 0, n_head, d_value])
-    helper = LayerHelper("multi_head_attention", name=name)
-    out = helper.create_variable_for_type_inference(q.dtype, q.shape)
-    wshape = (q.shape[0], n_head, q.shape[1], k.shape[1])
-    wvar = helper.create_variable_for_type_inference(q.dtype, wshape, True)
-    ins = {"Q": [q], "K": [k], "V": [v]}
-    if attn_bias is not None:
-        ins["Mask"] = [attn_bias]
-    helper.append_op("flash_attention" if use_flash else "scaled_dot_product_attention",
-                     ins, {"Out": [out], "Weights": [wvar]},
-                     {"causal": causal, "scale": d_key ** -0.5,
-                      "layout": "bthd"})
+    out = flash_attention(q, k, v, attn_bias=attn_bias, causal=causal,
+                          use_flash=use_flash, name=name)
     out = reshape(out, [0, 0, n_head * d_value])
     if dropout_rate:
         out = dropout(out, dropout_rate,
                       dropout_implementation="upscale_in_train")
     return fc(out, d_model, num_flatten_dims=2, param_attr=param_attr,
               bias_attr=False, name=f"{name}_o" if name else None)
+
+
+# ---------------------------------------------------------------------------
+# decoder-only language-model blocks
+# ---------------------------------------------------------------------------
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """y = x * rsqrt(mean(x^2, last axis) + epsilon) * w, statistics and
+    weight in float32. The last axis is the hidden size, or one head's
+    width for the per-head norm of q and k over [B, T, H, Dh]."""
+    helper = LayerHelper("rms_norm", name=name)
+    w = helper.create_parameter(param_attr, shape=[int(input.shape[-1])],
+                                dtype="float32",
+                                default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype, input.shape)
+    helper.append_op("rms_norm", {"X": [input], "Scale": [w]}, {"Y": [out]},
+                     {"epsilon": epsilon})
+    return out
+
+
+def rotary_embedding(input, theta=10000.0, name=None):
+    """Rotary positions (rotate-half form) over [B, T, H, Dh]; position t
+    is the index along axis 1."""
+    return _same_shape_out(LayerHelper("rotary_embedding", name=name), input,
+                           "rotary_embedding", {"theta": float(theta)})
+
+
+def short_conv(input, filter_size=3, param_attr=None, name=None):
+    """Causal depthwise convolution along T of [B, T, C], filter [C,
+    filter_size], no bias: out[t] = sum_j w[:, j] * x[t - (K-1) + j]."""
+    helper = LayerHelper("short_conv", name=name)
+    w = helper.create_parameter(
+        param_attr, shape=[int(input.shape[-1]), int(filter_size)],
+        dtype=input.dtype)
+    return _same_shape_out(helper, input, "short_conv",
+                           extra_inputs={"Filter": [w]})
+
+
+def swiglu(x, y, name=None):
+    """silu(x) * y, the gate of a gated linear unit."""
+    return _same_shape_out(LayerHelper("swiglu", name=name), x, "swiglu",
+                           extra_inputs={"Y": [y]})
+
+
+def moe_route(input, num_experts, k, use_expert_bias=True,
+              norm_topk_prob=True, routed_scaling_factor=1.0,
+              param_attr=None, name=None):
+    """The router of a sparse expert layer over [..., H]: sigmoid scores
+    of ALL `num_experts`, the k experts chosen by score + bias, their
+    weights the chosen scores renormalised and scaled; float32
+    throughout. Returns (topk_idx [..., k] int32, topk_w [..., k]
+    float32). The bias is a persistable variable that is no Parameter
+    (it only selects; no gradient, no optimizer state), `<name>.bias`,
+    zero until something sets it."""
+    helper = LayerHelper("moe_route", name=name)
+    w = helper.create_parameter(
+        param_attr, shape=[int(input.shape[-1]), int(num_experts)],
+        dtype="float32", default_initializer=NormalInitializer(0.0, 0.02))
+    ins = {"X": [input], "Weight": [w]}
+    if use_expert_bias:
+        bias = helper.create_global_variable(
+            [int(num_experts)], "float32", persistable=True,
+            name=f"{helper.name}.bias")
+        helper.set_variable_initializer(bias, ConstantInitializer(0.0))
+        ins["Bias"] = [bias]
+    lead = tuple(input.shape[:-1])
+    idx = helper.create_variable_for_type_inference("int32", lead + (k,),
+                                                    True)
+    tw = helper.create_variable_for_type_inference("float32", lead + (k,))
+    helper.append_op("moe_route", ins, {"TopkIdx": [idx], "TopkW": [tw]},
+                     {"k": int(k), "norm_topk_prob": bool(norm_topk_prob),
+                      "routed_scaling_factor": float(routed_scaling_factor)})
+    return idx, tw
+
+
+def moe_expert_ffn(input, topk_idx, topk_w, experts_held, first_expert,
+                   intermediate_size, param_attr=None, name=None):
+    """The expert layer's part of THIS chip: experts `first_expert ..
+    first_expert + experts_held - 1` of a layer routed over all the
+    model's experts. out[n] = sum over the chosen experts of token n that
+    are held here of topk_w * (silu(x W1[e]) * (x W3[e])) W2[e]; what the
+    absent experts would add is left out, and no token is dropped.
+    Returns (out, local_pairs, max_expert_pairs): the (token, expert)
+    pairs computed here and the fullest held expert's, int32 scalars."""
+    helper = LayerHelper("moe_expert_ffn", name=name)
+    H, F, E = int(input.shape[-1]), int(intermediate_size), int(experts_held)
+    init = NormalInitializer(0.0, 0.02)
+    w1 = helper.create_parameter(_sub_attr(param_attr, "w1"), [E, H, F],
+                                 input.dtype, default_initializer=init)
+    w3 = helper.create_parameter(_sub_attr(param_attr, "w3"), [E, H, F],
+                                 input.dtype, default_initializer=init)
+    w2 = helper.create_parameter(_sub_attr(param_attr, "w2"), [E, F, H],
+                                 input.dtype, default_initializer=init)
+    out = helper.create_variable_for_type_inference(input.dtype, input.shape)
+    pairs = helper.create_variable_for_type_inference("int32", (), True)
+    fullest = helper.create_variable_for_type_inference("int32", (), True)
+    helper.append_op(
+        "moe_expert_ffn",
+        {"X": [input], "TopkIdx": [topk_idx], "TopkW": [topk_w],
+         "W1": [w1], "W3": [w3], "W2": [w2]},
+        {"Out": [out], "LocalPairs": [pairs], "MaxExpertPairs": [fullest]},
+        {"first_expert": int(first_expert)})
+    return out, pairs, fullest
 
 
 def add_position_encoding(input, alpha=1.0, beta=1.0, name=None):

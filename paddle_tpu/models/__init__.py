@@ -23,3 +23,4 @@ from . import faster_rcnn
 from . import dcgan
 from . import seq2seq
 from . import resnet_with_preprocess
+from . import lfm2_moe

@@ -10,4 +10,5 @@ from . import kernels_struct
 from . import kernels_vision
 from . import kernels_control
 from . import kernels_extra
+from . import kernels_moe
 from .registry import KERNELS, get_kernel, has_kernel

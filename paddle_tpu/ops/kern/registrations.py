@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from ..pallas import flash_attention as fa
 from ..pallas import layer_norm as ln
 from ..pallas import embedding as emb
+from ..pallas import grouped_matmul as gm
 from . import decode_attention as da
 from . import quant
 from .registry import KernelSpec, register
@@ -369,4 +370,39 @@ register(KernelSpec(
     config_ok=_q_config_ok,
     example=_q_example,
     note="shared int8 blockwise quantize (EQuARX wire format)",
+))
+
+
+# ---------------------------------------------------------- moe_expert_ffn
+def _moe_space(x, idx, tw, w1, *a, **kw):
+    return [{"tile_rows": t} for t in (256, 512, 1024)]
+
+
+def _moe_example(rng):
+    N, H, F, E, k = 32, 16, 24, 2, 2
+    x = jnp.asarray(rng.standard_normal((N, H)), jnp.float32)
+    idx = jnp.asarray(rng.randint(0, 4, size=(N, 1)), jnp.int32)
+    idx = jnp.concatenate([idx, (idx + 1) % 4], axis=1)
+    tw = jnp.asarray(rng.uniform(0.2, 0.8, size=(N, k)), jnp.float32)
+    w1 = jnp.asarray(rng.standard_normal((E, H, F)) * 0.3, jnp.float32)
+    w3 = jnp.asarray(rng.standard_normal((E, H, F)) * 0.3, jnp.float32)
+    w2 = jnp.asarray(rng.standard_normal((E, F, H)) * 0.3, jnp.float32)
+    return (x, idx, tw, w1, w3, w2), {"first_expert": 1}
+
+
+register(KernelSpec(
+    name="moe_expert_ffn",
+    fn=gm.try_expert_ffn,
+    reference=gm.expert_ffn_reference,
+    probe=gm.supports,
+    tol=(2e-5, 2e-5),
+    op_types=("moe_expert_ffn",),
+    signature=lambda x, idx, tw, w1, *a, **kw: (
+        _shape(x) + _shape(idx)[1:] + _shape(w1)),
+    tune_space=_moe_space,
+    config_ok=lambda cfg, *a, **kw: not cfg or cfg.get("tile_rows", 0) % 16
+    == 0,
+    example=_moe_example,
+    note="no-drop expert FFN of the experts held here: grouped products "
+         "over pairs sorted by expert, fwd+bwd (custom_vjp)",
 ))

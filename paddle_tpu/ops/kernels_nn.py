@@ -777,6 +777,13 @@ def _sdpa(ctx, ins, attrs):
     # the score-tensor HBM traffic; fp16 would overflow (65504 max, and
     # a -1e9 pad mask → -inf), so everything else computes in f32
     cdt = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
+    h_axis = 2 if bthd else 1
+    group = q.shape[h_axis] // k.shape[h_axis] if q.ndim == 4 else 1
+    if group > 1:
+        # grouped-query attention: each key-value head serves `group`
+        # query heads that follow one another
+        k = jnp.repeat(k, group, axis=h_axis)
+        v = jnp.repeat(v, group, axis=h_axis)
     if bthd:
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(cdt) \
             * jnp.asarray(scale, cdt)
@@ -936,3 +943,64 @@ def _flash_attention(ctx, ins, attrs):
     # no kernel wins at this shape (the measured table is in PERF.md
     # section 6, PR 28), or none can lower here: one composition, in _sdpa
     return _sdpa(ctx, ins, attrs)
+
+
+# ---------------------------------------------------------------------------
+# decoder-only language-model blocks: RMSNorm, rotary positions, the gated
+# short convolution's depthwise filter, SwiGLU's gate
+# ---------------------------------------------------------------------------
+@kernel("rms_norm")
+def _rms_norm(ctx, ins, attrs):
+    """y = x * rsqrt(mean(x^2, last axis) + eps) * scale, in float32; the
+    last axis is whatever the caller normalises over (the hidden size, or
+    one head's 64 for the per-head norm of q and k)."""
+    x = _x(ins)
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    y = xf * jax.lax.rsqrt(ms + attrs.get("epsilon", 1e-5))
+    scale = _opt(ins, "Scale")
+    if scale is not None:
+        y = y * scale.astype(jnp.float32)
+    return {"Y": [y.astype(x.dtype)]}
+
+
+@kernel("rotary_embedding")
+def _rotary_embedding(ctx, ins, attrs):
+    """Rotary positions in the rotate-half form over X [B, T, H, D]:
+    position t turns the pair (x[i], x[i + D/2]) by t * theta^(-2i/D).
+    The angles are float32 whatever X is."""
+    x = _x(ins)
+    T, D = x.shape[1], x.shape[-1]
+    half = D // 2
+    inv = jnp.power(jnp.float32(attrs.get("theta", 10000.0)),
+                    -jnp.arange(half, dtype=jnp.float32) * 2.0 / D)
+    ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.cos(ang)[None, :, None, :]
+    sin = jnp.sin(ang)[None, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return {"Out": [out.astype(x.dtype)]}
+
+
+@kernel("short_conv")
+def _short_conv(ctx, ins, attrs):
+    """Causal depthwise convolution along T of X [B, T, C] with Filter
+    [C, K]: out[t, c] = sum_j Filter[c, j] * x[t - (K - 1) + j, c], zeros
+    before the sequence. K shifted products; float32 inside."""
+    x, filt = _x(ins), ins["Filter"][0]
+    K = filt.shape[1]
+    T = x.shape[1]
+    xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
+    ff = filt.astype(jnp.float32)
+    out = sum(xf[:, j:j + T, :] * ff[:, j] for j in range(K))
+    return {"Out": [out.astype(x.dtype)]}
+
+
+@kernel("swiglu")
+def _swiglu(ctx, ins, attrs):
+    """Out = silu(X) * Y, the gate of a gated linear unit."""
+    x, y = autocast(_x(ins), ins["Y"][0])
+    xf = x.astype(jnp.float32)
+    out = xf * jax.nn.sigmoid(xf) * y.astype(jnp.float32)
+    return {"Out": [out.astype(x.dtype)]}

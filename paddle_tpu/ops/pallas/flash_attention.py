@@ -295,6 +295,13 @@ def _dot(a, b):
                                preferred_element_type=jnp.float32)
 
 
+def _kv_row(group):
+    """Row of k / v for row b of q where `group` query heads share one
+    key-value head: q is [B * H, T, D] and k, v are [B * H / group, S, D],
+    so b = batch * H + head reads batch * H / group + head // group."""
+    return (lambda b: b) if group == 1 else (lambda b: b // group)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
@@ -359,6 +366,7 @@ def _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
     S = k.shape[1]
     DV = v.shape[-1]
     H = n_heads
+    kv = _kv_row(BH // k.shape[0])
     n_k = S // block_k
     grid = (BH, T // block_q, n_k)
     out, lse = pl.pallas_call(
@@ -368,8 +376,8 @@ def _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, DV), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, block_k, D), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((None, block_k, DV), lambda b, i, j: (kv(b), j, 0)),
             pl.BlockSpec((None, 1, block_k), lambda b, i, j: (b // H, 0, j)),
         ],
         out_specs=[
@@ -434,8 +442,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
 def _dkv_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
                 *refs, causal, scale, n_q, offset,
                 p_dtype=jnp.float32, has_bias=True):
-    """Grid (B*H, n_kv, n_q), q innermost: recompute p^T block-wise,
-    accumulate dk/dv in VMEM scratch. With has_bias, db_ref [1, bk] is
+    """Grid (B*KVH, n_kv, group*n_q), q innermost: recompute p^T
+    block-wise, accumulate dk/dv in VMEM scratch over the q blocks of the
+    `group` query heads of this key-value head (group = 1: one head's). With has_bias, db_ref [1, bk] is
     the per-head bias gradient row (d s / d bias = 1): its block index
     is constant in the innermost q dim, so it stays resident in VMEM and
     accumulates in-place across the q steps; without it, neither the
@@ -444,10 +453,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
         dk_ref, dv_ref, db_ref, dk_acc, dv_acc = refs
     else:
         (dk_ref, dv_ref, dk_acc, dv_acc), db_ref = refs, None
-    k_idx, q_idx = pl.program_id(1), pl.program_id(2)
+    # the innermost axis walks the q blocks of every query head that
+    # shares this key-value head: n_q steps a head, one head after another
+    k_idx, step = pl.program_id(1), pl.program_id(2)
+    q_idx = step % n_q
     bk, bq = k_ref.shape[0], q_ref.shape[0]
 
-    @pl.when(q_idx == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -477,7 +489,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
         ds = ds_f.astype(q_ref.dtype)
         dk_acc[...] = dk_acc[...] + _dot(ds.T, q_ref[...]) * scale
 
-    @pl.when(q_idx == n_q - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _flush():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -488,9 +500,11 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
               has_bias=True):
     q, k, v, bias, out, lse = res
     BH, T, D = q.shape
-    S = k.shape[1]
+    BKV, S = k.shape[:2]
     DV = v.shape[-1]
     H = n_heads
+    group = BH // BKV
+    kv = _kv_row(group)
     do = g.astype(jnp.float32)
     # delta_i = rowsum(dO * O): the softmax-normalization correction term.
     # An lse cotangent folds in here: d s_ij gets p_ij * g_lse_i, i.e.
@@ -509,8 +523,8 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
         grid=(BH, n_q, n_k),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, DV), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((None, block_k, D), lambda b, i, j: (kv(b), j, 0)),
+            pl.BlockSpec((None, block_k, DV), lambda b, i, j: (kv(b), j, 0)),
             pl.BlockSpec((None, 1, block_k), lambda b, i, j: (b // H, 0, j)),
             pl.BlockSpec((None, block_q, DV), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
@@ -530,26 +544,40 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
         pl.BlockSpec((None, block_k, DV), lambda b, j, i: (b, j, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((BH, S, D), k.dtype),
-        jax.ShapeDtypeStruct((BH, S, DV), v.dtype),
+        jax.ShapeDtypeStruct((BKV, S, D), k.dtype),
+        jax.ShapeDtypeStruct((BKV, S, DV), v.dtype),
     ]
     if has_bias:
         out_specs.append(
             pl.BlockSpec((None, 1, block_k), lambda b, j, i: (b, 0, j)))
-        out_shape.append(jax.ShapeDtypeStruct((BH, 1, S), jnp.float32))
+        out_shape.append(jax.ShapeDtypeStruct((BKV, 1, S), jnp.float32))
+    # grid row b is a key-value head; step i of the innermost axis is q
+    # block i % n_q of query head b * group + i // n_q
+    if group == 1:
+        def qrow(b, i):
+            return b, i
+    else:
+        def qrow(b, i):
+            return b * group + i // n_q, i % n_q
+    KVH = H // group
     outs = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, scale=scale, n_q=n_q,
                           offset=S - T + causal_offset, p_dtype=p_dtype,
                           has_bias=has_bias),
-        grid=(BH, n_k, n_q),
+        grid=(BKV, n_k, group * n_q),
         in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((None, block_q, D),
+                         lambda b, j, i: qrow(b, i) + (0,)),
             pl.BlockSpec((None, block_k, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((None, block_k, DV), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((None, 1, block_k), lambda b, j, i: (b // H, 0, j)),
-            pl.BlockSpec((None, block_q, DV), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((None, 1, block_q), lambda b, j, i: (b, 0, i)),
-            pl.BlockSpec((None, 1, block_q), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((None, 1, block_k),
+                         lambda b, j, i: (b // KVH, 0, j)),
+            pl.BlockSpec((None, block_q, DV),
+                         lambda b, j, i: qrow(b, i) + (0,)),
+            pl.BlockSpec((None, 1, block_q),
+                         lambda b, j, i: (qrow(b, i)[0], 0, qrow(b, i)[1])),
+            pl.BlockSpec((None, 1, block_q),
+                         lambda b, j, i: (qrow(b, i)[0], 0, qrow(b, i)[1])),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -567,7 +595,7 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
         return dq, dk, dv, None
     dk, dv, db_bh = outs
     # per-head bias-grad rows → the [B, 1, S] layout the kernel consumed
-    db = db_bh.reshape(BH // H, H, S).sum(axis=1, keepdims=True)
+    db = db_bh.reshape(BH // H, KVH, S).sum(axis=1, keepdims=True)
     return dq, dk, dv, db
 
 
@@ -1033,6 +1061,10 @@ def supports(q, k, v, bias=None, block_q=DEFAULT_BLOCK_Q,
         return False
     B, H, T, D = q.shape
     S = k.shape[2]
+    # grouped-query attention: k and v may have fewer heads, each shared
+    # by H / KVH query heads that follow one another
+    if k.shape[1] != v.shape[1] or H % k.shape[1]:
+        return False
     bq, bk = _choose_blocks(T, S, D, v.shape[-1], block_q, block_k)
     if not bq or not bk or T < 8 or S < 8:
         return False
@@ -1052,8 +1084,8 @@ def _prep(q, k, v, bias, scale, block_q, block_k):
     if not block_q or not block_k:
         raise NotImplementedError("seq len must tile")
     qr = q.reshape(B * H, T, D)
-    kr = k.reshape(B * H, S, D)
-    vr = v.reshape(B * H, S, v.shape[-1])
+    kr = k.reshape(B * k.shape[1], S, D)
+    vr = v.reshape(B * v.shape[1], S, v.shape[-1])
     return qr, kr, vr, _bias_rows(bias, B, S), H, scale, block_q, block_k
 
 
@@ -1085,6 +1117,9 @@ def flash_attention_reference(q, k, v, bias=None, causal=False, scale=None,
             causal, scale, causal_offset).swapaxes(1, 2)
     D = q.shape[-1]
     scale = scale if scale is not None else D ** -0.5
+    group = q.shape[1] // k.shape[1]
+    if group > 1:       # grouped-query attention: share each k / v head
+        k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
     s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * scale
     if bias is not None:
         b = bias.reshape(bias.shape[0], 1, 1, k.shape[2])

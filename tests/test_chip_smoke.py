@@ -69,7 +69,7 @@ def test_kernels_phase_runs_every_registered_kernel(smoke, watch,
     info = smoke.phase_kernels(smoke.example_kernel_cases(), watch)
     assert sorted(info) == ["decode_attend", "dequant_attend_int8",
                             "flash_attention", "int8_quant",
-                            "layer_norm", "lookup_pool"]
+                            "layer_norm", "lookup_pool", "moe_expert_ffn"]
 
 
 def test_kernels_phase_fails_on_a_rejected_shape(smoke, watch):

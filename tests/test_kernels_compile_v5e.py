@@ -118,3 +118,75 @@ def _prod(shape):
     for d in shape:
         n *= d
     return n
+
+
+def _kernel_names(compiled):
+    return [i[0] for i in _instructions(compiled.as_text())
+            if 'custom_call_target="tpu_custom_call"' in i[3]]
+
+
+def test_tiled_attention_with_8_key_value_heads_compiles_at_8192(one_chip):
+    """The cell lfm2_train_1chip's attention: [2, 8192, 32 x 64] queries
+    over 8 key-value heads, causal, bf16, forward and backward through
+    the tiled kernels (the op's `bthd` arrays, transposed for them)."""
+    Bq, T, Hq, KV = 2, 8192, 32, 8
+    sds = jax.ShapeDtypeStruct
+    q = sds((Bq, T, Hq, D), jnp.bfloat16, sharding=one_chip)
+    kv = sds((Bq, T, KV, D), jnp.bfloat16, sharding=one_chip)
+    assert not fa.picks_short(q, kv, kv, None, layout="bthd")
+    assert fa.supports(sds((Bq, Hq, T, D), q.dtype),
+                       sds((Bq, KV, T, D), q.dtype),
+                       sds((Bq, KV, T, D), q.dtype))
+
+    def step(q, k, v, g):
+        def attend(q, k, v):
+            return fa.flash_attention(
+                q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
+                causal=True).swapaxes(1, 2)
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out,) + vjp(g)
+
+    compiled = jax.jit(step).lower(q, kv, kv, q).compile()
+    names = _kernel_names(compiled)
+    for kernel in ("flash_attention_fwd", "flash_attention_dq",
+                   "flash_attention_dkv"):
+        assert any(kernel in n for n in names), names
+    # dk, dv come out at 8 heads, and no [B, H, T, T] scores reach HBM
+    _, dq, dk, dv = jax.eval_shape(step, q, kv, kv, q)
+    assert dk.shape == dv.shape == (Bq, T, KV, D) and dq.shape == q.shape
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < Bq * Hq * T * T * 2 // 8
+
+
+def test_grouped_expert_products_compile_at_the_cells_widths(one_chip):
+    """`moe_expert_ffn` of one expert layer of lfm2_train_1chip: 16384
+    tokens, top-4 of 64, 8 experts of 2048 x 1536 held, bf16, forward and
+    backward: the four grouped kernels, the experts' whole matrices as
+    blocks in VMEM."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    N, Hd, F, E, k = 16384, 2048, 1536, 8, 4
+    sds = jax.ShapeDtypeStruct
+
+    def S(shape, dtype):
+        return sds(shape, dtype, sharding=one_chip)
+
+    def loss(x, tw, w1, w3, w2, idx):
+        out, counts = gm.expert_ffn(x, idx, tw, w1, w3, w2, first_expert=0)
+        return jnp.sum(out.astype(jnp.float32)), counts
+
+    compiled = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
+            S((N, Hd), jnp.bfloat16), S((N, k), jnp.float32),
+            S((E, Hd, F), jnp.bfloat16), S((E, Hd, F), jnp.bfloat16),
+            S((E, F, Hd), jnp.bfloat16), S((N, k), jnp.int32)).compile()
+    names = _kernel_names(compiled)
+    for kernel in ("moe_gmm_swiglu", "moe_gmm", "moe_swiglu_bwd",
+                   "moe_tgmm"):
+        assert any(kernel in n for n in names), names
+    # the buffer is the worst case's (every token's 4 pairs here) and the
+    # module keeps a handful of buffers of it, not one a product
+    rows = gm.buffer_tiles(N, k, E, gm.DEFAULT_TILE_ROWS) \
+        * gm.DEFAULT_TILE_ROWS
+    assert rows == N * k + E * gm.DEFAULT_TILE_ROWS
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 12 * rows * Hd * 2

@@ -434,6 +434,24 @@ SPECS["flash_attention"] = S(
 SPECS["add_position_encoding"] = S({"X": (2, 5, 4)},
                                    {"alpha": 1.0, "beta": 1.0})
 
+# decoder-only language-model blocks (float32 inside, as the norms)
+SPECS["rms_norm"] = S({"X": (2, 3, 8), "Scale": (8,)}, {"epsilon": 1e-5},
+                      f32=True)
+SPECS["rotary_embedding"] = S({"X": (1, 5, 2, 8)}, {"theta": 100.0},
+                              f32=True)
+SPECS["short_conv"] = S({"X": (2, 6, 4), "Filter": (4, 3)}, f32=True)
+SPECS["swiglu"] = S({"X": (3, 4), "Y": (3, 4)}, f32=True)
+# sparse experts: the selection is piecewise constant (the generator's
+# scores stand apart), the routing weights and the experts are smooth
+SPECS["moe_route"] = S(
+    {"X": (5, 6), "Weight": (6, 4), "Bias": (4,)},
+    {"k": 2, "norm_topk_prob": True, "routed_scaling_factor": 1.0},
+    diff=["X", "Weight"], f32=True)
+SPECS["moe_expert_ffn"] = S(
+    {"X": (6, 4), "TopkIdx": ("int", (6, 2), 4), "TopkW": ("pos", (6, 2)),
+     "W1": (2, 4, 5), "W3": (2, 4, 5), "W2": (2, 5, 4)},
+    {"first_expert": 1}, f32=True)
+
 # recurrent (weights + input grads through lax.scan)
 SPECS["lstm"] = S(
     {"Input": (2, 5, 4), "WeightIH": (4, 12), "WeightHH": (3, 12)},
