@@ -15,9 +15,9 @@ body is skipped. Device time follows the pairs routed here.
     expert e:  y = (silu(x W1[e]) * (x W3[e])) W2[e]
     out[n]   = sum over the pairs (n, e) held here of w[n, e] * y
 
-Four kernels, one grid step a tile, the expert's whole weight matrix
-one block (it changes only where the expert changes, so the weights
-are read once an expert):
+Four kernels over the buffer, one grid step a tile, the expert's whole
+weight matrix one block (it changes only where the expert changes, so
+the weights are read once an expert):
 
     moe_gmm_swiglu   g = silu(xs W1[e]) * (xs W3[e])
     moe_gmm          out = sum_i lhs_i rhs_i[e]  (rhs transposed or not):
@@ -30,10 +30,20 @@ are read once an expert):
                      inside the used tiles
     moe_tgmm         dW[e] = sum over e's tiles of lhs^T rhs
 
-The gathers between the token order and the sorted buffer are XLA's:
-into the buffer a tile at a time over the tiles in use, back to the
-tokens one gather over every (token, choice), a pair that is not held
-reading a filled-in zero (PERF.md section 7).
+and a fifth on the way back to the tokens:
+
+    moe_combine      out[n] = sum of the buffer rows of token n's held
+                     pairs (times their weights): one grid step a block
+                     of tokens. The pairs are ranked in token order, so
+                     the rows a block needs of one expert are one range
+                     of the buffer; the kernel brings each range in by
+                     DMA and places its rows by a 0/1 (or weight) matrix
+                     on the MXU. Its cost follows the rows in use and
+                     the output, not the N * k (token, choice) pairs, of
+                     which the other chips' experts hold most.
+
+The gather into the buffer is XLA's, a tile at a time over the tiles in
+use (PERF.md section 7).
 """
 import functools
 
@@ -93,8 +103,15 @@ def make_plan(topk_idx, first_expert, n_held, tile_rows):
     tile_expert [T]   the held expert (0-based) of each tile
     n_active [1]      tiles in use, at least one an expert
     counts [n_held]   pairs of each held expert
+    block_off [(B + 1) * n_held]
+                      the pairs are ranked in token order, so the rows of
+                      expert e that belong to the tokens of block b (B
+                      blocks of `_combine_tiling`'s tokens) are the one
+                      range [off[b, e], off[b + 1, e]) of the buffer:
+                      what `moe_combine` walks
     """
     N, k = topk_idx.shape
+    tb = _combine_tiling(N, tile_rows)[0]
     T = buffer_tiles(N, k, n_held, tile_rows)
     M = T * tile_rows
     local = topk_idx.astype(jnp.int32).reshape(-1) - first_expert
@@ -113,9 +130,13 @@ def make_plan(topk_idx, first_expert, n_held, tile_rows):
     tile_expert = jnp.minimum(
         jnp.sum(jnp.arange(T)[:, None] >= tile_end[None, :], axis=1),
         n_held - 1).astype(jnp.int32)
+    block_end = jnp.minimum((jnp.arange(-(-N // tb)) + 1) * tb, N) * k - 1
+    block_off = row_start[None, :] + jnp.concatenate(
+        [jnp.zeros((1, n_held), jnp.int32), ranks[block_end] + 1])
     return {"dest": dest.reshape(N, k), "src": src,
             "tile_expert": tile_expert,
-            "n_active": tile_end[-1:].astype(jnp.int32), "counts": counts}
+            "n_active": tile_end[-1:].astype(jnp.int32), "counts": counts,
+            "block_off": block_off.reshape(-1).astype(jnp.int32)}
 
 
 # ---------------------------------------------------------------------------
@@ -296,18 +317,216 @@ def _gather_rows(x, plan, tm):
                              jnp.zeros((src.shape[0], x.shape[1]), x.dtype))
 
 
-def _combine(rows, dest, weights=None):
-    """Buffer rows [M, H] back to tokens: out[n] = sum_k of the rows of
-    token n's held pairs (times `weights` [N, k]). A pair whose expert
-    is not held points one past the buffer (dest = M) and reads a zero
-    the gather fills in, without a copy: nine pairs in ten at eight
-    experts held of 64, and a row copied for each of them cost four
-    times the whole gather (4.8 ms against 1.2)."""
-    picked = jnp.take(rows, dest, axis=0, mode="fill", fill_value=0)
-    picked = picked.astype(jnp.float32)
-    if weights is not None:
-        picked = picked * weights[..., None]
-    return jnp.sum(picked, axis=1)
+def _combine_tiling(n_tokens, tm):
+    """(token block, rows a chunk, chunks a product, row alignment) of
+    `moe_combine`, from the shapes. A chunk is what one DMA brings in: it
+    starts on a whole sublane tile of the buffer, lies inside the tiles in
+    use (so it is no longer than a tile) and is short, because the MXU's
+    passes follow the rows staged and a block's range of one expert is
+    short (16 rows of 256 tokens at 8 experts held of 64, top-4). A
+    product selects from 256 staged rows; the token block is a whole
+    number of lane groups (the per-choice arrays have the tokens along
+    the lanes). On the chip at 16384 tokens x 2048 bf16, 8245 pairs held
+    (PERF.md section 6, PR 31), unweighted / weighted ms: 256 tokens,
+    chunks of 16: 0.27 / 0.49; chunks of 32: 0.28 / 0.55; of 64: 0.33 /
+    0.72; 128 tokens: 0.30 / 0.50; 512 tokens, chunks of 32: 0.29 / 0.67;
+    of 128: 0.47 / 1.22; 1024 tokens: 0.48 / 1.22; the gather this
+    replaced: 3.92 / 3.92."""
+    align = 16 if tm % 16 == 0 else 8 if tm % 8 == 0 else 1
+    chunk = max(align, min(16, tm) // align * align)
+    return (256 if n_tokens > _LANES else _LANES), chunk, \
+        max(1, 256 // chunk), align
+
+
+def _panel(width):
+    """Columns of one pass over the tokens: the whole row up to 2048, so
+    that the staged rows and the float32 accumulator stay a few MB."""
+    if width <= 2048:
+        return width
+    return next((w for w in range(2048, 0, -_LANES) if width % w == 0),
+                width)
+
+
+def _select_dot(sel, rows, weighted):
+    """sel [tb, K] float32, one non-zero a column at most (1, or the
+    pair's weight) x rows [K, H] -> [tb, H] float32, each product exact
+    and the weight a float32: a bf16 row times a 0/1 is one MXU pass; a
+    float32 weight is the sum of three bf16 pieces, each piece times a
+    bf16 row exact in float32; float32 rows take the MXU's full
+    precision."""
+    if rows.dtype == jnp.float32:
+        return jax.lax.dot_general(
+            sel, rows, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    out = None
+    for _ in range(3 if weighted else 1):
+        piece = sel.astype(rows.dtype)
+        part = _dot(piece, rows)
+        out = part if out is None else out + part
+        sel = sel - piece.astype(jnp.float32)
+    return out
+
+
+def _combine_kernel(off, na, *refs, weighted, n_held, k, tm, chunk, group,
+                    align, slots):
+    """One token block a grid step. Step b starts the DMAs of block b + 1
+    (each held expert's range of the buffer, a chunk a DMA, into the
+    other half of the staging rows) and then selects block b's rows out
+    of its own half: sel[t, r] is the weight of the pair of token t whose
+    row is staged at r, found by comparing the block's `dest` with the
+    buffer positions of the staged rows."""
+    dest_ref, *w_ref, rows_ref, out_ref, stage_ref, acc_ref, sem, meta, \
+        count = refs
+    b = pl.program_id(1)
+    active = na[0] * tm
+    K = group * chunk
+    width = stage_ref.shape[1]
+    col = pl.multiple_of(pl.program_id(0) * width, width)
+
+    def copy(slot, start):
+        return pltpu.make_async_copy(
+            rows_ref.at[pl.ds(pl.multiple_of(start, align), chunk),
+                        pl.ds(col, width)],
+            stage_ref.at[pl.ds(pl.multiple_of(slot * chunk, chunk), chunk),
+                         :],
+            sem.at[slot])
+
+    def stage(blk):
+        base = (blk % 2) * slots
+
+        def expert(e, n):
+            lo = off[blk * n_held + e]
+            hi = off[(blk + 1) * n_held + e]
+            lo_al = lo // align * align
+            n_chunks = jnp.where(hi > lo, (hi - lo_al + chunk - 1) // chunk,
+                                 0)
+
+            def one(c, n):
+                first = lo_al + c * chunk
+                # the last chunk in use is read from inside the tiles in
+                # use: past them the buffer was never written
+                start = jnp.minimum(first, active - chunk)
+                slot = base + n
+                copy(slot, start).start()
+                meta[3 * slot] = start
+                meta[3 * slot + 1] = jnp.maximum(lo, first)
+                meta[3 * slot + 2] = jnp.minimum(hi, first + chunk)
+                return n + 1
+
+            return jax.lax.fori_loop(0, n_chunks, one, n)
+
+        count[blk % 2] = jax.lax.fori_loop(0, n_held, expert, 0)
+
+    @pl.when(b == 0)
+    def _():
+        # a staged row that no pair owns is multiplied by 0: it has to be
+        # finite, so the rows no DMA has filled yet are zeros
+        stage_ref[...] = jnp.zeros_like(stage_ref)
+        stage(b)
+
+    @pl.when(b + 1 < pl.num_programs(1))
+    def _():
+        stage(b + 1)
+
+    def columns(ref):
+        """[k, tb], the tokens along the lanes (how [N, k] is dense in
+        HBM) -> [tb, 128], choice j of a token in lane j."""
+        per_choice = ref[...]
+        tb = per_choice.shape[1]
+        sub = jax.lax.broadcasted_iota(jnp.int32, (_LANES, tb), 0)
+        wide = jnp.zeros((_LANES, tb), per_choice.dtype)
+        for j in range(k):
+            wide = jnp.where(sub == j, per_choice[j:j + 1, :], wide)
+        return wide.T
+
+    base = (b % 2) * slots
+    n = count[b % 2]
+    dest = columns(dest_ref)
+    w = columns(w_ref[0]) if weighted else None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, K), 1)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def product(g, carry):
+        first = base + g * group
+
+        def staged(i, pos):
+            """The buffer positions of slot i's rows that this block owns
+            (the others stay -1, which no `dest` is), lanes i * chunk on;
+            a slot past the last one staged owns nothing."""
+            slot = first + i
+            live = g * group + i < n
+
+            @pl.when(live)
+            def _():
+                copy(slot, 0).wait()
+
+            p = meta[3 * slot] + lane - i * chunk
+            own = (lane >= i * chunk) & (lane < (i + 1) * chunk) \
+                & (p >= meta[3 * slot + 1]) \
+                & (p < jnp.where(live, meta[3 * slot + 2], 0))
+            return jnp.where(own, p, pos)
+
+        pos = jax.lax.fori_loop(0, group, staged,
+                                jnp.full((1, K), -1, jnp.int32))
+        sel = jnp.zeros((dest.shape[0], K), jnp.float32)
+        for j in range(k):
+            sel = jnp.where(dest[:, j:j + 1] == pos,
+                            w[:, j:j + 1] if weighted else 1.0, sel)
+        rows = stage_ref[pl.ds(pl.multiple_of(first * chunk, K), K), :]
+        acc_ref[...] += _select_dot(sel, rows, weighted)
+        return carry
+
+    jax.lax.fori_loop(0, -(-n // group), product, 0)
+    out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _combine(rows, plan, weights, n_held, tm, interpret):
+    """Buffer rows [M, H] back to tokens: out[n] = sum of the rows of
+    token n's held pairs (times `weights` [N, k], float32), in float32,
+    rounded once to the rows' dtype. The cost follows the rows in use and
+    the [N, H] output: XLA's gather over every (token, choice) cost a row
+    for each of the N * k pairs, nine in ten of them held elsewhere.
+    Jitted so that a program's expert layers share one trace of the
+    kernel a variant (8 calls a step traced and lowered apart cost the
+    cell's set-up 4.1 s of Python, 0.6 s so: PERF.md section 6, PR 31)."""
+    dest = plan["dest"]
+    N, k = dest.shape
+    H = rows.shape[1]
+    tb, chunk, group, align = _combine_tiling(N, tm)
+    Hp = _panel(H)
+    # a block's ranges hold at most tb * k rows, and each range may start
+    # and end inside a chunk
+    slots = (tb * k + n_held * (align + chunk - 2)) // chunk
+    slots = -(-slots // group) * group
+    weighted = weights is not None
+
+    # [N, k] arrays go in as [k, N]: a Mosaic operand is row-major, and
+    # k = 4 in the minor dimension would be padded to 128 lanes in HBM
+    per_choice = pl.BlockSpec((k, tb), lambda c, b, off, na: (0, b))
+    args = [dest.T] + ([weights.T] if weighted else []) + [rows]
+    return pl.pallas_call(
+        functools.partial(_combine_kernel, weighted=weighted, n_held=n_held,
+                          k=k, tm=tm, chunk=chunk, group=group, align=align,
+                          slots=slots),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(H // Hp, -(-N // tb)),
+            in_specs=[per_choice] * (len(args) - 1)
+            + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tb, Hp), lambda c, b, off, na: (b, c)),
+            scratch_shapes=[
+                pltpu.VMEM((2 * slots * chunk, Hp), rows.dtype),
+                pltpu.VMEM((tb, Hp), jnp.float32),
+                pltpu.SemaphoreType.DMA((2 * slots,)),
+                pltpu.SMEM((2 * slots * 3,), jnp.int32),
+                pltpu.SMEM((2,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((N, H), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name="moe_combine", interpret=interpret)(
+            plan["block_off"], plan["n_active"], *args)
 
 
 def _int_zero(a):
@@ -323,7 +542,7 @@ def _ffn_fwd(x, topk_w, w1, w3, w2, plan, n_held, tm, interpret):
     xs = _gather_rows(x, plan, tm)
     g = _gmm_swiglu(plan, xs, w1, w3, tm, interpret)
     y = _gmm(plan, [(g, w2)], False, tm, interpret)
-    out = _combine(y, plan["dest"], topk_w).astype(x.dtype)
+    out = _combine(y, plan, topk_w, n_held, tm, interpret)
     return out, (x, topk_w, w1, w3, w2, plan)
 
 
@@ -343,7 +562,7 @@ def _ffn_bwd(n_held, tm, interpret, res, dout):
     dw1 = _tgmm(plan, xs, dh1, n_held, tm, interpret)
     dw3 = _tgmm(plan, xs, dh3, n_held, tm, interpret)
     dw2 = _tgmm(plan, gw, dys, n_held, tm, interpret)
-    dx = _combine(dxs, dest).astype(x.dtype)
+    dx = _combine(dxs, plan, None, n_held, tm, interpret)
     dw = jnp.take(dw_rows[:, 0], dest, mode="fill",
                   fill_value=0).astype(topk_w.dtype)
     return (dx, dw, dw1.astype(w1.dtype), dw3.astype(w3.dtype),

@@ -162,7 +162,7 @@ def test_grouped_expert_products_compile_at_the_cells_widths(one_chip):
     """`moe_expert_ffn` of one expert layer of lfm2_train_1chip: 16384
     tokens, top-4 of 64, 8 experts of 2048 x 1536 held, bf16, forward and
     backward: the four grouped kernels, the experts' whole matrices as
-    blocks in VMEM."""
+    blocks in VMEM, and `moe_combine` on the way back to the tokens."""
     from paddle_tpu.ops.pallas import grouped_matmul as gm
     N, Hd, F, E, k = 16384, 2048, 1536, 8, 4
     sds = jax.ShapeDtypeStruct
@@ -181,12 +181,45 @@ def test_grouped_expert_products_compile_at_the_cells_widths(one_chip):
             S((E, F, Hd), jnp.bfloat16), S((N, k), jnp.int32)).compile()
     names = _kernel_names(compiled)
     for kernel in ("moe_gmm_swiglu", "moe_gmm", "moe_swiglu_bwd",
-                   "moe_tgmm"):
+                   "moe_tgmm", "moe_combine"):
         assert any(kernel in n for n in names), names
     # the buffer is the worst case's (every token's 4 pairs here) and the
     # module keeps a handful of buffers of it, not one a product
     rows = gm.buffer_tiles(N, k, E, gm.DEFAULT_TILE_ROWS) \
         * gm.DEFAULT_TILE_ROWS
     assert rows == N * k + E * gm.DEFAULT_TILE_ROWS
+    # 2265923584 at the parent of PR 31, whose gather back to the tokens
+    # kept a [N, k, Hd] intermediate (268 MB): 1997129728 without it
     assert compiled.memory_analysis().temp_size_in_bytes \
-        < 12 * rows * Hd * 2
+        < 2265923584 - N * k * Hd * 2 // 2
+
+
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "unweighted"])
+def test_moe_combine_compiles_at_the_cells_shapes(one_chip, weighted):
+    """The way back to the tokens alone, as lfm2_train_1chip calls it: a
+    [69632, 2048] bf16 buffer, 16384 tokens, top-4, 8 experts held; with
+    the routing weights (the forward's `out`) and without (the
+    backward's `dx`)."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    N, Hd, E, k, tm = 16384, 2048, 8, 4, gm.DEFAULT_TILE_ROWS
+    M = gm.buffer_tiles(N, k, E, tm) * tm
+    assert M == 69632
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def back(rows, idx, tw):
+        plan = gm.make_plan(idx, 0, E, tm)
+        return gm._combine(rows, plan, tw if weighted else None, E, tm,
+                           False)
+
+    compiled = jax.jit(back).lower(
+        S((M, Hd), jnp.bfloat16), S((N, k), jnp.int32),
+        S((N, k), jnp.float32)).compile()
+    assert any("moe_combine" in n for n in _kernel_names(compiled))
+    out = jax.eval_shape(back, S((M, Hd), jnp.bfloat16),
+                         S((N, k), jnp.int32), S((N, k), jnp.float32))
+    assert out.shape == (N, Hd) and out.dtype == jnp.bfloat16
+    # no [N, k, Hd] intermediate: the plan's index arrays and little else
+    assert compiled.memory_analysis().temp_size_in_bytes < N * Hd * 2

@@ -268,8 +268,22 @@ def test_no_token_is_dropped_when_every_token_picks_one_held_expert(N):
     assert int(np.sum(counts)) == 0 and not np.asarray(out).any()
 
 
-def test_grouped_kernels_gradients_match_the_composition():
+def _twice(idx):
+    """Every third token names its first expert twice, with two weights."""
+    idx = idx.copy()
+    idx[::3, 1] = idx[::3, 0]
+    return idx
+
+
+@pytest.mark.parametrize("tile_rows", [8, 16])
+@pytest.mark.parametrize("choices", ["distinct", "one expert twice"])
+def test_grouped_kernels_gradients_match_the_composition(choices, tile_rows):
+    """Through `expert_ffn`: the gradient of the rows (dx comes back
+    through `moe_combine`, unweighted) and of the weights, which the
+    forward's weighted combine applies."""
     x, idx, tw, w1, w3, w2 = _expert_case()
+    if choices == "one expert twice":
+        idx = _twice(idx)
 
     def grads(fn):
         def loss(x, tw, w1, w3, w2):
@@ -278,11 +292,99 @@ def test_grouped_kernels_gradients_match_the_composition():
         return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(
             *(jnp.asarray(a) for a in (x, tw, w1[2:5], w3[2:5], w2[2:5])))
 
-    got = grads(lambda *a, **kw: gm.expert_ffn(*a, tile_rows=16,
+    got = grads(lambda *a, **kw: gm.expert_ffn(*a, tile_rows=tile_rows,
                                                interpret=True, **kw))
     want = grads(gm.expert_ffn_reference)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
+
+
+def _take_combine(rows, dest, weights=None):
+    """What `moe_combine` replaced (PR 31), kept as its oracle: XLA's
+    gather over every (token, choice), a pair that is not held reading a
+    filled-in zero; float32 weights and sum, one rounding."""
+    picked = jnp.take(rows, dest, axis=0, mode="fill", fill_value=0)
+    picked = picked.astype(jnp.float32)
+    if weights is not None:
+        picked = picked * weights[..., None]
+    return jnp.sum(picked, axis=1).astype(rows.dtype)
+
+
+def _combine_case(routing, N=40, k=2):
+    """Experts 2, 3, 4 of 8 are held."""
+    idx = np.stack([RNG.permutation(8)[:k] for _ in range(N)]).astype(
+        "int32")
+    if routing == "all to one held expert":     # ranges of a whole block
+        idx[:, 0], idx[:, 1] = 3, 7
+    elif routing == "none held":
+        idx[:, 0], idx[:, 1] = 0, 6
+    elif routing == "one expert twice":
+        idx = _twice(idx)
+    return idx
+
+
+@pytest.mark.parametrize("tile_rows", [8, 16])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("weighted", [True, False],
+                         ids=["weighted", "unweighted"])
+@pytest.mark.parametrize("routing,N", [
+    ("random", 40), ("random", 300),     # 300: off a multiple of the block
+    ("all to one held expert", 520), ("none held", 24),
+    ("one expert twice", 270)])
+def test_moe_combine_matches_the_gather_it_replaced(routing, N, weighted,
+                                                    dtype, tile_rows):
+    """The kernel alone, on a buffer whose rows past the tiles in use are
+    NaN (the grouped kernels never write them): the same sum as the
+    gather's, to the last bit in float32 rows (a token's at most k
+    products are added in another order, so a bf16 result may round the
+    other way once)."""
+    idx = _combine_case(routing, N)
+    plan = gm.make_plan(jnp.asarray(idx), 2, 3, tile_rows)
+    M, in_use = plan["src"].shape[0], int(plan["n_active"][0]) * tile_rows
+    rows = np.full((M, 16), np.nan, "float32")
+    rows[:in_use] = _f32(in_use, 16)
+    rows = jnp.asarray(rows, dtype)
+    w = jnp.asarray(RNG.uniform(0.1, 1.0, idx.shape), jnp.float32) \
+        if weighted else None
+    got = gm._combine(rows, plan, w, 3, tile_rows, True)
+    want = _take_combine(rows, plan["dest"], w)
+    assert got.shape == (N, 16) and got.dtype == rows.dtype
+    if routing == "none held":
+        assert not np.asarray(got, "float32").any()
+    held = int(np.sum(plan["counts"]))
+    assert held == ((idx >= 2) & (idx < 5)).sum()
+    if routing == "all to one held expert":
+        # a block's range of expert 1 is the whole block: many chunks
+        off = np.asarray(plan["block_off"]).reshape(-1, 3)
+        tb, chunk, _, _ = gm._combine_tiling(N, tile_rows)
+        np.testing.assert_array_equal(np.diff(off[:, 1]), [tb, tb, N % tb])
+        assert tb >= 16 * chunk
+    tol = dict(atol=1e-6, rtol=1e-6) if dtype == "float32" else \
+        dict(atol=1e-2, rtol=1e-2)
+    np.testing.assert_allclose(np.asarray(got, "float32"),
+                               np.asarray(want, "float32"), **tol)
+
+
+def test_bench_expert_ffn_tool_refuses_without_a_chip(tmp_path, monkeypatch,
+                                                      capsys):
+    """tools/bench_expert_ffn.py (how the one-layer timings of PERF.md
+    were measured) times nothing off the chip: no `ms` line, no file,
+    exit code 2; its copy of the replaced gather is the oracle's sum."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools", "bench_expert_ffn.py")
+    spec = importlib.util.spec_from_file_location("bench_expert_ffn", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    monkeypatch.chdir(tmp_path)
+    assert tool.main(["[[64, 2, 16, 24, 2, 8]]", "1"]) == 2
+    said = capsys.readouterr()
+    assert "ms" not in said.out and "not a TPU" in said.err
+    assert not (tmp_path / "chiprun_out").exists()
+    rows, dest = jnp.asarray(_f32(12, 16)), jnp.asarray([[0, 12], [3, 3]])
+    w = jnp.asarray(_f32(2, 2))
+    np.testing.assert_array_equal(tool.take_combine(rows, dest, w),
+                                  _take_combine(rows, dest, w))
 
 
 def test_the_buffer_holds_the_worst_case_and_a_tile_has_one_expert():
