@@ -516,10 +516,6 @@ _HISTORY_METRICS = (
     ("transformer_b256_tokens_per_sec", "tokens/sec", "b256"),
     ("transformer_b256_mfu", "mfu", "b256"),
     ("flash_attn_32k_causal_ms", "ms", "flash"),
-    ("kern_decode_fp32_off_ms", "ms", "kern"),
-    ("kern_decode_fp32_on_ms", "ms", "kern"),
-    ("kern_decode_int8_off_ms", "ms", "kern"),
-    ("kern_decode_int8_on_ms", "ms", "kern"),
 )
 
 
@@ -1093,165 +1089,6 @@ def _async_mode(k=4, steps=40):
         restore()
 
 
-def _kern_mode(steps=24, maxlen=16, slots=4):
-    """`bench.py --kern`: A/B the ops/kern registry dispatch seam on
-    the incremental-decode stage — PADDLE_TPU_KERN=off (the
-    byte-identical jnp lowering) vs registry ON with the Pallas
-    interpreter forced. CPU-honesty note recorded in the artifact:
-    interpret-mode Pallas is SLOWER than fused XLA on CPU, so the
-    wall-time columns here are evidence the kernels actually ran and
-    match token-for-token, not a speed claim — the speed claim needs
-    the chip, where the same seam dispatches compiled kernels. Two
-    variants: fp32 KV cache (decode_attend) and int8 block-quantized
-    KV cache (int8_quant at the cache writes + fused
-    dequant_attend_int8). Prints ONE JSON line + BENCH_kernels.json
-    and appends paddle_tpu.bench.history.v1 records."""
-    import paddle_tpu as pt
-    from paddle_tpu.core import framework as fw
-    from paddle_tpu.models import transformer as tfm
-
-    # seeded tiny stack (the test_serving_farm recipe): wide random
-    # params so greedy decode produces varied, comparable tokens
-    cfg = tfm.TransformerConfig(src_vocab=64, trg_vocab=64,
-                                max_len=maxlen, d_model=32, d_inner=64,
-                                n_head=4, n_layer=2, dropout=0.0,
-                                label_smooth_eps=0.0)
-    infer, start = fw.Program(), fw.Program()
-    with pt.program_guard(infer, start):
-        with pt.unique_name.guard():
-            tfm.build_infer_program(cfg, maxlen=maxlen)
-    exe = pt.Executor(pt.CPUPlace())
-    exe.run(start)
-    rng = np.random.RandomState(7)
-    scope = pt.global_scope()
-    params = {}
-    for v in infer.persistable_vars():
-        a = np.asarray(scope.get(v.name))
-        if v.name.startswith("layer_norm") and v.name.endswith(".w_0"):
-            nv = 1.0 + 0.2 * rng.randn(*a.shape)
-        elif v.name.endswith(".b_0"):
-            nv = 0.1 * rng.randn(*a.shape)
-        else:
-            nv = 0.35 * rng.randn(*a.shape)
-        params[v.name] = nv.astype(a.dtype)
-
-    r2 = np.random.RandomState(3)
-    src = np.zeros((slots, maxlen), np.int64)
-    src_len = np.ones((slots,), np.int64)
-    for j in range(slots):
-        n = int(r2.randint(3, maxlen))
-        src[j, :n] = r2.randint(2, 60, (n,))
-        src_len[j] = n
-
-    def run_arm(kern_on, kv_quant):
-        os.environ["PADDLE_TPU_KERN"] = "on" if kern_on else "off"
-        stats0 = None
-        if kern_on:
-            # loaded only for the ON arms — the off arms must witness
-            # a pallas-free, registry-free process
-            from paddle_tpu.ops.pallas import flash_attention as fa
-            fa.set_mode("interpret")
-            from paddle_tpu.ops.kern import registry as kreg
-            stats0 = {k: dict(v) for k, v
-                      in kreg.STATS["by_kernel"].items()}
-        dec = tfm.IncrementalDecoder(cfg, params, num_slots=slots,
-                                     max_len=maxlen, kv_quant=kv_quant)
-        state = dec.write_slots(dec.init_state(),
-                                dec.prefill(src, src_len),
-                                list(range(slots)))
-        ids = np.zeros(slots, np.int64)
-        pos = np.zeros(slots, np.int64)
-        ids = dec.step(state, ids, pos)           # compile
-        toks = [ids.copy()]
-        t0 = time.perf_counter()
-        for _ in range(1, steps):
-            pos = pos + 1
-            ids = dec.step(state, ids, pos)
-            toks.append(ids.copy())
-        step_ms = (time.perf_counter() - t0) / max(steps - 1, 1) * 1e3
-        accepted = {}
-        if kern_on:
-            from paddle_tpu.ops.kern import registry as kreg
-            for name, per in kreg.STATS["by_kernel"].items():
-                d = per["accepted"] - stats0.get(name, {}).get(
-                    "accepted", 0)
-                if d:
-                    accepted[name] = d
-        return {"step_ms": round(step_ms, 2), "toks": toks,
-                "accepted": accepted}
-
-    old_kern = os.environ.get("PADDLE_TPU_KERN")
-    try:
-        # off arms FIRST: while they run, no ops.kern machinery and no
-        # ops.pallas module may load (the bench-contract pin, witnessed
-        # here too)
-        off_fp32 = run_arm(False, None)
-        off_int8 = run_arm(False, "int8")
-        clean_off = not any(
-            m.startswith(("paddle_tpu.ops.pallas",
-                          "paddle_tpu.ops.kern.registry"))
-            for m in sys.modules)
-        on_fp32 = run_arm(True, None)
-        on_int8 = run_arm(True, "int8")
-    finally:
-        fa_mod = sys.modules.get(
-            "paddle_tpu.ops.pallas.flash_attention")
-        if fa_mod is not None:
-            fa_mod.set_mode("auto")
-        if old_kern is None:
-            os.environ.pop("PADDLE_TPU_KERN", None)
-        else:
-            os.environ["PADDLE_TPU_KERN"] = old_kern
-
-    def match(a, b):
-        return round(float(np.mean([np.array_equal(x, y)
-                                    for x, y in zip(a["toks"],
-                                                    b["toks"])])), 4)
-
-    fp32_match = match(off_fp32, on_fp32)
-    int8_match = match(off_int8, on_int8)
-    n_layer = cfg.n_layer
-    pass_dispatch = (
-        on_fp32["accepted"].get("decode_attend", 0) >= n_layer
-        and on_int8["accepted"].get("dequant_attend_int8", 0) >= n_layer
-        and on_int8["accepted"].get("int8_quant", 0) >= n_layer)
-    total_accepted = sum(on_fp32["accepted"].values()) \
-        + sum(on_int8["accepted"].values())
-    result = {
-        "metric": "kern_registry_accepted_dispatches",
-        "value": total_accepted,
-        "unit": "kernels dispatched at trace time",
-        "platform": "cpu",
-        "kern_decode_fp32_off_ms": off_fp32["step_ms"],
-        "kern_decode_fp32_on_ms": on_fp32["step_ms"],
-        "kern_decode_int8_off_ms": off_int8["step_ms"],
-        "kern_decode_int8_on_ms": on_int8["step_ms"],
-        "fp32_token_match": fp32_match,
-        "int8_token_match": int8_match,
-        "accepted_fp32": on_fp32["accepted"],
-        "accepted_int8": on_int8["accepted"],
-        "registry_off_imported_nothing": clean_off,
-        "pass_dispatch": pass_dispatch,
-        "pass_parity": fp32_match == 1.0 and int8_match == 1.0,
-        "note": ("interpret-mode Pallas on CPU: the on-arm times are "
-                 "evidence of dispatch + token parity, not speed; the "
-                 "speed A/B needs the chip"),
-        "history_stage": "kern",
-        "steps": steps, "slots": slots, "maxlen": maxlen,
-    }
-    try:
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "BENCH_kernels.json")
-        with open(path, "w") as f:
-            json.dump({"schema": "paddle_tpu.bench.kernels.v1",
-                       **result}, f, indent=1)
-    except OSError:
-        pass
-    _append_history(result)
-    _emit(result)
-    return 0 if (pass_dispatch and result["pass_parity"]) else 1
-
-
 def main():
     for i, arg in enumerate(sys.argv[1:], start=1):
         if arg.startswith("--deepfm-vocab-rows"):
@@ -1274,8 +1111,6 @@ def main():
             _, eq, v = arg.partition("=")
             depth = int(v) if eq and v else 4
             sys.exit(_async_mode(k=depth))
-        if arg == "--kern":
-            sys.exit(_kern_mode())
     import jax
     dev = jax.devices()[0]          # the one backend initialization
     if dev.platform != "tpu" and \

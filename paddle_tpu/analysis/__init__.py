@@ -15,10 +15,9 @@ IR *before* tracing:
     recompile-hazard   attrs/feed signatures that bust the compile cache
 
 The `meshlint` subpackage extends the same pipeline to SHARDED
-executions (PartitionSpecs vs the mesh + API-capability verdicts,
-collective consistency, donation aliasing, per-device footprint,
-static recompile hazards) — see analysis/meshlint/__init__.py. It is
-imported lazily (ParallelExecutor.verify(), FarmConfig.verify(),
+executions (PartitionSpecs vs the mesh, collective consistency,
+donation aliasing, per-device footprint, static recompile hazards) —
+see analysis/meshlint/__init__.py. It is imported lazily (ParallelExecutor.verify(), FarmConfig.verify(),
 tools/tpulint.py), never from here: the validate-off path must not pay
 for it.
 

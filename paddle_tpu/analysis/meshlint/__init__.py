@@ -10,11 +10,9 @@ meshlint extends the same pass pipeline (Diagnostic records, registry,
 fix hints, crash-isolation) to sharded executions:
 
     mesh-spec               every PartitionSpec vs the declared mesh
-                            (axis exists, divisibility, rank), plus
-                            API-capability verdicts: which of the two
-                            shard_map APIs (this image's jax-0.4.37
-                            shim vs current jax) rejects a construct,
-                            and why
+                            (axis exists and is used once,
+                            divisibility, rank): what jax.shard_map
+                            rejects at trace time
     collective-consistency  per-member collective sequences under a
                             policy (gradsync bucket order, pipeline
                             schedule, sparse exchange); conditional
@@ -35,29 +33,24 @@ fix hints, crash-isolation) to sharded executions:
 
 Entry points: ParallelExecutor.verify() / FarmConfig.verify() (and
 their PADDLE_TPU_VALIDATE pre-trace gates), tools/tpulint.py, and
-`classify` — the machine-readable classification of the 18 red
-multichip test configs (LINT_multichip.json).
+`classify.green_configs` — the passing parallel tests' configs, on
+which every pass must stay silent.
 
 The validate-off path never imports this package (bench-contract pin);
 keep every import of meshlint lazy.
 """
-from .capability import (PROFILE_CURRENT, PROFILE_SHIM, active_profile,
-                         api_profiles, capability_verdict, explain,
-                         supports)
 from .context import (MESH_PASSES, MeshLintContext, MeshSpec,
                       ShardMapUse, mesh_pass, mesh_pass_names,
                       normalize_spec, run_mesh_passes, spec_str,
                       verify_mesh)
 from .spec_check import static_spec_verdict
 from . import spec_check, collectives, donation, footprint, recompile, kerncap  # noqa: F401 (pass registration)
-from .classify import classify_red_tests, green_configs, red_configs
+from .classify import green_configs
 
 __all__ = [
-    "PROFILE_CURRENT", "PROFILE_SHIM", "active_profile", "api_profiles",
-    "capability_verdict", "explain", "supports",
     "MESH_PASSES", "MeshLintContext", "MeshSpec", "ShardMapUse",
     "mesh_pass", "mesh_pass_names", "normalize_spec", "run_mesh_passes",
     "spec_str", "verify_mesh",
     "static_spec_verdict",
-    "classify_red_tests", "green_configs", "red_configs",
+    "green_configs",
 ]

@@ -1,25 +1,17 @@
-"""Machine-readable classification of the repo's 18 red multichip test
-configs (and the green control set).
+"""The zero-false-positive control set: the sharded configs of the
+repo's passing parallel tests, each reconstructed as a MeshLintContext
+— the SAME object the executor gates lint. Every one of them runs
+green, so the mesh passes must stay silent on all of them
+(tools/tpulint.py checks it, tests/test_meshlint.py pins it).
 
-Every currently-failing parallel test on this image is one of three
-API capabilities (capability.py). This module reconstructs each red
-test's sharded config as a MeshLintContext — the SAME object the
-executor gates lint — runs the mesh passes over it, and records which
-pass fired and the both-API verdict. tools/tpulint.py serializes the
-result as LINT_multichip.json; tests/test_meshlint.py pins that every
-red config classifies and every green config produces zero errors.
-
-The configs mirror the tests exactly (meshes, specs, schedules — see
+The configs mirror the tests (meshes, specs, schedules — see
 tests/test_four_axis.py, tests/test_pipeline_1f1b.py,
-tests/test_parallel_advanced.py, tests/test_multihost.py); keep them
-in sync when a test changes.
+tests/test_parallel_advanced.py, tests/test_multihost.py,
+tests/test_parallel.py); keep them in sync when a test changes.
 """
-from . import capability as _cap
-from .context import MeshLintContext, MeshSpec, ShardMapUse, \
-    run_mesh_passes
-from .spec_check import capability_findings
+from .context import MeshLintContext, MeshSpec, ShardMapUse
 
-__all__ = ["red_configs", "green_configs", "classify_red_tests"]
+__all__ = ["green_configs"]
 
 _P = ()  # replicated spec
 
@@ -28,16 +20,14 @@ def _gpipe_use(n_stages, data_axis=None):
     """The GPipe PipelineTrainer shard_map call site
     (parallel/pipeline.py:_build_fn): stacked per-stage params sharded
     over pp, feeds replicated (or batch-split over data_axis), loss
-    grad taken THROUGH the boundary (value_and_grad at the red line),
-    body = pipelined lax.scan with stage-masked selects + ppermute."""
+    grad taken THROUGH the boundary, body = pipelined lax.scan with
+    stage-masked selects + ppermute."""
     feed_spec = (None, data_axis) if data_axis else _P
     n_params = 2 * n_stages  # fc weight + bias per stage
     return ShardMapUse(
         "pipeline.gpipe",
         in_specs=[("pp",)] * n_params + [feed_spec, _P],
-        out_specs=[_P],
-        grad_through=True,
-        body_features=("pipelined_scan", "ppermute", "psum"))
+        out_specs=[_P])
 
 
 def _1f1b_use(n_stages, data_axis=None):
@@ -46,15 +36,10 @@ def _1f1b_use(n_stages, data_axis=None):
     accumulator over data_axis when present."""
     feed_spec = (None, data_axis) if data_axis else _P
     n_params = 2 * n_stages
-    feats = ["scan", "inner_vjp", "ppermute"]
-    if data_axis:
-        feats.append("dp_psum_masked_accumulator")
     return ShardMapUse(
         "pipeline.1f1b",
         in_specs=[("pp",)] * n_params + [feed_spec, _P],
-        out_specs=[_P] + [("pp",)] * n_params,
-        grad_through=False,
-        body_features=feats)
+        out_specs=[_P] + [("pp",)] * n_params)
 
 
 def _four_axis_use():
@@ -64,18 +49,12 @@ def _four_axis_use():
         "four_axis.train_step",
         in_specs=[("pp", None, "tp"), ("pp", "tp", None),
                   (None, "dp", "sp", None), (None, "dp", "sp", None)],
-        out_specs=[_P],
-        grad_through=True,
-        body_features=("pipelined_scan", "ppermute", "psum"))
+        out_specs=[_P])
 
 
-def _multihost_ctx(label):
-    return MeshLintContext(
-        MeshSpec({"dp": 2}), processes=2, backend="cpu", label=label)
-
-
-def red_configs():
-    """[(test_id, MeshLintContext)] for all 18 red multichip tests."""
+def _multichip_test_configs():
+    """[(test_id, MeshLintContext)] for the 18 multichip tests whose
+    configs are spelled out test by test."""
     out = []
     four_axis_meshes = [
         ("axes0", {"dp": 2, "tp": 2, "pp": 2, "sp": 1}),
@@ -106,8 +85,7 @@ def red_configs():
     for t in ("test_1f1b_matches_gpipe_and_dense",
               "test_1f1b_matches_gpipe_with_dropout",
               "test_more_microbatches_than_stages"):
-        # these compare 1F1B against a GPipe leg; the GPipe leg's
-        # boundary transpose is what dies (pipeline.py:382)
+        # these compare 1F1B against a GPipe leg
         out.append((
             f"tests/test_pipeline_1f1b.py::TestOneFOneBNumerics::{t}",
             MeshLintContext(MeshSpec({"pp": 4}),
@@ -126,15 +104,16 @@ def red_configs():
               "expert_parallel_moe", "tensor_parallel_training"):
         out.append((
             f"tests/test_multihost.py::test_two_process_{t}",
-            _multihost_ctx(f"multihost[{t}]")))
+            MeshLintContext(MeshSpec({"dp": 2}),
+                            label=f"multihost[{t}]")))
     return out
 
 
 def green_configs():
-    """[(label, MeshLintContext)] for currently-GREEN parallel configs
-    — the zero-false-positive control set. Every one of these passes on
-    this image, so meshlint must produce no ERROR for any of them."""
-    out = []
+    """[(label, MeshLintContext)] for the parallel configs the tests
+    run green — the zero-false-positive control set: meshlint must
+    produce no ERROR for any of them."""
+    out = _multichip_test_configs()
     # pure 1F1B, no data axis: bit-correct (test_1f1b_trains)
     out.append(("1f1b-no-dp", MeshLintContext(
         MeshSpec({"pp": 4}), uses=[_1f1b_use(4)],
@@ -144,9 +123,7 @@ def green_configs():
         MeshSpec({"pp": 4}),
         uses=[ShardMapUse(
             "pipeline.forward",
-            in_specs=[("pp",), _P], out_specs=[_P],
-            grad_through=False,
-            body_features=("pipelined_scan", "ppermute"))],
+            in_specs=[("pp",), _P], out_specs=[_P])],
         label="pipeline-forward")))
     # data-parallel gradsync (test_parallel.py): single process
     for mode in ("fp32", "bf16", "int8:bucket_mb=1"):
@@ -159,7 +136,6 @@ def green_configs():
         uses=[ShardMapUse(
             "tp.matmul",
             in_specs=[(None, "tp"), ("tp", None)], out_specs=[_P],
-            grad_through=True, body_features=("psum",),
             arg_shapes=[(8, 8), (8, 8)])],
         label="tensor-parallel")))
     # sparse embedding exchange (single-process)
@@ -167,30 +143,3 @@ def green_configs():
         MeshSpec({"dp": 8}), grad_sync="fp32", sparse="shard:stale=2",
         label="sparse-shard")))
     return out
-
-
-def classify_red_tests():
-    """One record per red test: which pass fires, which capability, and
-    the both-API verdict — the LINT_multichip.json payload. The
-    classification is derived by RUNNING the passes on the
-    reconstructed config (not hand-assigned), so the gate and this
-    table cannot disagree."""
-    records = []
-    for test_id, mctx in red_configs():
-        caps = [c for c, _ in capability_findings(mctx)
-                if not _cap.supports(_cap.PROFILE_SHIM, c)]
-        diags = run_mesh_passes(mctx)
-        firing = [d for d in diags if d.severity == "error"
-                  and any(c in d.message for c in caps)]
-        cap = caps[0] if caps else None
-        records.append({
-            "test": test_id,
-            "label": mctx.label,
-            "mesh": str(mctx.mesh),
-            "pass": firing[0].pass_name if firing else None,
-            "capability": cap,
-            "verdict": _cap.capability_verdict(cap) if cap else None,
-            "classified": bool(firing),
-            "message": firing[0].message if firing else None,
-        })
-    return records

@@ -3,13 +3,13 @@
 Mirrors the proglint shape exactly (analysis/pipeline.py): passes are
 `fn(mctx) -> [Diagnostic]` registered with @mesh_pass, run in
 registration order, crash-isolated to INFO diagnostics, and report
-through the same Diagnostic records — so the CLI, the executor gates,
-and LINT_multichip.json all consume one format.
+through the same Diagnostic records — so the CLI and the executor
+gates consume one format.
 
 Everything here is import-light: no jax at module level, and a
 MeshLintContext can describe a sharded execution WITHOUT live devices
 (MeshSpec is axis names + sizes, not a jax.sharding.Mesh) — that is
-what makes the 18 red-test configs classifiable on any host.
+what lets the multichip tests' configs be linted on any host.
 """
 from ..diagnostics import Diagnostic, ProgramVerificationError, INFO
 
@@ -106,19 +106,10 @@ class ShardMapUse:
     out_specs     same for outputs (may be empty when unknown)
     arg_shapes    per-arg global shape tuple, or None when unknown
     arg_names     per-arg label for messages (optional)
-    grad_through  the call site is differentiated THROUGH (the
-                  transpose crosses the shard_map boundary); grad
-                  taken INSIDE the body does not count
-    body_features subset of {"scan", "pipelined_scan", "ppermute",
-                  "psum", "cond", "inner_vjp",
-                  "dp_psum_masked_accumulator"} — what the body does,
-                  as known at the call site
-    check_disabled  check_vma/check_rep turned off (the repo default)
     """
 
     def __init__(self, name, in_specs, out_specs=(), arg_shapes=None,
-                 arg_names=None, grad_through=False, body_features=(),
-                 check_disabled=True):
+                 arg_names=None):
         self.name = name
         self.in_specs = tuple(normalize_spec(s) for s in in_specs)
         self.out_specs = tuple(normalize_spec(s) for s in out_specs)
@@ -127,9 +118,6 @@ class ShardMapUse:
                            else (None,) * n)
         self.arg_names = (tuple(arg_names) if arg_names is not None
                           else tuple(f"arg{i}" for i in range(n)))
-        self.grad_through = bool(grad_through)
-        self.body_features = frozenset(body_features)
-        self.check_disabled = bool(check_disabled)
 
 
 class MeshLintContext:
@@ -148,8 +136,6 @@ class MeshLintContext:
     pipeline_schedule  "gpipe" | "1f1b" | None
     data_axis       pipeline data axis name (PipelineTrainer data_axis)
     member_policies per-member policy strings when members may diverge
-    processes       process count the config assumes (multi-host)
-    backend         "cpu" | "tpu" | ... (capability checks)
     param_specs     {param name -> PartitionSpec} for footprint
     extra_state_bytes  flat extra per-member bytes (e.g. KV cache)
     memory_cap_bytes   per-device byte budget (None = skip the check)
@@ -159,8 +145,8 @@ class MeshLintContext:
     def __init__(self, mesh, uses=(), program=None, fetch_names=(),
                  feed_names=(), donate_state=True, async_steps=None,
                  grad_sync=None, sparse=None, pipeline_schedule=None,
-                 data_axis=None, member_policies=None, processes=1,
-                 backend=None, param_specs=None, extra_state_bytes=0,
+                 data_axis=None, member_policies=None,
+                 param_specs=None, extra_state_bytes=0,
                  memory_cap_bytes=None, label=""):
         if not isinstance(mesh, MeshSpec):
             mesh = MeshSpec.from_mesh(mesh)
@@ -177,8 +163,6 @@ class MeshLintContext:
         self.data_axis = data_axis
         self.member_policies = (None if member_policies is None
                                 else tuple(member_policies))
-        self.processes = int(processes)
-        self.backend = backend
         self.param_specs = dict(param_specs or {})
         self.extra_state_bytes = int(extra_state_bytes)
         self.memory_cap_bytes = memory_cap_bytes

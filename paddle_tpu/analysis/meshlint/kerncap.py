@@ -6,10 +6,7 @@ STATIC capability probe — shapes and dtypes only, runnable on
 jax.ShapeDtypeStructs without data. This pass runs those same probes
 at lint time over the Program's declared shapes, so a sharded config
 learns BEFORE anything traces which ops will silently lower their jnp
-fallback (functional, just unaccelerated). It is the perf-side
-analogue of the mesh-spec pass's API-capability verdicts and names the
-active profile with the same vocabulary (capability.PROFILE_SHIM /
-PROFILE_CURRENT).
+fallback (functional, just unaccelerated).
 
 Mesh awareness: the program body traces INSIDE shard_map, so each
 device sees the per-shard batch — when the config declares a data
@@ -18,12 +15,10 @@ A kernel that accepts the global batch but rejects the per-device
 slice is exactly the surprise this pass exists to catch.
 
 Import discipline (bench-contract pin): ops.kern is imported lazily
-INSIDE the pass body and only after ops.registry.kern_enabled() says
-the registry is on — a validate-off or PADDLE_TPU_KERN=off process
-never pulls the registry through this module.
+INSIDE the pass body — a validate-off process never pulls the
+registry through this module.
 """
 from ..diagnostics import Diagnostic, WARNING
-from . import capability as _cap
 from .context import mesh_pass
 
 __all__ = ["check_kern_capability", "probe_program_kernels"]
@@ -113,8 +108,7 @@ _EXTRACTORS = {
 def probe_program_kernels(program, mesh=None, data_axis=None):
     """[(block_idx, op_idx, op_type, kernel_name, shape_str, ok)] for
     every program op a registered kernel serves and whose declared
-    shapes give the probe a static verdict. Caller gates on
-    kern_enabled() — this imports ops.kern."""
+    shapes give the probe a static verdict. Imports ops.kern."""
     from ...ops.kern import registry as kreg
     dp = 1
     if mesh is not None and data_axis and data_axis in mesh.axes:
@@ -146,11 +140,7 @@ def probe_program_kernels(program, mesh=None, data_axis=None):
 def check_kern_capability(mctx):
     if mctx.program is None:
         return []
-    from ...ops import registry as opreg
-    if not opreg.kern_enabled():
-        return []  # registry off: nothing dispatches, nothing to warn
     diags = []
-    active = _cap.active_profile()
     dp = 1
     if mctx.data_axis and mctx.data_axis in mctx.mesh.axes:
         dp = mctx.mesh.axis_size(mctx.data_axis)
@@ -164,12 +154,10 @@ def check_kern_capability(mctx):
             WARNING, "kern-capability",
             f"op {op_type!r} has a registered Pallas kernel "
             f"({kernel!r}) but its capability probe rejects the "
-            f"declared shapes [{shapes}]{sharded} — on the active API "
-            f"({active}) this op lowers the jnp fallback: correct, "
-            f"just not accelerated",
+            f"declared shapes [{shapes}]{sharded} — this op lowers the "
+            f"jnp fallback: correct, just not accelerated",
             block_idx=bidx, op_idx=i, op_type=op_type,
             hint="see `tpukern probe` for the kernel's shape/dtype "
                  "gate; pad or retile the offending dims (or accept "
-                 "the fallback) — PADDLE_TPU_KERN=off silences the "
-                 "registry entirely"))
+                 "the fallback)"))
     return diags

@@ -1,43 +1,40 @@
-"""mesh-spec pass: PartitionSpecs vs the declared mesh, plus
-API-capability verdicts.
+"""mesh-spec pass: PartitionSpecs vs the declared mesh.
 
-The structural rules mirror what this image's shard_map enforces at
-trace time (probed empirically; pinned against the real API by
-tests/test_meshlint_property.py over 300+ random configs):
+The structural rules mirror what jax.shard_map enforces at trace time
+(pinned against the real API by tests/test_meshlint_property.py over
+300+ random configs):
 
     unknown axis    any spec axis not on the mesh       -> reject
+    axis reuse      one axis in several entries         -> reject
     rank            len(spec) > value rank              -> reject
     divisibility    dim size % prod(axis sizes) != 0    -> reject
-    axis reuse      one axis in several entries         -> ACCEPTED by
-                    0.4.37, rejected by current jax — flagged as an
-                    API-capability divergence, not a structural error
-
-On top of the structural rules, the pass evaluates each call site
-against the capability table (capability.py) for BOTH APIs, so a
-config that this image rejects (or silently mis-executes) gets a
-static verdict naming the API and the reason before anything traces.
 """
-from ..diagnostics import Diagnostic, ERROR, WARNING, INFO
-from . import capability as _cap
+from ..diagnostics import Diagnostic, ERROR
 from .context import entry_axes, mesh_pass, normalize_spec, spec_str
 
-__all__ = ["static_spec_verdict", "check_mesh_specs",
-           "capability_findings"]
+__all__ = ["static_spec_verdict", "check_mesh_specs"]
 
 
 def static_spec_verdict(mesh, spec, shape=None, kind="in"):
-    """(ok, reasons) — does THIS image's shard_map accept `spec` for a
-    value of `shape` on `mesh`? Pure structural model, no jax import.
+    """(ok, reasons) — does jax.shard_map accept `spec` for a value of
+    `shape` on `mesh`? Pure structural model, no jax import.
     `shape=None` skips the shape-dependent rules (rank/divisibility).
     `kind` only flavors the reason strings ("in" | "out")."""
     spec = normalize_spec(spec)
     reasons = []
-    for d, entry in enumerate(spec):
+    seen = set()
+    for entry in spec:
         for ax in entry_axes(entry):
             if ax not in mesh.axes:
                 reasons.append(
                     f"{kind}_spec {spec_str(spec)} names axis {ax!r} "
                     f"not on the {mesh}")
+            elif ax in seen:
+                reasons.append(
+                    f"{kind}_spec {spec_str(spec)} uses axis {ax!r} "
+                    f"more than once: jax binds a mesh axis to at "
+                    f"most one dimension of one value")
+            seen.add(ax)
     if shape is not None:
         if len(spec) > len(shape):
             reasons.append(
@@ -59,53 +56,9 @@ def static_spec_verdict(mesh, spec, shape=None, kind="in"):
     return (not reasons), reasons
 
 
-def _reused_axes(spec):
-    """Axis names appearing in more than one entry slot of one spec."""
-    seen, reused = set(), []
-    for entry in normalize_spec(spec):
-        for ax in entry_axes(entry):
-            if ax in seen and ax not in reused:
-                reused.append(ax)
-            seen.add(ax)
-    return reused
-
-
-def _verdict_clause(capability):
-    """'rejected by <api> (<why>); accepted by <api> (<why>)' — the
-    both-API sentence every capability diagnostic carries."""
-    parts = []
-    for profile, v in _cap.capability_verdict(capability).items():
-        word = "accepted" if v["ok"] else "rejected"
-        parts.append(f"{word} by {profile}: {v['why']}")
-    return "; ".join(parts)
-
-
-def capability_findings(mctx):
-    """(capability, use-or-None, severity-on-active-profile) triples
-    for every capability the config exercises. Shared by the pass and
-    by classify.py, so the classification and the gate agree by
-    construction."""
-    findings = []
-    for use in mctx.uses:
-        if use.grad_through and ("pipelined_scan" in use.body_features
-                                 or "scan" in use.body_features):
-            findings.append(("shard_map.transpose_pipelined_scan", use))
-        if "dp_psum_masked_accumulator" in use.body_features:
-            findings.append(
-                ("shard_map.dp_psum_masked_accumulator", use))
-        for spec in use.in_specs + use.out_specs:
-            if _reused_axes(spec):
-                findings.append(("shard_map.axis_reuse_in_spec", use))
-                break
-    if mctx.processes > 1 and (mctx.backend or "cpu") == "cpu":
-        findings.append(("multiprocess_cpu_collectives", None))
-    return findings
-
-
 @mesh_pass("mesh-spec")
 def check_mesh_specs(mctx):
     diags = []
-    # ---- structural rules, per call site and per arg --------------
     for use in mctx.uses:
         specs = [("in", n, s, sh) for n, s, sh in
                  zip(use.arg_names, use.in_specs, use.arg_shapes)]
@@ -121,48 +74,5 @@ def check_mesh_specs(mctx):
                     var_names=[name],
                     hint="fix the PartitionSpec or the mesh axis "
                          "sizes; this exact config fails at trace "
-                         "time on every jax"))
-    # ---- API-capability verdicts ----------------------------------
-    active = _cap.active_profile()
-    seen = set()
-    for cap, use in capability_findings(mctx):
-        key = (cap, use.name if use else None)
-        if key in seen:
-            continue
-        seen.add(key)
-        ok_active = _cap.supports(active, cap)
-        where = f"shard_map {use.name!r}" if use else \
-            f"{mctx.processes}-process {mctx.backend or 'cpu'} config"
-        offending = ""
-        if use is not None and use.in_specs:
-            offending = (" (in_specs: " + ", ".join(
-                spec_str(s) for s in use.in_specs) + ")")
-        clause = _verdict_clause(cap)
-        if not ok_active:
-            diags.append(Diagnostic(
-                ERROR, "mesh-spec",
-                f"{where}: capability {cap!r} is unsupported on the "
-                f"active API ({active}){offending} — {clause}",
-                var_names=[use.name] if use else [],
-                hint="restructure to avoid the construct on this "
-                     "image (e.g. keep vjp inside the body like the "
-                     "1F1B path), or run on a jax that supports it"))
-        elif any(not _cap.supports(p, cap)
-                 for p in _cap.api_profiles()):
-            # fine here, breaks on the OTHER API: portability warning
-            diags.append(Diagnostic(
-                WARNING, "mesh-spec",
-                f"{where}: capability {cap!r} diverges across APIs"
-                f"{offending} — {clause}",
-                var_names=[use.name] if use else [],
-                hint="portable configs avoid API-divergent "
-                     "constructs"))
-    # check_vma is shimmed, not native, on 0.4.37 — say so once
-    if active == _cap.PROFILE_SHIM and any(
-            use.check_disabled for use in mctx.uses):
-        diags.append(Diagnostic(
-            INFO, "mesh-spec",
-            "check_vma=False is translated to check_rep=False by the "
-            "paddle_tpu shim on this image "
-            f"({_cap.explain(_cap.PROFILE_SHIM, 'shard_map.check_vma_kwarg')})"))
+                         "time"))
     return diags

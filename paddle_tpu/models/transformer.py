@@ -608,7 +608,7 @@ class IncrementalDecoder:
         # single-token ragged decode kernel for the fp32 cache, the
         # fused dequantize-attend for the int8 cache. Each call below
         # still self-gates (try_* convention) — None keeps the exact
-        # jnp composition, and PADDLE_TPU_KERN=off never loads kern.
+        # jnp composition.
         from ..ops.registry import accel as _accel, lowering_for
         fused_dequant = _accel("dequant_attend_int8") if quant else None
         fused_decode = None if quant else _accel("decode_attend")

@@ -4,14 +4,10 @@ Owns Pallas dispatch end-to-end (ROADMAP item 3, the TPP thesis: a
 small set of tuned, registered primitives beats ad-hoc lowering):
 
 - registry.py       KernelSpec records (capability probe, jnp reference
-                    composition, numerics tolerance, tune space) and the
+                    composition, numerics tolerance) and the counting
                     dispatch that op kernels reach through the ONE seam
-                    in ops/registry.py (`accel`).
-- autotune.py       block-size search harness; tuned configs cached per
-                    (shape, dtype, platform) key the way the compile
-                    cache keys executables — PADDLE_TPU_KERN_CACHE dir
-                    with atomic publish, warm-started from the committed
-                    KERN_TUNED.json baseline.
+                    in ops/registry.py (`accel`), beside the gate
+                    (`active`) every kernel's try_* asks.
 - quant.py          the shared int8 blockwise quantize/dequantize
                     primitive (gradsync buckets, the KV cache, and the
                     collective wire all route here).
@@ -23,10 +19,7 @@ small set of tuned, registered primitives beats ad-hoc lowering):
 Import discipline: this package body is LAZY (PEP 562). Importing
 `ops.kern` (or the pure-jnp `ops.kern.quant`, which every int8 producer
 shares) loads no Pallas code; the registry and its kernel modules load
-only when ops.registry.accel() — which checks the PADDLE_TPU_KERN
-switch first — actually resolves an adapter. Registry-off paths
-therefore never import the kernel machinery or ops/pallas/ (pinned in
-tests/test_bench_contract.py).
+when ops.registry.accel() first resolves an adapter.
 """
 import importlib
 
@@ -37,7 +30,7 @@ __all__ = ["KernelSpec", "register", "get", "names", "specs", "adapter",
 _API = ("KernelSpec", "register", "get", "names", "specs", "adapter",
         "dispatch", "parity_check", "STATS", "KERN_SPECS", "ADAPTERS")
 
-_LAZY = ("autotune", "quant", "decode_attention")
+_LAZY = ("quant", "decode_attention")
 
 
 def __getattr__(name):

@@ -36,7 +36,7 @@ row, not NaN.
 Perf gates (auto mode only; interpret bypasses): MIN_T_DECODE /
 MIN_T_DEQUANT. Defaults are conservative and UNMEASURED on real chips
 — the expected crossover by the flash MIN_SEQ_LEN analogy, pending an
-on-chip sweep via `tools/tpukern.py tune`.
+on-chip sweep (no benchmark cell runs them yet: PERF.md 7.1).
 """
 import functools
 
@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from ..pallas import flash_attention as fa
+from ..registry import active
 
 if fa._HAS_PALLAS:
     from jax.experimental import pallas as pl
@@ -318,23 +319,22 @@ def probe_dequant(q, kq, ks, vq, vs, pos, scale=None, *,
 
 
 # ----------------------------------------------------------- dispatch
-def try_decode_attend(q, k, v, pos, scale=None, block_t=None):
+def try_decode_attend(q, k, v, pos, scale=None):
     """try_* dispatch entry (the house policy shape): result or None."""
-    use, interpret = fa.active()
+    use, interpret = active()
     if not use:
         return None
     if not probe_decode(q, k, v, pos, scale, interpret=interpret):
         return None
-    return decode_attend(q, k, v, pos, scale, block_t, interpret)
+    return decode_attend(q, k, v, pos, scale, interpret=interpret)
 
 
-def try_dequant_attend(q, kq, ks, vq, vs, pos, scale=None,
-                       block_t=None):
-    use, interpret = fa.active()
+def try_dequant_attend(q, kq, ks, vq, vs, pos, scale=None):
+    use, interpret = active()
     if not use:
         return None
     if not probe_dequant(q, kq, ks, vq, vs, pos, scale,
                          interpret=interpret):
         return None
-    return dequant_attend(q, kq, ks, vq, vs, pos, scale, block_t,
-                          interpret)
+    return dequant_attend(q, kq, ks, vq, vs, pos, scale,
+                          interpret=interpret)

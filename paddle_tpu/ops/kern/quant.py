@@ -24,8 +24,8 @@ should FUSE into the consumer instead of materializing fp32 (that is
 exactly what decode_attention.dequant_attend does for the KV cache).
 
 This module imports NO Pallas code at module level (every int8
-producer imports it, including registry-off paths — the pallas pieces
-load lazily inside the kernel entry points only).
+producer imports it — the pallas pieces load lazily inside the kernel
+entry points only).
 """
 import jax
 import jax.numpy as jnp
@@ -134,24 +134,22 @@ def probe_quant(flat, block_size=256, *, interpret=False):
     return bool(_pick_rows(n // block_size, block_size))
 
 
-def try_quantize(flat, block_size=256, block_rows=None):
+def try_quantize(flat, block_size=256):
     """try_* dispatch entry: the fused kernel's (codes, scales), or
     None -> caller runs the jnp reference."""
-    from ..pallas import flash_attention as fa
-    use, interpret = fa.active()
+    from ..registry import active
+    use, interpret = active()
     if not use:
         return None
     if not probe_quant(flat, block_size, interpret=interpret):
         return None
-    return quantize_int8_pallas(flat, block_size, block_rows, interpret)
+    return quantize_int8_pallas(flat, block_size, interpret=interpret)
 
 
 def quantize_int8_blockwise(flat, block_size=256):
-    """THE shared entry every int8 producer calls: registry-dispatched
-    fused kernel when the kern registry is enabled and the probe
-    passes, else the jnp reference — same bits either way. Routes
-    through the ops.registry.accel seam so registry-off runs load no
-    kernel machinery at all."""
+    """THE shared entry every int8 producer calls: the fused kernel
+    where its gate and probe pass (through the ops.registry.accel
+    seam), else the jnp reference — same bits either way."""
     from ..registry import accel
     fused = accel("int8_quant")
     if fused is not None:

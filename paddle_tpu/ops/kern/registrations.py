@@ -3,8 +3,7 @@
 One KernelSpec per kernel: the try_* dispatch entry, the jnp reference
 composition it must match, a STATIC capability probe (runs on
 jax.ShapeDtypeStruct — meshlint and the CLI probe without data), the
-parity tolerance, the autotuner's shape signature + candidate space +
-staleness re-probe, and a small interpret-runnable example for the
+parity tolerance, and a small interpret-runnable example for the
 selftest gate.
 
 The probes mirror each try_* function's own acceptance conditions
@@ -22,10 +21,6 @@ from ..pallas import grouped_matmul as gm
 from . import decode_attention as da
 from . import quant
 from .registry import KernelSpec, register
-
-
-def _shape(x):
-    return tuple(int(d) for d in x.shape)
 
 
 # ------------------------------------------------------------ layer_norm
@@ -63,24 +58,6 @@ def _ln_probe(x, scale, bias, eps, begin_norm_axis, *, interpret=False,
     return True
 
 
-def _ln_space(x, *a, **kw):
-    rows, C = x.shape[-2], x.shape[-1]
-    out = []
-    for br in (8, 16, 32, 64, 128, 256, 512):
-        if br <= rows and rows % br == 0 and br * C <= ln._BLOCK_BUDGET:
-            out.append({"block_rows": br})
-    return out
-
-
-def _ln_config_ok(cfg, x, *a, **kw):
-    br = cfg.get("block_rows")
-    if br is None:
-        return not cfg
-    rows, C = x.shape[-2], x.shape[-1]
-    return (br % 8 == 0 or br == rows) and rows % br == 0 \
-        and br * C <= ln._BLOCK_BUDGET
-
-
 def _ln_example(rng):
     x = jnp.asarray(rng.standard_normal((16, 128)), jnp.float32)
     g = jnp.asarray(rng.standard_normal(128), jnp.float32)
@@ -95,9 +72,6 @@ register(KernelSpec(
     probe=_ln_probe,
     tol=(2e-5, 2e-5),
     op_types=("layer_norm",),
-    signature=lambda x, *a, **kw: _shape(x),
-    tune_space=_ln_space,
-    config_ok=_ln_config_ok,
     example=_ln_example,
     note="fused minor-axis LayerNorm, fwd+bwd (custom_vjp)",
 ))
@@ -132,30 +106,6 @@ def _flash_probe(q, k, v, bias=None, causal=False, scale=None,
     return fa.supports(*_flash_bhtd(q, k, v, layout), bias=bias)
 
 
-def _flash_space(q, k, v, *a, layout="bhtd", **kw):
-    q, k, v = _flash_bhtd(q, k, v, layout)
-    T, S = q.shape[2], k.shape[2]
-    D, DV = q.shape[-1], v.shape[-1]
-    out = []
-    for bq in (256, 512, 1024, 2048):
-        for bk in (512, 1024, 2048):
-            got = fa._choose_blocks(T, S, D, DV, bq, bk)
-            if got == (bq, bk) and {"block_q": bq, "block_k": bk} \
-                    not in out:
-                out.append({"block_q": bq, "block_k": bk})
-    return out
-
-
-def _flash_config_ok(cfg, q, k, v, *a, layout="bhtd", **kw):
-    bq, bk = cfg.get("block_q"), cfg.get("block_k")
-    if bq is None and bk is None:
-        return not cfg
-    q, k, v = _flash_bhtd(q, k, v, layout)
-    T, S = q.shape[2], k.shape[2]
-    return fa._choose_blocks(T, S, q.shape[-1], v.shape[-1],
-                             bq, bk) == (bq, bk)
-
-
 def _flash_example(rng):
     q = jnp.asarray(rng.standard_normal((1, 2, 128, 64)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, 2, 128, 64)), jnp.float32)
@@ -170,11 +120,6 @@ register(KernelSpec(
     probe=_flash_probe,
     tol=(2e-5, 2e-5),
     op_types=("flash_attention",),
-    signature=lambda q, k, v, *a, layout="bhtd", **kw: (
-        _shape(q) + (fa._tiled_dims(q, k, layout)[1], v.shape[-1])
-        + ((layout,) if layout != "bhtd" else ())),
-    tune_space=_flash_space,
-    config_ok=_flash_config_ok,
     example=_flash_example,
     note="fused attention, fwd+bwd (custom_vjp): one-tile kernel on "
          "[B,T,H*D] for short sequences, tiled online-softmax for long",
@@ -196,26 +141,6 @@ def _emb_probe(table, inv, weights=None, pool="sum", *,
     return bool(br) and (R // br) * br == R
 
 
-def _emb_space(table, inv, *a, **kw):
-    R = inv.shape[0]
-    out = []
-    for br in (8, 16, 32, 64, 128, 256, 512):
-        if br <= R and R % br == 0:
-            out.append({"block_rows": br})
-    return out
-
-
-def _emb_config_ok(cfg, table, inv, *a, **kw):
-    br = cfg.get("block_rows")
-    if br is None:
-        return not cfg
-    C, D = table.shape
-    R, F = inv.shape
-    if R % br or (br % 8 and br != R):
-        return False
-    return C * D + br * (C + D + F) <= emb._VMEM_BUDGET
-
-
 def _emb_example(rng):
     table = jnp.asarray(rng.standard_normal((64, 128)), jnp.float32)
     inv = jnp.asarray(rng.randint(-1, 64, size=(16, 4)), jnp.int32)
@@ -229,29 +154,12 @@ register(KernelSpec(
     probe=_emb_probe,
     tol=(2e-5, 2e-5),
     op_types=("lookup_pool", "fused_embedding_seq_pool"),
-    signature=lambda table, inv, *a, **kw: (_shape(table) + _shape(inv)),
-    tune_space=_emb_space,
-    config_ok=_emb_config_ok,
     example=_emb_example,
     note="fused embedding lookup+pool (one-hot MXU gather)",
 ))
 
 
 # ---------------------------------------------------------- decode_attend
-def _da_space(q, k, v, pos, *a, **kw):
-    T = k.shape[1]
-    out = []
-    for bt in (128, 256, 512, 1024):
-        if fa._pick_block(T, bt) == bt:
-            out.append({"block_t": bt})
-    return out
-
-
-def _da_config_ok(cfg, q, k, v, pos, *a, **kw):
-    bt = cfg.get("block_t")
-    if bt is None:
-        return not cfg
-    return fa._pick_block(k.shape[1], bt) == bt
 
 
 def _da_example(rng):
@@ -270,29 +178,12 @@ register(KernelSpec(
     probe=da.probe_decode,
     tol=(2e-5, 2e-5),
     op_types=("decode_attend",),
-    signature=lambda q, k, v, pos, *a, **kw: (_shape(q) + (k.shape[1],)),
-    tune_space=_da_space,
-    config_ok=_da_config_ok,
     example=_da_example,
     note="single-token ragged decode attention over the slot pool",
 ))
 
 
 # ----------------------------------------------------- dequant_attend_int8
-def _dq_space(q, kq, ks, vq, vs, pos, *a, **kw):
-    T = kq.shape[1]
-    out = []
-    for bt in (128, 256, 512, 1024):
-        if fa._pick_block(T, bt) == bt:
-            out.append({"block_t": bt})
-    return out
-
-
-def _dq_config_ok(cfg, q, kq, *a, **kw):
-    bt = cfg.get("block_t")
-    if bt is None:
-        return not cfg
-    return fa._pick_block(kq.shape[1], bt) == bt
 
 
 def _dq_example(rng):
@@ -318,36 +209,16 @@ register(KernelSpec(
     probe=da.probe_dequant,
     tol=(2e-5, 2e-5),
     op_types=("dequant_attend_int8",),
-    signature=lambda q, kq, ks, *a, **kw: (_shape(q) + (kq.shape[1],)
-                                           + (ks.shape[-1],)),
-    tune_space=_dq_space,
-    config_ok=_dq_config_ok,
     example=_dq_example,
     note="fused int8 dequantize-attend over the block-quantized KV cache",
 ))
 
 
 # -------------------------------------------------------------- int8_quant
-def _q_space(flat, block_size=256, **kw):
-    nb = flat.shape[0] // max(block_size, 1)
-    out = []
-    for br in (64, 128, 256, 512, 1024):
-        if quant._pick_rows(nb, block_size, br) == br:
-            out.append({"block_rows": br})
-    return out
-
-
-def _q_config_ok(cfg, flat, block_size=256, **kw):
-    br = cfg.get("block_rows")
-    if br is None:
-        return not cfg
-    nb = flat.shape[0] // max(block_size, 1)
-    return quant._pick_rows(nb, block_size, br) == br
 
 
 def _q_example(rng):
-    # 1024 blocks: enough rows that the tune ladder (128-multiple row
-    # tiles) has real candidates
+    # 1024 blocks: two row tiles of the default 512
     flat = jnp.asarray(rng.standard_normal(1024 * 256), jnp.float32)
     # a zero block exercises the safe-scale path
     flat = flat.at[:256].set(0.0)
@@ -364,18 +235,12 @@ register(KernelSpec(
     # reduction order
     tol=(0.0, 1e-7),
     op_types=("int8_quant",),
-    signature=lambda flat, block_size=256, **kw: (flat.shape[0],
-                                                  block_size),
-    tune_space=_q_space,
-    config_ok=_q_config_ok,
     example=_q_example,
     note="shared int8 blockwise quantize (EQuARX wire format)",
 ))
 
 
 # ---------------------------------------------------------- moe_expert_ffn
-def _moe_space(x, idx, tw, w1, *a, **kw):
-    return [{"tile_rows": t} for t in (256, 512, 1024)]
 
 
 def _moe_example(rng):
@@ -397,11 +262,6 @@ register(KernelSpec(
     probe=gm.supports,
     tol=(2e-5, 2e-5),
     op_types=("moe_expert_ffn",),
-    signature=lambda x, idx, tw, w1, *a, **kw: (
-        _shape(x) + _shape(idx)[1:] + _shape(w1)),
-    tune_space=_moe_space,
-    config_ok=lambda cfg, *a, **kw: not cfg or cfg.get("tile_rows", 0) % 16
-    == 0,
     example=_moe_example,
     note="no-drop expert FFN of the experts held here: grouped products "
          "over pairs sorted by expert, fwd+bwd (custom_vjp)",

@@ -5,10 +5,11 @@ kernels through OpKernelType/REGISTER_OP_CUDA_KERNEL — a (place, dtype,
 layout) key picked at run time per op. Here the registry holds a
 KernelSpec per Pallas kernel: a STATIC capability probe (shapes/dtypes
 the kernel accepts — the PR-9 embedding-template gate), the jnp
-reference composition it must match, a numerics tolerance for the
-parity gate, and a block-size tune space for the autotuner. Dispatch
-is trace-time: the op kernel asks through ops.registry.accel(), gets
-the kernel result or None, and lowers its own jnp fallback on None —
+reference composition it must match, and a numerics tolerance for the
+parity gate. Block sizes are each kernel's own defaults, swept on the
+chip through the kernel's own function. Dispatch is trace-time: the
+op kernel asks through ops.registry.accel(), gets the kernel result
+or None, and lowers its own jnp fallback on None —
 exactly the try_* convention the three original pallas modules used,
 now behind one seam instead of three ad-hoc import sites.
 
@@ -34,9 +35,7 @@ class KernelSpec:
     name        registry key ("flash_attention", "decode_attend", ...)
     fn          THE dispatch entry (try_* convention): self-gates on
                 active() + its own probe, returns the kernel result or
-                None -> caller lowers the jnp fallback. Accepts the
-                tune-space config keys as kwargs (block_q, block_rows,
-                ...).
+                None -> caller lowers the jnp fallback.
     reference   jnp reference composition with the same user-level
                 signature as fn — the numerics ground truth.
     probe       fn(*args, interpret=False, **kw) -> bool. STATIC
@@ -47,12 +46,6 @@ class KernelSpec:
     op_types    dispatch-seam keys this kernel serves: op type strings
                 ("layer_norm") and/or library-call names
                 ("dequant_attend_int8"). Defaults to (name,).
-    signature   fn(*args, **kw) -> hashable shape signature for the
-                autotune cache key (None = not tunable).
-    tune_space  fn(*args, **kw) -> [candidate config dicts].
-    config_ok   fn(config, *args, **kw) -> bool: is a loaded (possibly
-                stale) tuned config still legal for these args? A
-                config failing this falls back to default blocks.
     example     fn(rng: np.random.RandomState) -> (args, kwargs) —
                 small interpret-runnable inputs for the CLI/selftest
                 parity gate.
@@ -60,17 +53,13 @@ class KernelSpec:
     """
 
     def __init__(self, name, fn, reference, probe, tol=(2e-5, 2e-5),
-                 op_types=None, signature=None, tune_space=None,
-                 config_ok=None, example=None, note=""):
+                 op_types=None, example=None, note=""):
         self.name = name
         self.fn = fn
         self.reference = reference
         self.probe = probe
         self.tol = tuple(tol)
         self.op_types = tuple(op_types or (name,))
-        self.signature = signature
-        self.tune_space = tune_space or (lambda *a, **k: [])
-        self.config_ok = config_ok or (lambda cfg, *a, **k: True)
         self.example = example
         self.note = note
 
@@ -104,14 +93,9 @@ def specs():
 
 
 def dispatch(name, *args, **kwargs):
-    """Run kernel `name` with its tuned config merged in; the result,
-    or None when fn's own gate rejects (backend, mode, shapes). The
-    autotuner consult is read-only here — explicit `tpukern tune` or
-    PADDLE_TPU_KERN_AUTOTUNE=1 populates the cache."""
-    spec = get(name)
-    from . import autotune
-    cfg = autotune.tuned_config(spec, args, kwargs)
-    out = spec.fn(*args, **kwargs, **cfg)
+    """Run kernel `name` and count the call: the result, or None when
+    fn's own gate rejects (backend, mode, shapes)."""
+    out = get(name).fn(*args, **kwargs)
     STATS["dispatches"] += 1
     per = STATS["by_kernel"].setdefault(name, {"accepted": 0,
                                                "rejected": 0})

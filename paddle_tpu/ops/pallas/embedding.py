@@ -43,7 +43,7 @@ try:
 except Exception:  # pragma: no cover
     _HAS_PALLAS = False
 
-from .flash_attention import active
+from ..registry import active
 
 __all__ = ["lookup_pool", "lookup_pool_reference", "try_lookup_pool",
            "STATS"]
@@ -56,17 +56,15 @@ STATS = {"pallas_calls": 0}
 _VMEM_BUDGET = 1536 * 1024
 
 
-def _pick_rows(R, C, D, F, pref=None):
+def _pick_rows(R, C, D, F):
     """Largest row block (multiple of 8, or R itself) that divides R
-    and fits the budget next to the resident [C, D] table. 0 if none.
-    `pref` caps the preference below the VMEM-derived one (the kern
-    autotuner's knob)."""
+    and fits the budget next to the resident [C, D] table. 0 if none."""
     table = C * D
     if table >= _VMEM_BUDGET:
         return 0
     per_row = C + D + F          # one-hot row + out row + inv row
     cap = (_VMEM_BUDGET - table) // max(per_row, 1)
-    pref = max(8, min(R, cap, pref or cap))
+    pref = max(8, min(R, cap))
     if pref >= R:
         return R
     for b in range(pref // 8 * 8, 0, -8):
@@ -189,13 +187,11 @@ lookup_pool.defvjp(_fwd_vjp, _bwd_vjp)
 from ..kernels_extra import lookup_pool_reference  # noqa: E402
 
 
-def try_lookup_pool(table, inv, weights=None, pool="sum",
-                    block_rows=None):
+def try_lookup_pool(table, inv, weights=None, pool="sum"):
     """THE dispatch policy: the fused kernel's result, or None → caller
     falls back to lookup_pool_reference. Requirements: Pallas active,
     2D table/inv, a known pool mode, and table + row block within the
-    VMEM budget. block_rows caps the row-block preference (the kern
-    autotuner's knob); _pick_rows still legalizes it."""
+    VMEM budget."""
     use_pallas, interpret = active()
     if not use_pallas or pool not in ("sum", "mean"):
         return None
@@ -205,7 +201,7 @@ def try_lookup_pool(table, inv, weights=None, pool="sum",
     R, F = inv.shape
     if R < 8:
         return None
-    br = _pick_rows(R, C, D, F, block_rows)
+    br = _pick_rows(R, C, D, F)
     if not br or (R // br) * br != R:
         return None
     return lookup_pool(table, inv.astype(jnp.int32), weights, pool,

@@ -61,8 +61,9 @@ Supported extras (covers the flagship transformer end-to-end):
   zero. Do not read fully-masked rows from the plain `flash_attention`
   output.
 
-The tiled kernel's block sizes default to 1024x2048 (tuned on v5e;
-clamped to a VMEM budget per head dim, see _choose_blocks).
+The tiled kernel's block sizes default to 1024x2048 (clamped to a
+VMEM budget per head dim, see _choose_blocks); what the chip measured
+of it is in PERF.md sections 5 and 7.6.
 
 When to use which path is try_flash's to say, and only its: the short
 kernel for `bthd` arrays with both lengths 256, 384 or 512; the tiled
@@ -76,14 +77,12 @@ runtime with no shape stated, and the table above SHORT_MIN_SEQ_LEN
 replaces it for the op's path only. Interpret mode (CPU tests) bypasses
 the performance gates.
 
-Measured regime note (v5e, D=64, T=32k causal): ~0.2 attn-MFU fwd+bwd
-with the default 1024x2048 blocks — a swept optimum (512/256-row and
-1024-col variants are 2-48% slower). The bound is the VPU, not the MXU:
-per score element the kernel does 2D=128 MXU flops against ~10 VPU ops
-(exp/max/mul in f32), so at D=64 the exp pipeline saturates first.
-attn-MFU rises with head dim.
+Where the tiled kernel's time goes at D=64 (the share of its roofline
+each of its three kernels reaches, and why) is in PERF.md sections 5
+and 7.6: per score element it does 2D=128 MXU flops against ~10 VPU
+ops (exp/max/mul in f32).
 
-The named escape is implemented behind `softmax_dtype`: with
+An escape from that VPU cost is implemented behind `softmax_dtype`: with
 jnp.bfloat16, the probability exp (the dominant VPU cost — one
 transcendental per score element in fwd, dq AND dkv) runs in bf16 while
 everything that controls numerics stays f32: the scores matmul
@@ -104,6 +103,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+# the gate lives beside mosaic_target in ops/registry.py; the two names
+# are re-exported for the callers that spell them fa.set_mode / fa.active
+from ..registry import active, set_mode  # noqa: F401
 
 try:
     from jax.experimental import pallas as pl
@@ -161,27 +164,16 @@ MIN_SEQ_LEN_BTHD = 1024
 # run under value_and_grad, not silently fall back).
 STATS = {"pallas_calls": 0}
 
-# "auto": Pallas iff the program is lowered for a TPU; "interpret": force
-# the kernel through the Pallas interpreter (CPU tests); "off": jnp fallback.
-_MODE = "auto"
-
 # m/l scratch rows are stored lane-replicated at this width (1-lane
 # vectors are not a legal VMEM tile).
 _LANES = 128
 
-# Tuned on v5e (block sweeps at T=8192 and T=32768: 1024x2048 is ~12%
-# faster than 512x1024 at 32k and ties at 8k; 2048x2048 fails to compile
-# — the fp32 scores tile exceeds VMEM): shared by supports() and
-# flash_attention() so the dispatch guard and the call can't drift.
-# _prep clamps the pair to a VMEM budget for larger head dims.
+# Shared by supports() and flash_attention() so the dispatch guard and
+# the call can't drift (2048x2048 fails to compile: the fp32 scores tile
+# exceeds VMEM). _prep clamps the pair to a VMEM budget for larger head
+# dims. The kernels' readings at these blocks: PERF.md 5 and 7.6.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 2048
-
-
-def set_mode(mode):
-    global _MODE
-    assert mode in ("auto", "interpret", "off")
-    _MODE = mode
 
 
 # dtype of the probability exp inside the kernels; f32 = exact flash
@@ -197,18 +189,6 @@ def set_softmax_dtype(dtype):
     dtype = jnp.dtype(dtype)
     assert dtype in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16))
     _SOFTMAX_DTYPE = dtype
-
-
-def active():
-    """(use_pallas, interpret) for the trace in progress: compiled
-    kernels only where the program is being lowered for a TPU
-    (ops.registry.mosaic_target), the interpreter when forced."""
-    if not _HAS_PALLAS or _MODE == "off":
-        return False, False
-    if _MODE == "interpret":
-        return True, True
-    from ..registry import mosaic_target
-    return mosaic_target(), False
 
 
 def _pick_block(n, pref):
@@ -1161,7 +1141,7 @@ def tiled_min_len(with_lse=False, layout="bhtd"):
 
 
 def try_flash(q, k, v, bias=None, causal=False, scale=None, with_lse=False,
-              causal_offset=0, block_q=None, block_k=None, layout="bhtd"):
+              causal_offset=0, layout="bhtd"):
     """THE dispatch policy, in one place (used by ops/kernels_nn.py,
     parallel/ring_attention.py, parallel/ulysses.py): returns a Pallas
     kernel's result (`out`, or `(out, lse)` with `with_lse`) in the
@@ -1180,9 +1160,7 @@ def try_flash(q, k, v, bias=None, causal=False, scale=None, with_lse=False,
 
     Interpret mode (CPU tests) bypasses the performance gates, not the
     shape tests. `with_lse` and `causal_offset` callers (ring attention)
-    are served by the tiled kernel only. block_q/block_k override its
-    default tile preference (the kern autotuner's knob); _prep still
-    re-legalizes them through _choose_blocks."""
+    are served by the tiled kernel only."""
     use_pallas, interpret = active()
     if not use_pallas:
         return None
@@ -1202,12 +1180,9 @@ def try_flash(q, k, v, bias=None, causal=False, scale=None, with_lse=False,
     if with_lse:
         out, lse = flash_attention_with_lse(
             q, k, v, bias=bias, causal=causal, scale=scale,
-            interpret=interpret, block_q=block_q, block_k=block_k,
-            causal_offset=causal_offset)
+            interpret=interpret, causal_offset=causal_offset)
         return (out.swapaxes(1, 2) if bthd else out), lse
     out = flash_attention(q, k, v, bias=bias, causal=causal, scale=scale,
-                          block_q=block_q or DEFAULT_BLOCK_Q,
-                          block_k=block_k or DEFAULT_BLOCK_K,
                           interpret=interpret,
                           causal_offset=causal_offset)
     return out.swapaxes(1, 2) if bthd else out

@@ -58,7 +58,7 @@ try:
 except Exception:  # pragma: no cover
     _HAS_PALLAS = False
 
-from .flash_attention import active
+from ..registry import active
 
 __all__ = ["expert_ffn", "expert_ffn_reference", "try_expert_ffn",
            "supports", "make_plan", "DEFAULT_TILE_ROWS"]
@@ -610,10 +610,10 @@ def expert_ffn_reference(x, topk_idx, topk_w, w1, w3, w2, first_expert=0,
 
 
 def supports(x, topk_idx, topk_w, w1, w3, w2, first_expert=0,
-             tile_rows=None, interpret=False, **_kw):
+             interpret=False, **_kw):
     """Static shape test: 2-D tokens, one float dtype for x and the
-    weights, lane-aligned widths and a sublane-aligned tile on the chip
-    (the interpreter takes any)."""
+    weights, lane-aligned widths on the chip (the interpreter takes
+    any)."""
     if not _HAS_PALLAS or getattr(x, "ndim", 0) != 2 or w1.ndim != 3:
         return False
     E, H, F = w1.shape
@@ -623,20 +623,17 @@ def supports(x, topk_idx, topk_w, w1, w3, w2, first_expert=0,
         return False
     if not (x.dtype == w1.dtype == w3.dtype == w2.dtype):
         return False
-    tm = int(tile_rows or DEFAULT_TILE_ROWS)
-    return interpret or (H % _LANES == 0 and F % _LANES == 0
-                         and tm % 16 == 0)
+    return interpret or (H % _LANES == 0 and F % _LANES == 0)
 
 
-def try_expert_ffn(x, topk_idx, topk_w, w1, w3, w2, first_expert=0,
-                   tile_rows=None):
+def try_expert_ffn(x, topk_idx, topk_w, w1, w3, w2, first_expert=0):
     """The dispatch entry (try_* convention): (out, counts) through the
     kernels, or None and the op lowers its own composition."""
     use_pallas, interpret = active()
     if not use_pallas or not supports(x, topk_idx, topk_w, w1, w3, w2,
-                                      first_expert, tile_rows, interpret):
+                                      first_expert, interpret=interpret):
         return None
-    if interpret and tile_rows is None:
-        tile_rows = 8      # the tests' sizes: several tiles an expert
+    # the tests' sizes: several tiles an expert
+    tile_rows = 8 if interpret else None
     return expert_ffn(x, topk_idx, topk_w, w1, w3, w2, first_expert,
                       tile_rows, interpret)

@@ -38,7 +38,7 @@ try:
 except Exception:  # pragma: no cover
     _HAS_PALLAS = False
 
-from .flash_attention import active
+from ..registry import active
 
 __all__ = ["layer_norm", "try_layer_norm", "STATS"]
 
@@ -208,14 +208,12 @@ def _bwd_vjp(eps, block_rows, interpret, res, dy):
 layer_norm.defvjp(_fwd_vjp, _bwd_vjp)
 
 
-def try_layer_norm(x, scale, bias, eps, begin_norm_axis,
-                   block_rows=None):
+def try_layer_norm(x, scale, bias, eps, begin_norm_axis):
     """THE dispatch policy: returns (y, mean, var) on the Pallas path or
     None → caller falls back to the fused-XLA composition. Requirements:
     Pallas active, norm over exactly the minor axis, affine params
     present, C a lane multiple (or small-array full tile), and a legal
-    row block. block_rows overrides the picked 2D row block (the kern
-    autotuner's knob); an illegal override is ignored, not fatal."""
+    row block."""
     use_pallas, interpret = active()
     if not use_pallas or scale is None or bias is None:
         return None
@@ -234,10 +232,6 @@ def try_layer_norm(x, scale, bias, eps, begin_norm_axis,
     br = _pick_rows(rows, C)
     if not br or (rows // br) * br != rows:
         return None
-    if block_rows and rows % block_rows == 0 \
-            and (block_rows % 8 == 0 or block_rows == rows) \
-            and block_rows * C <= _BLOCK_BUDGET:
-        br = block_rows
     # 3D blocks span at least one whole [T, C] slab — gate it to the
     # VMEM budget or the kernel would fail in Mosaic lowering on shapes
     # the jnp fallback handles fine

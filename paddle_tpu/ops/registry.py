@@ -14,30 +14,22 @@ Kernel signature:
     .is_test  executor mode (inference disables dropout etc.)
     .place    the target Place
     .accel    the Pallas dispatch seam (see accel() below)
+
+How an op reaches a Pallas kernel, all of it: the op kernel asks
+ctx.accel(op_type); accel() looks the op type up in the kern table and
+hands back a callable that counts the call and runs the kernel's try_*
+entry; try_* asks active() (may this trace lower a kernel at all) and
+then its own shape / length policy, and returns the kernel's result or
+None, on which the op lowers its jnp composition.
 """
 import contextlib
-import os
 import threading
 
 __all__ = ["kernel", "get_kernel", "has_kernel", "closest_kernels",
-           "KernelCtx", "KERNELS", "autocast", "accel", "kern_enabled",
-           "ENV_KERN", "lowering_for", "mosaic_target"]
+           "KernelCtx", "KERNELS", "autocast", "accel", "lowering_for",
+           "mosaic_target", "set_mode", "active"]
 
 KERNELS = {}
-
-# THE registry switch: PADDLE_TPU_KERN=off|0|false disables the kern
-# subsystem entirely — accel() returns None before ops/kern (and thus
-# ops/pallas) is ever imported, so every op kernel lowers its jnp
-# fallback, byte-identical to a build without the subsystem (pinned in
-# tests/test_bench_contract.py). Default is on: dispatch still
-# self-gates per kernel on backend/mode/shape.
-ENV_KERN = "PADDLE_TPU_KERN"
-
-
-def kern_enabled():
-    return os.environ.get(ENV_KERN, "").lower() not in ("off", "0",
-                                                        "false")
-
 
 _lowering = threading.local()
 
@@ -76,15 +68,37 @@ def mosaic_target():
     return platform == "tpu" and not partitioned
 
 
+# "auto": kernels iff the trace's target is a TPU (mosaic_target);
+# "interpret": every kernel through the Pallas interpreter (CPU tests);
+# "off": no kernel, every op lowers its jnp composition.
+_MODE = "auto"
+
+
+def set_mode(mode):
+    global _MODE
+    assert mode in ("auto", "interpret", "off")
+    _MODE = mode
+
+
+def active():
+    """(use_pallas, interpret) for the trace in progress — THE gate
+    every kernel's try_* entry asks first: compiled kernels only where
+    the program is being lowered for a TPU (mosaic_target), the
+    interpreter when forced, nothing when off."""
+    if _MODE == "off":
+        return False, False
+    if _MODE == "interpret":
+        return True, True
+    return mosaic_target(), False
+
+
 def accel(op_type):
     """The ONE Pallas dispatch seam: a callable running the registered
     kernel for `op_type` (returns the kernel result, or None when its
     own gate rejects — the try_* convention), or None when the kern
-    registry is off or holds nothing for this op. Op kernels reach this
-    through ctx.accel; trace-time lowering consults the registry here
-    instead of per-call-site pallas imports."""
-    if not kern_enabled():
-        return None
+    table holds nothing for this op. Op kernels reach this through
+    ctx.accel; trace-time lowering consults the table here instead of
+    per-call-site pallas imports."""
     from . import kern
     return kern.adapter(op_type)
 

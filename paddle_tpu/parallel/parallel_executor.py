@@ -413,7 +413,6 @@ class ParallelExecutor:
         object verify() lints and tools/tpulint.py serializes. Imports
         meshlint, so only validate-on paths may call it (bench pin)."""
         from ..analysis.meshlint import MeshLintContext
-        import jax as _jax
         param_specs = {n: tuple(sh.spec)
                        for n, sh in self._shardings.items()}
         return MeshLintContext(
@@ -426,8 +425,6 @@ class ParallelExecutor:
             grad_sync=self.grad_sync,
             sparse=(self.sparse_engine.policy
                     if self.sparse_engine is not None else None),
-            processes=_jax.process_count(),
-            backend=_jax.default_backend(),
             param_specs=param_specs,
             memory_cap_bytes=memory_cap_bytes,
             label="ParallelExecutor")
@@ -436,9 +433,9 @@ class ParallelExecutor:
                raise_on_error=True, memory_cap_bytes=None):
         """Static pre-trace verification of this executor's sharded
         config: proglint over the Program (use-before-def, shapes,
-        hazards) plus the meshlint passes (mesh-spec API-capability
-        verdicts, collective consistency, donation aliasing, device
-        footprint, recompile hazards). Runs automatically on each
+        hazards) plus the meshlint passes (mesh specs, collective
+        consistency, donation aliasing, device footprint, recompile
+        hazards). Runs automatically on each
         compile when PADDLE_TPU_VALIDATE=1 (or run(validate=True));
         callable directly for lint-only flows (tools/tpulint.py).
         Returns the combined diagnostics list."""
@@ -579,11 +576,11 @@ class ParallelExecutor:
         if fn is None:
             # opt-in pre-trace verification gate (same tri-state as
             # Executor.run: validate= arg > PADDLE_TPU_VALIDATE env):
-            # proglint + meshlint once per compile, so a bad spec or a
-            # capability the active jax rejects surfaces as a
-            # ProgramVerificationError with a named pass instead of a
-            # _SpecError stack from inside the trace. Cache hits (and
-            # the default validate-off path) never import meshlint.
+            # proglint + meshlint once per compile, so a bad spec
+            # surfaces as a ProgramVerificationError with a named pass
+            # instead of a _SpecError stack from inside the trace. Cache
+            # hits (and the default validate-off path) never import
+            # meshlint.
             from ..core.executor import Executor as _Exec
             if _Exec._validate_requested(validate):
                 self.verify(fetch_list=fetch_names,

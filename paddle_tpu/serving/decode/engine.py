@@ -232,7 +232,7 @@ class DecodeEngine:
             _tm.gauge("serving.decode.kv_cache_bytes").set(
                 self.kv_cache_bytes)
             # kern-registry evidence from the step trace (read via
-            # sys.modules — registry-off runs must not import kern)
+            # sys.modules: a step that asked for no kernel loaded none)
             import sys
             kr = sys.modules.get("paddle_tpu.ops.kern.registry")
             if kr is not None:

@@ -656,66 +656,11 @@ def test_elastic_off_paths_untouched(tmp_path):
     assert "ELASTIC_OFF_OK" in p.stdout
 
 
-def test_kern_off_paths_untouched():
-    """tpukern's off contract (the bench-contract pin, the pattern of
-    PRs 9/10/11/12): with PADDLE_TPU_KERN=off an fp32 infer/decode run
-    imports NEITHER the ops.pallas modules NOR any ops/kern machinery.
-    The int8 KV-cache opt-in may pull the pure-jnp ops.kern.quant
-    module (the shared wire primitive every int8 producer routes
-    through) — but still no pallas, no registry, no registrations, no
-    autotuner."""
-    code = (
-        "import os, sys\n"
-        "import numpy as np\n"
-        "import paddle_tpu as pt\n"
-        "from paddle_tpu.core import framework as fw\n"
-        "from paddle_tpu.models import transformer as tfm\n"
-        "cfg = tfm.TransformerConfig(src_vocab=16, trg_vocab=16,"
-        " max_len=8, d_model=8, d_inner=16, n_head=2, n_layer=1,"
-        " dropout=0.0, label_smooth_eps=0.0)\n"
-        "infer, start = fw.Program(), fw.Program()\n"
-        "with pt.program_guard(infer, start):\n"
-        "    with pt.unique_name.guard():\n"
-        "        tfm.build_infer_program(cfg, maxlen=8)\n"
-        "pt.Executor(pt.CPUPlace()).run(start)\n"
-        "scope = pt.global_scope()\n"
-        "params = {v.name: np.asarray(scope.get(v.name))"
-        " for v in infer.persistable_vars()}\n"
-        "dec = tfm.IncrementalDecoder(cfg, params, num_slots=2,"
-        " max_len=8)\n"
-        "dec.step(dec.init_state(), np.zeros(2, np.int64),"
-        " np.zeros(2, np.int64))\n"
-        "bad = [m for m in sys.modules if"
-        " m.startswith('paddle_tpu.ops.pallas')"
-        " or m == 'paddle_tpu.ops.kern'"
-        " or m.startswith('paddle_tpu.ops.kern.')]\n"
-        "assert not bad, 'fp32 kern-off run imported %s' % bad\n"
-        "deci = tfm.IncrementalDecoder(cfg, params, num_slots=2,"
-        " max_len=8, kv_quant='int8')\n"
-        "deci.step(deci.init_state(), np.zeros(2, np.int64),"
-        " np.zeros(2, np.int64))\n"
-        "bad = [m for m in sys.modules if"
-        " m.startswith('paddle_tpu.ops.pallas') or any(s in m for s in"
-        " ('kern.registry', 'kern.registrations',"
-        " 'kern.decode_attention', 'kern.autotune'))]\n"
-        "assert not bad, 'int8 kern-off run imported %s' % bad\n"
-        "assert 'paddle_tpu.ops.kern.quant' in sys.modules, "
-        "'int8 cache writes must route through the shared primitive'\n"
-        "print('KERN_OFF_OK')\n")
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               PADDLE_TPU_KERN="off")
-    p = subprocess.run([sys.executable, "-c", code], env=env,
-                       capture_output=True, text=True, timeout=240,
-                       cwd=REPO)
-    assert p.returncode == 0, (p.stdout[-400:], p.stderr[-1200:])
-    assert "KERN_OFF_OK" in p.stdout
-
-
 def test_kern_default_dispatch_byte_identical():
-    """Registry ON (the default) on a backend where no Pallas kernel
-    can run (CPU, auto mode): every dispatch rejects at the fn gate
-    and the decode tokens are byte-identical to the registry-off
-    lowering — the seam counts evidence, it never changes numerics."""
+    """On a backend where no Pallas kernel can run (CPU, auto mode)
+    every dispatch rejects at the fn gate and the decode tokens are
+    byte-identical to the kernels-off lowering (set_mode("off")) — the
+    seam counts evidence, it never changes numerics."""
     code = (
         "import os, sys\n"
         "import numpy as np\n"
@@ -749,20 +694,20 @@ def test_kern_default_dispatch_byte_identical():
         "        toks.append(ids.copy())\n"
         "        pos = pos + 1\n"
         "    return np.stack(toks)\n"
-        "os.environ['PADDLE_TPU_KERN'] = 'off'\n"
+        "from paddle_tpu.ops.registry import set_mode\n"
+        "set_mode('off')\n"
         "off = run()\n"
-        "os.environ.pop('PADDLE_TPU_KERN')\n"
+        "set_mode('auto')\n"
         "on = run()\n"
         "assert off.tobytes() == on.tobytes(), "
-        "'registry-on dispatch changed decode tokens'\n"
+        "'auto-mode dispatch changed decode tokens'\n"
         "from paddle_tpu.ops.kern import registry as kreg\n"
         "assert kreg.STATS['dispatches'] > 0, "
-        "'default-on decode never consulted the registry'\n"
+        "'decode never consulted the registry'\n"
         "assert kreg.STATS['accepted'] == 0, "
         "'a Pallas kernel claimed to run on the CPU backend'\n"
         "print('KERN_DEFAULT_OK')\n")
     env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("PADDLE_TPU_KERN", None)
     p = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=240,
                        cwd=REPO)

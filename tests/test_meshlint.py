@@ -1,10 +1,10 @@
 """meshlint — the whole-program sharding & collective static verifier.
 
 Per-pass seeded-defect fixtures (each pass fires with the right
-location and verdict), the capability table's both-API wording, the
-shared ckey vocabulary regression (static diagnostics and the runtime
-recompile explainer must name components with the SAME words), the
-18-red-config classification + LINT_multichip.json baseline, the
+location and verdict), the shared ckey vocabulary regression (static
+diagnostics and the runtime recompile explainer must name components
+with the SAME words), the green control set (the passing parallel
+tests' configs, the 18 multichip ones among them, lint silent), the
 executor/farm verify() gates, and the tpulint CLI."""
 import json
 import os
@@ -74,92 +74,29 @@ def test_static_spec_verdict_pure():
     assert not ok and "dp*tp" in reasons[0]
 
 
-def test_capability_verdict_names_both_apis():
-    v = ml.capability_verdict("shard_map.transpose_pipelined_scan")
-    assert set(v) == {ml.PROFILE_SHIM, ml.PROFILE_CURRENT}
-    assert v[ml.PROFILE_SHIM]["ok"] is False
-    assert "reproduced on this image" in v[ml.PROFILE_SHIM]["why"]
-    assert v[ml.PROFILE_CURRENT]["ok"] is True
-    with pytest.raises(KeyError):
-        ml.supports(ml.PROFILE_SHIM, "no.such.capability")
-
-
-def test_active_profile_is_shim_on_this_image():
-    import jax
-    assert jax.__version__.startswith("0.4.")
-    assert ml.active_profile() == ml.PROFILE_SHIM
-
-
-def test_grad_through_pipelined_scan_flagged_with_verdict():
-    mesh = ml.MeshSpec({"pp": 4})
-    use = ml.ShardMapUse(
-        "pipeline.gpipe", in_specs=[("pp",), ()], out_specs=[()],
-        grad_through=True,
-        body_features=("pipelined_scan", "ppermute"))
-    errs = _errors(ml.run_mesh_passes(
-        ml.MeshLintContext(mesh, uses=[use]), passes=["mesh-spec"]))
-    assert len(errs) == 1
-    msg = errs[0].message
-    assert "shard_map.transpose_pipelined_scan" in msg
-    # the offending specs and BOTH API verdicts are in the one message
-    assert "P('pp')" in msg
-    assert "rejected by jax-0.4.37-shim" in msg
-    assert "accepted by jax-current" in msg
-
-
 def test_inner_vjp_scan_not_flagged():
     """The 1F1B shape — vjp INSIDE the body, no boundary transpose —
-    must stay quiet (test_1f1b_trains is green on this image)."""
+    must stay quiet (test_1f1b_trains is green)."""
     mesh = ml.MeshSpec({"pp": 4})
     use = ml.ShardMapUse(
         "pipeline.1f1b", in_specs=[("pp",), ()],
-        out_specs=[(), ("pp",)], grad_through=False,
-        body_features=("scan", "inner_vjp", "ppermute"))
+        out_specs=[(), ("pp",)])
     assert not _errors(ml.run_mesh_passes(
         ml.MeshLintContext(mesh, uses=[use])))
 
 
-def test_dp_psum_masked_accumulator_flagged():
-    mesh = ml.MeshSpec({"pp": 2, "dp": 4})
-    use = ml.ShardMapUse(
-        "pipeline.1f1b", in_specs=[("pp",), (None, "dp")],
-        grad_through=False,
-        body_features=("scan", "inner_vjp",
-                       "dp_psum_masked_accumulator"))
+def test_axis_reuse_is_an_error():
+    """jax binds a mesh axis to at most one dimension of one value:
+    reuse within one spec fails at trace time, so it is an ERROR
+    (tests/test_meshlint_property.py holds the rule to jax.shard_map)."""
+    mesh = ml.MeshSpec({"dp": 2, "tp": 2})
+    use = ml.ShardMapUse("u", in_specs=[("dp", "dp"), ("dp", "tp")],
+                         arg_shapes=[(4, 4), (4, 4)])
     errs = _errors(ml.run_mesh_passes(
         ml.MeshLintContext(mesh, uses=[use]), passes=["mesh-spec"]))
     assert len(errs) == 1
-    assert "dp_psum_masked_accumulator" in errs[0].message
-    assert "numerically" in ml.explain(
-        ml.PROFILE_SHIM, "shard_map.dp_psum_masked_accumulator") \
-        or "incorrectly" in ml.explain(
-        ml.PROFILE_SHIM, "shard_map.dp_psum_masked_accumulator")
-
-
-def test_multiprocess_cpu_flagged():
-    mctx = ml.MeshLintContext(ml.MeshSpec({"dp": 2}), processes=2,
-                              backend="cpu")
-    errs = _errors(ml.run_mesh_passes(mctx, passes=["mesh-spec"]))
-    assert len(errs) == 1
-    assert "multiprocess_cpu_collectives" in errs[0].message
-    # single-process same config: quiet
-    assert not _errors(ml.run_mesh_passes(ml.MeshLintContext(
-        ml.MeshSpec({"dp": 2}), processes=1, backend="cpu")))
-
-
-def test_axis_reuse_is_divergence_warning_not_error():
-    """0.4.37 accepts axis reuse in one spec (probed), current jax
-    rejects it — on this image that is a portability WARNING."""
-    mesh = ml.MeshSpec({"dp": 2})
-    use = ml.ShardMapUse("u", in_specs=[("dp", "dp")],
-                         arg_shapes=[(4, 4)])
-    diags = ml.run_mesh_passes(ml.MeshLintContext(mesh, uses=[use]),
-                               passes=["mesh-spec"])
-    assert not _errors(diags)
-    warns = [d for d in diags if d.severity == "warning"]
-    assert len(warns) == 1
-    assert "shard_map.axis_reuse_in_spec" in warns[0].message
-    assert "rejected by jax-current" in warns[0].message
+    assert "uses axis 'dp' more than once" in errs[0].message
+    assert "input 'arg0'" in errs[0].message
 
 
 # ------------------------------------------- collective-consistency
@@ -355,39 +292,13 @@ def test_recompile_hazard_leading_batch_is_info():
     assert all(d.severity == "info" for d in diags)
 
 
-# ------------------------------------------------- classification
-def test_all_18_red_configs_classified():
-    recs = ml.classify_red_tests()
-    assert len(recs) == 18
-    assert all(r["classified"] for r in recs), \
-        [r["test"] for r in recs if not r["classified"]]
-    by_cap = {}
-    for r in recs:
-        by_cap.setdefault(r["capability"], []).append(r["test"])
-    assert len(by_cap["shard_map.transpose_pipelined_scan"]) == 9
-    assert len(by_cap["shard_map.dp_psum_masked_accumulator"]) == 1
-    assert len(by_cap["multiprocess_cpu_collectives"]) == 8
-    for r in recs:
-        assert r["pass"] == "mesh-spec"
-        assert r["verdict"][ml.PROFILE_SHIM]["ok"] is False
-        assert r["verdict"][ml.PROFILE_CURRENT]["ok"] is True
-
-
-def test_baseline_json_matches_derivation():
-    path = os.path.join(REPO, "LINT_multichip.json")
-    assert os.path.exists(path), \
-        "run tools/tpulint.py --write-baseline and commit the result"
-    with open(path) as f:
-        base = json.load(f)
-    derived = {r["test"]: (r["pass"], r["capability"])
-               for r in ml.classify_red_tests()}
-    committed = {r["test"]: (r["pass"], r["capability"])
-                 for r in base["red_tests"]}
-    assert derived == committed
-
-
+# ------------------------------------------------- control set
 def test_green_configs_zero_false_positives():
-    for label, mctx in ml.green_configs():
+    greens = ml.green_configs()
+    assert len(greens) == 25
+    # the 18 multichip tests whose configs are spelled out one by one
+    assert sum(label.startswith("tests/") for label, _ in greens) == 18
+    for label, mctx in greens:
         errs = _errors(ml.run_mesh_passes(mctx))
         assert not errs, (label, [d.message for d in errs])
 
@@ -449,8 +360,9 @@ def test_farm_config_verify():
 
 
 def test_verify_mesh_raises_and_unknown_pass():
-    mctx = ml.MeshLintContext(ml.MeshSpec({"dp": 2}), processes=2,
-                              backend="cpu")
+    mctx = ml.MeshLintContext(
+        ml.MeshSpec({"dp": 2}),
+        uses=[ml.ShardMapUse("u", in_specs=[("xx",)])])
     with pytest.raises(ProgramVerificationError):
         ml.verify_mesh(mctx, raise_on_error=True)
     with pytest.raises(ValueError):
@@ -506,16 +418,3 @@ def test_validate_off_never_imports_meshlint():
                        cwd=REPO)
     assert p.returncode == 0, (p.stdout[-400:], p.stderr[-800:])
     assert "LAZY_OK" in p.stdout
-
-
-def test_quarantine_preflight_is_static():
-    """Satellite pin: the dryrun shard_map legs are now skipped by a
-    STATIC meshlint verdict (pass name + capability in the warning),
-    not by catching a live _SpecError."""
-    import inspect
-    import __graft_entry__ as ge
-    src = inspect.getsource(ge._quarantined_shard_map_leg)
-    assert "run_mesh_passes" in src
-    # no live exception catch left — verdict precedes execution
-    assert "except _SpecError" not in src
-    assert "except Exception" not in src

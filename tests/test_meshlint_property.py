@@ -1,8 +1,7 @@
 """Property pin for meshlint's spec checker.
 
 `static_spec_verdict` claims to predict — without tracing — whether
-the shard_map API on THIS image accepts a (mesh, PartitionSpec, shape)
-triple. This file holds it to that claim: several hundred randomly
+jax.shard_map accepts a (mesh, PartitionSpec, shape) triple. This file holds it to that claim: several hundred randomly
 generated configs, each checked against the real shard_map under
 `jax.eval_shape`. Any disagreement in either direction is a failure —
 a false positive would quarantine working parallel code, a false
@@ -16,8 +15,8 @@ import numpy as np
 import pytest
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from paddle_tpu.analysis import meshlint as ml
 
@@ -65,7 +64,7 @@ def _random_case(rng):
 
 def _shard_map_accepts(mesh, spec, shape):
     f = shard_map(lambda x: x, mesh=mesh, in_specs=(P(*spec),),
-                  out_specs=P(*spec), check_rep=False)
+                  out_specs=P(*spec), check_vma=False)
     try:
         jax.eval_shape(f, jax.ShapeDtypeStruct(shape, np.float32))
         return True
@@ -107,7 +106,7 @@ def test_spec_verdict_reasons_only_on_reject():
 
 def test_green_parallel_configs_have_zero_errors():
     """The false-positive pin at the config level: every config the
-    green (passing-on-this-image) parallel tests use must come through
+    green parallel tests use must come through
     the FULL pass list with zero error diagnostics."""
     greens = ml.green_configs()
     assert len(greens) >= 5

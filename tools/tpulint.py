@@ -8,15 +8,10 @@ prove about this repo before anything traces or compiles:
      use-before-def, unknown ops, dead code, shape/dtype abstract
      interpretation incl. control-flow sub-blocks, WAW hazards,
      recompile hazards);
-  2. meshlint over the sharded-execution configs: the classified red
-     multichip test configs (each must classify to a named pass with a
-     both-API capability verdict), the green parallel control set
-     (must produce ZERO errors — the false-positive pin), the
-     gradsync / sparse policy grammars, and the serving FarmConfig
-     shapes;
-  3. the LINT_multichip.json baseline: the committed classification of
-     the 18 red multichip tests must match what the passes derive
-     today (drift = the capability table and reality disagree = fail).
+  2. meshlint over the sharded-execution configs: the passing parallel
+     tests' configs (must produce ZERO errors — the false-positive
+     pin), the gradsync / sparse policy grammars, and the serving
+     FarmConfig shapes.
 
 Exit status is non-zero when any error-severity diagnostic fires (or
 any warning with --strict) — a CI gate, like proglint.
@@ -24,7 +19,6 @@ any warning with --strict) — a CI gate, like proglint.
 Examples:
   python tools/tpulint.py                      # the whole gate
   python tools/tpulint.py --json               # machine-readable
-  python tools/tpulint.py --write-baseline     # refresh LINT_multichip.json
   python tools/tpulint.py --selftest           # fast smoke (tier-1)
 """
 import argparse
@@ -38,8 +32,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 sys.path.insert(0, os.path.join(_REPO, "tools"))
-
-BASELINE = os.path.join(_REPO, "LINT_multichip.json")
 
 # policy grammar strings the repo's docs/benchmarks advertise — each
 # must parse (a grammar regression breaks users' env vars silently)
@@ -71,19 +63,10 @@ def lint_models(names=None, quiet=False):
 
 
 def lint_mesh_configs(quiet=False):
-    """Section 2: meshlint — red classification, green control set,
-    policy grammars, farm shapes."""
-    from paddle_tpu.analysis.diagnostics import Diagnostic, ERROR
+    """Section 2: meshlint — green control set, policy grammars, farm
+    shapes."""
     ml = _meshlint()
-    out = {"red": [], "green": {}, "grammars": {}, "farm": {},
-           "errors": []}
-
-    for rec in ml.classify_red_tests():
-        out["red"].append(rec)
-        if not rec["classified"]:
-            out["errors"].append(
-                f"red config {rec['test']} did not classify: no "
-                f"meshlint pass names a capability for it")
+    out = {"green": {}, "grammars": {}, "farm": {}, "errors": []}
 
     for label, mctx in ml.green_configs():
         diags = ml.run_mesh_passes(mctx)
@@ -129,51 +112,6 @@ def lint_mesh_configs(quiet=False):
     return out
 
 
-def check_baseline(red_records):
-    """Section 3: the committed LINT_multichip.json must match today's
-    derivation (test -> pass/capability). Returns error strings."""
-    if not os.path.exists(BASELINE):
-        return [f"baseline {BASELINE} missing; run "
-                f"tools/tpulint.py --write-baseline and commit it"]
-    with open(BASELINE) as f:
-        base = json.load(f)
-    errs = []
-    base_by_test = {r["test"]: r for r in base.get("red_tests", [])}
-    now_by_test = {r["test"]: r for r in red_records}
-    for test in sorted(set(base_by_test) | set(now_by_test)):
-        b, n = base_by_test.get(test), now_by_test.get(test)
-        if b is None:
-            errs.append(f"red config {test} is new (not in baseline)")
-        elif n is None:
-            errs.append(f"baseline red config {test} no longer "
-                        f"derived")
-        elif (b["pass"], b["capability"]) != (n["pass"],
-                                              n["capability"]):
-            errs.append(
-                f"classification drift for {test}: baseline "
-                f"{b['pass']}/{b['capability']} vs derived "
-                f"{n['pass']}/{n['capability']}")
-    return errs
-
-
-def write_baseline(red_records):
-    ml = _meshlint()
-    payload = {
-        "comment": "Machine-readable classification of the red "
-                   "multichip tests: which meshlint pass flags each "
-                   "config and the per-API capability verdict. "
-                   "Regenerate with tools/tpulint.py --write-baseline "
-                   "after changing the capability table or the tests.",
-        "api_profiles": list(ml.api_profiles()),
-        "mesh_passes": ml.mesh_pass_names(),
-        "red_tests": red_records,
-    }
-    with open(BASELINE, "w") as f:
-        json.dump(payload, f, indent=1, sort_keys=False)
-        f.write("\n")
-    return payload
-
-
 def selftest():
     """Fast smoke for tier-1 (tpudoctor pattern: last stdout line is a
     JSON object with an "ok" field). Exercises every pass once with a
@@ -199,12 +137,6 @@ def selftest():
         "mesh-spec", "collective-consistency", "donation-aliasing",
         "device-footprint", "mesh-recompile-hazard",
         "kern-capability"}
-    # all red configs classify and the baseline (when present) agrees
-    recs = ml.classify_red_tests()
-    checks["red_configs_classified"] = (
-        len(recs) == 18 and all(r["classified"] for r in recs))
-    if os.path.exists(BASELINE):
-        checks["baseline_consistent"] = not check_baseline(recs)
     # green control set stays quiet
     checks["green_zero_errors"] = all(
         not any(d.severity == "error" for d in ml.run_mesh_passes(m))
@@ -228,8 +160,6 @@ def main(argv=None):
                    help="suppress info-severity diagnostics")
     p.add_argument("--skip-models", action="store_true",
                    help="meshlint sections only (no model builds)")
-    p.add_argument("--write-baseline", action="store_true",
-                   help=f"write {os.path.basename(BASELINE)} and exit")
     p.add_argument("--list-passes", action="store_true",
                    help="print proglint + meshlint pass names and exit")
     p.add_argument("--selftest", action="store_true",
@@ -246,18 +176,12 @@ def main(argv=None):
         return 0
 
     mesh_report = lint_mesh_configs(quiet=args.quiet)
-    if args.write_baseline:
-        write_baseline(mesh_report["red"])
-        print(f"wrote {BASELINE} ({len(mesh_report['red'])} red "
-              f"configs)")
-        return 0
-    mesh_report["baseline"] = check_baseline(mesh_report["red"])
 
     model_report = {}
     if not args.skip_models:
         model_report = lint_models(args.models, quiet=args.quiet)
 
-    failed = bool(mesh_report["errors"] or mesh_report["baseline"])
+    failed = bool(mesh_report["errors"])
     n_warn_total = 0
     for name, rec in model_report.items():
         sevs = [d["severity"] for d in rec["diagnostics"]]
@@ -276,13 +200,10 @@ def main(argv=None):
         failed = True
 
     if not args.as_json:
-        n_red = sum(r["classified"] for r in mesh_report["red"])
-        print(f"meshlint {n_red}/{len(mesh_report['red'])} red "
-              f"multichip configs classified, "
-              f"{len(mesh_report['green'])} green configs clean, "
+        print(f"meshlint {len(mesh_report['green'])} green configs, "
               f"{len(mesh_report['grammars'])} grammars, "
               f"{len(mesh_report['farm'])} farm shapes")
-        for e in mesh_report["errors"] + mesh_report["baseline"]:
+        for e in mesh_report["errors"]:
             print(f"  error: {e}")
     else:
         print(json.dumps({"models": model_report,

@@ -66,12 +66,11 @@ Modes:
                    device slice exists and shed exactly when the
                    planner reports the ceiling; an over-mem-cap grow
                    must be rejected by the meshlint pre-spawn gate.
-                   Writes BENCH_autoscale.json + autoscale_* history
-                   records.
+                   Writes no file.
   --bench-scale    static 1-replica vs SLO-autoscaled group under
                    the same traffic_spike script: goodput, peak
-                   replicas, extra compiles; merges a bench section
-                   into BENCH_autoscale.json.
+                   replicas, extra compiles; writes the bench section
+                   of BENCH_autoscale.json.
 
 Examples:
   python tools/tpuserve.py /models/mnist --name mnist --port 8500
@@ -2179,8 +2178,7 @@ def _scale_selftest_problems(problems):
 
 
 def _scale_write_bench(section, payload):
-    """Merge one section into BENCH_autoscale.json (selftest and
-    bench write different halves of the same artifact)."""
+    """Merge one section into BENCH_autoscale.json."""
     out_path = os.path.join(_REPO, "BENCH_autoscale.json")
     data = {}
     try:
@@ -2198,47 +2196,6 @@ def _scale_write_bench(section, payload):
     return out_path
 
 
-def _scale_append_history(ramp):
-    """autoscale_* records onto the bench history spine (same shape
-    as _guard_append_history; `tpustat --slo` gates them: goodput is
-    higher-better, _ms lower-better). Best-effort."""
-    try:
-        import subprocess
-
-        from paddle_tpu.telemetry import slo
-        try:
-            sha = subprocess.run(
-                ["git", "rev-parse", "--short", "HEAD"], cwd=_REPO,
-                capture_output=True, text=True,
-                timeout=10).stdout.strip() or None
-        except Exception:  # noqa: BLE001 — sha is optional
-            sha = None
-        import jax
-        dev = jax.devices()[0]      # the backend the bench just ran on
-        common = {"schema": slo.HISTORY_SCHEMA,
-                  "platform": dev.platform,
-                  "device_kind": dev.device_kind, "git_sha": sha,
-                  "unix_time": round(time.time(), 1),
-                  "stage": "scale"}
-        recs = []
-        for key, metric, unit in (
-                ("goodput_tokens_per_s", "autoscale_spike_goodput_tps",
-                 "tokens/s"),
-                ("drain_ms", "autoscale_spike_drain_ms", "ms")):
-            v = ramp.get(key)
-            if isinstance(v, (int, float)) and v:
-                recs.append(dict(common, metric=metric, value=v,
-                                 unit=unit))
-        if not recs:
-            return None
-        path = os.environ.get("BENCH_HISTORY_PATH") \
-            or os.path.join(_REPO, "BENCH_history.jsonl")
-        slo.append_history(path, recs)
-        return path
-    except Exception:  # noqa: BLE001 — history is best-effort
-        return None
-
-
 def run_selftest_scale(args):
     from paddle_tpu import telemetry
     telemetry.enable()
@@ -2246,8 +2203,6 @@ def run_selftest_scale(args):
     info = _scale_selftest_problems(problems)
     result = {"mode": "selftest-scale", **info,
               "problems": problems, "ok": not problems}
-    result["artifact"] = _scale_write_bench("selftest", result)
-    result["history_appended"] = _scale_append_history(info["ramp"])
     if args.as_json:
         print(json.dumps(result, default=str))
     else:
@@ -2445,8 +2400,7 @@ def main(argv=None):
                         "scale-up recompiles, brownout must shed "
                         "ONLY at the device ceiling (deferred while "
                         "a free slice exists), and an over-cap grow "
-                        "must be verify-rejected; writes "
-                        "BENCH_autoscale.json + history records")
+                        "must be verify-rejected; writes no file")
     p.add_argument("--bench-scale", action="store_true",
                    dest="bench_scale",
                    help="static 1-replica vs SLO-autoscaled group "
