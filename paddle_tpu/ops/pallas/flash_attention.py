@@ -13,9 +13,22 @@ MXU matmuls. Online-softmax state (m, l, acc) lives in VMEM scratch that
 persists across the k iterations of one q block; outputs are flushed on
 the last k step. No [T,S] score matrix ever hits HBM. The backward pass
 is the standard flash recomputation: forward saves only the per-row
-logsumexp; dq / dk+dv kernels rebuild the probabilities block-wise with
-the same pipelined grid structure. This replaces the reference's unfused
-softmax(QK^T)V composition
+logsumexp, and ONE kernel, flash_attention_bwd, rebuilds the
+probabilities of a block once and gives all five products their
+operands: its grid is (batch*kv_heads, k_blocks, group*q_blocks) with
+the q blocks innermost, dk and dv accumulate in VMEM scratch over the q
+blocks of the query heads that share the key-value head, and dq
+accumulates in a float32 buffer that holds the dq of all those heads
+([group*T, D]: 32 MiB of VMEM at the 8192-token cell, with the output's
+buffers) and is written back once a key-value head. Where that buffer
+would pass FUSED_BWD_VMEM (which the shapes decide, nothing else) the
+two kernels this one replaced run instead, flash_attention_dq (k blocks
+innermost) and flash_attention_dkv, each rebuilding the probabilities.
+Under a causal diagonal a block above it is skipped (and not fetched:
+the index maps hand a skipped step the block of a neighbouring active
+one), a block the diagonal crosses is masked, and a block wholly under
+it takes the same body without the mask. This replaces the reference's
+unfused softmax(QK^T)V composition
 (python/paddle/fluid/nets.py:scaled_dot_product_attention) as the
 long-sequence attention path, and is registered through jax.custom_vjp
 so it stays on the training path under jax.value_and_grad.
@@ -28,8 +41,8 @@ takes one batch row, walks the heads inside the body one 128-lane
 group at a time (two heads at D = 64, picked by lane masks so that every
 product is 128 lanes wide), and computes plain max / exp / sum in
 float32 on [256, S] scores that never leave VMEM. ONE backward kernel
-recomputes p once and gives dq, dk and dv (the tiled kernel rebuilds p
-twice). Residuals: out and the per-row logsumexp [B, H, T]. p is rounded
+recomputes p once and gives dq, dk and dv (as the tiled kernel's does
+since PR 33). Residuals: out and the per-row logsumexp [B, H, T]. p is rounded
 to the operands' dtype only as the operand of the second matmul, as
 _sdpa does; the scores themselves stay float32 (_sdpa rounds them to
 bf16), so it is at least as exact as the composition it replaces. At the
@@ -42,7 +55,7 @@ Supported extras (covers the flagship transformer end-to-end):
   pad-mask the NMT model builds, squeezed). Carried as [B, 1, S] so
   every block keeps Mosaic's (8,128)-or-full tiling rule; the per-head
   grid row maps onto the batch row inside the index_map (no per-head
-  materialization). The bias is DIFFERENTIABLE: the dkv kernel row-sums
+  materialization). The bias is DIFFERENTIABLE: the backward kernel row-sums
   the recomputed ds block into a per-(batch,head) [BH,1,S] f32 output
   (accumulated in-place across the innermost q steps) and the vjp
   reduces it over heads — a learnable additive bias (e.g. ALiBi-style
@@ -61,9 +74,10 @@ Supported extras (covers the flagship transformer end-to-end):
   zero. Do not read fully-masked rows from the plain `flash_attention`
   output.
 
-The tiled kernel's block sizes default to 1024x2048 (clamped to a
-VMEM budget per head dim, see _choose_blocks); what the chip measured
-of it is in PERF.md sections 5 and 7.6.
+The tiled kernel's blocks default to 1024 queries x 2048 keys, 1024 x
+1024 under a causal diagonal (clamped to a VMEM budget per head dim, see
+_choose_blocks; the chip's sweep is the table above DEFAULT_BLOCK_Q);
+what the chip measured of it is in PERF.md sections 5 and 7.6.
 
 When to use which path is try_flash's to say, and only its: the short
 kernel for `bthd` arrays with both lengths 256, 384 or 512; the tiled
@@ -77,14 +91,15 @@ runtime with no shape stated, and the table above SHORT_MIN_SEQ_LEN
 replaces it for the op's path only. Interpret mode (CPU tests) bypasses
 the performance gates.
 
-Where the tiled kernel's time goes at D=64 (the share of its roofline
-each of its three kernels reaches, and why) is in PERF.md sections 5
-and 7.6: per score element it does 2D=128 MXU flops against ~10 VPU
-ops (exp/max/mul in f32).
+Where the tiled kernel's time goes at D=64 is in PERF.md sections 5 and
+7.6: every product of this attention contracts over 64 of the MXU's 128
+rows or fills 64 of its 128 columns, so the chip's floor at this head
+is about twice the counted one, and per score element the kernels do
+2D=128 MXU flops against ~10 VPU ops (exp/max/mul in f32).
 
 An escape from that VPU cost is implemented behind `softmax_dtype`: with
 jnp.bfloat16, the probability exp (the dominant VPU cost — one
-transcendental per score element in fwd, dq AND dkv) runs in bf16 while
+transcendental per score element, forward and backward) runs in bf16 while
 everything that controls numerics stays f32: the scores matmul
 accumulation, the running max m, the scale factor alpha, the row-sum l
 (f32-accumulated reduction over bf16 p), and the output rescale. The
@@ -162,7 +177,10 @@ MIN_SEQ_LEN_BTHD = 1024
 # Trace-time evidence that the Pallas path (not the jnp fallback) was
 # selected — tests assert on this (VERDICT r1: the kernel must demonstrably
 # run under value_and_grad, not silently fall back).
-STATS = {"pallas_calls": 0}
+STATS = {"pallas_calls": 0,
+         # which backward the tiled kernel traced: one kernel with dq
+         # resident in VMEM, or dq and dk / dv apart (FUSED_BWD_VMEM)
+         "tiled_bwd_fused": 0, "tiled_bwd_split": 0}
 
 # m/l scratch rows are stored lane-replicated at this width (1-lane
 # vectors are not a legal VMEM tile).
@@ -170,10 +188,44 @@ _LANES = 128
 
 # Shared by supports() and flash_attention() so the dispatch guard and
 # the call can't drift (2048x2048 fails to compile: the fp32 scores tile
-# exceeds VMEM). _prep clamps the pair to a VMEM budget for larger head
-# dims. The kernels' readings at these blocks: PERF.md 5 and 7.6.
+# exceeds VMEM). _choose_blocks clamps the pair to a VMEM budget for
+# larger head dims. Swept on the chip once the backward was one kernel
+# (v5e, D = 64, bf16, `bthd` arrays, tools/bench_attention.py; PERF.md
+# section 6, PR 33): ms forward / backward (the vjp alone), block_q x
+# block_k; [B, S, H] with T = S:
+#              [2, 8192, 32 over 8]  [64, 2048, 16], bias      [128, 1024, 8], bias
+#              causal                causal       full         causal       full
+#   512x512    22.68 / 22.13         36.47/40.15  49.22/50.95  13.32/15.10  15.45/16.67
+#   512x1024   12.92 / 20.91         26.84/40.86  31.17/48.17  10.83/15.82  10.13/15.76
+#   512x2048   12.22 / 21.27         28.35/45.94  26.21/45.86
+#   1024x512   21.18 / 20.74         37.70/41.46  45.60/48.32  14.44/16.14  14.40/16.14
+#   1024x1024  11.05 / 19.84         24.57/40.24  28.41/47.26   8.79/15.79  10.02/15.98
+#   1024x2048  11.85 / 21.10         27.84/47.46  26.99/46.62
+# (the two-kernel backward it replaced, at 8192: 1024x2048 12.05 / 29.32,
+# 1024x1024 11.44 / 28.35.) Under a causal diagonal 1024 x 1024 wins at
+# every length: blocks of 2048 keys compute 1.25 x the causal half at
+# 8192 where blocks of 1024 compute 1.125 x. Without one, 2048 keys a
+# block are 2-5% ahead at 2048. Keys in blocks of 512 double the
+# forward's time. So the key block follows `causal` (_choose_blocks).
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 2048
+DEFAULT_BLOCK_K_CAUSAL = 1024
+
+# The tiled backward is ONE kernel (flash_attention_bwd) where the dq of
+# the query heads that share a key-value head can stay in VMEM for the
+# whole head (_bwd_resident_bytes): under this many bytes. Over it, dq
+# and dk / dv are two kernels that each rebuild the probabilities. The
+# v5e has 128 MiB of VMEM; with _TILED_VMEM beside it this budget asks
+# for 96 MiB at most (the short kernel's _SHORT_VMEM_MAX), which a
+# described v5e compiles at [1, 65536, 1 x 128]. The cell's [2, 8192, 32
+# over 8 x 64] holds 32 MiB and ran on the chip: 18.10 ms a step where the
+# two kernels took 27.48 (PERF.md section 6, PR 33).
+FUSED_BWD_VMEM = 64 * 1024 * 1024
+# the limit asked for the tiled backward's blocks and [bq, bk] float32
+# temporaries beside the resident dq: at 1024 x 2048 blocks they take
+# 21.2 MiB (a described v5e refuses the cell's kernel at 48 MiB in all
+# and takes 53.19)
+_TILED_VMEM = 32 * 1024 * 1024
 
 
 # dtype of the probability exp inside the kernels; f32 = exact flash
@@ -210,15 +262,19 @@ def _pick_block(n, pref):
     return 0
 
 
-def _choose_blocks(T, S, D, DV, pref_q=None, pref_k=None):
+def _choose_blocks(T, S, D, DV, pref_q=None, pref_k=None, causal=False):
     """The ONE block-selection policy (supports() and _prep share it):
-    pick legal tiles, then shrink — re-legalizing through _pick_block at
-    every step — until the fp32 scores tile fits the VMEM budget
-    (measured on v5e: 2M elements compiles at head dim <= 64, 4M does
-    not; halved budget for wider heads). Returns (0, 0) if no legal
-    in-budget pair exists."""
+    pick legal tiles (the defaults where the caller names none; the key
+    block by `causal`, as the sweep above DEFAULT_BLOCK_Q found), then
+    shrink — re-legalizing through _pick_block at every step — until the
+    fp32 scores tile fits the VMEM budget (measured on v5e: 2M elements
+    compiles at head dim <= 64, 4M does not; halved budget for wider
+    heads). Returns (0, 0) if no legal in-budget pair exists; what is
+    legal does not depend on `causal`."""
     bq = _pick_block(T, pref_q or DEFAULT_BLOCK_Q)
-    bk = _pick_block(S, pref_k or DEFAULT_BLOCK_K)
+    bk = _pick_block(S, DEFAULT_BLOCK_K_CAUSAL) \
+        if causal and not pref_k else 0
+    bk = bk or _pick_block(S, pref_k or DEFAULT_BLOCK_K)
     if not bq or not bk:
         return 0, 0
     budget = 2 * 1024 * 1024 if max(D, DV) <= 64 else 1024 * 1024
@@ -245,12 +301,52 @@ def _causal_active(q_idx, k_idx, block_q, block_k, offset):
     return k_idx * block_k <= (q_idx + 1) * block_q - 1 + offset
 
 
+def _causal_whole(q_idx, k_idx, block_q, block_k, offset):
+    """Does k block k_idx lie wholly under the diagonal of q block q_idx
+    (its last key visible to the block's first query)? Such a block needs
+    no mask."""
+    return (k_idx + 1) * block_k - 1 <= q_idx * block_q + offset
+
+
 def _causal_mask(s, q_idx, k_idx, block_q, block_k, offset):
     q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     k_pos = k_idx * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
     return jnp.where(q_pos + offset >= k_pos, s, _NEG_INF)
+
+
+def _on_block(causal, q_idx, k_idx, block_q, block_k, offset, body):
+    """Run `body(mask)` on block (q_idx, k_idx) for what the causal
+    diagonal leaves of it: not at all above the diagonal, with `mask`
+    (s -> masked s) where the diagonal crosses it, and with mask = None
+    where it lies wholly under it (28 of the 36 active blocks of a head
+    at 8192 with 1024 x 1024 blocks) or nothing is causal."""
+    if not causal:
+        body(None)
+        return
+    at = (q_idx, k_idx, block_q, block_k, offset)
+    whole = _causal_whole(*at)
+    pl.when(whole)(lambda: body(None))
+    pl.when(_causal_active(*at) & jnp.logical_not(whole))(
+        lambda: body(lambda s: _causal_mask(s, *at)))
+
+
+def _last_k(i, block_q, block_k, offset, n_k):
+    """The last k block that q block i reads under the causal diagonal.
+    An index map of k / v hands a skipped step (j past it) this block
+    again, so that Mosaic does not fetch what the body will not read."""
+    reach = jnp.maximum((i + 1) * block_q - 1 + offset, 0)
+    return jnp.minimum(jax.lax.div(reach, block_k), n_k - 1)
+
+
+def _first_q(j, block_q, block_k, offset, n_q):
+    """The first q block that reads k block j: the q-side twin of
+    _last_k, for the kernel that walks the q blocks innermost (its
+    skipped steps come first and are handed the block that follows)."""
+    start = jnp.maximum(j * block_k - offset, 0)
+    return jnp.minimum(jax.lax.div(start, block_q), n_q - 1)
+
 
 def _precision(a):
     """Sub-fp32 operands multiply exactly on the MXU at DEFAULT, and
@@ -300,18 +396,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    run = _causal_active(q_idx, k_idx, bq, bk, offset) if causal \
-        else (k_idx >= 0)
-
-    @pl.when(run)
-    def _compute():
+    def _compute(mask):
         # bf16 operands + fp32 accumulation: full-rate MXU, scale folded in
         # after the matmul
         s = _dot_t(q_ref[...], k_ref[...]) * scale
         if has_bias:
             s = s + b_ref[0, :].astype(jnp.float32)[None, :]    # [bq, bk]
-        if causal:
-            s = _causal_mask(s, q_idx, k_idx, bq, bk, offset)
+        if mask is not None:
+            s = mask(s)
         m_prev = m_ref[...][:, :1]                              # [bq, 1]
         l_prev = l_ref[...][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -327,12 +419,35 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         acc_ref[...] = acc_ref[...] * alpha + _dot(
             p.astype(v_ref.dtype), v_ref[...])
 
+    _on_block(causal, q_idx, k_idx, bq, bk, offset, _compute)
+
     @pl.when(k_idx == n_k - 1)
     def _flush():
         m = m_ref[...][:, :1]
         l = jnp.maximum(l_ref[...][:, :1], 1e-20)
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0, :] = (m + jnp.log(l))[:, 0]
+
+
+def _k_innermost_specs(kv, H, block_q, block_k, D, DV, causal, offset, n_k):
+    """The q, k, v and bias BlockSpecs of a grid (B*H, n_q, n_k): k / v /
+    bias blocks follow j, held at the row's last active block under a
+    causal diagonal."""
+    if causal:
+        def kj(i, j):
+            return jnp.minimum(j, _last_k(i, block_q, block_k, offset, n_k))
+    else:
+        def kj(i, j):
+            return j
+    return [
+        pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((None, block_k, D),
+                     lambda b, i, j: (kv(b), kj(i, j), 0)),
+        pl.BlockSpec((None, block_k, DV),
+                     lambda b, i, j: (kv(b), kj(i, j), 0)),
+        pl.BlockSpec((None, 1, block_k),
+                     lambda b, i, j: (b // H, 0, kj(i, j))),
+    ]
 
 
 def _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
@@ -345,21 +460,17 @@ def _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
     BH, T, D = q.shape
     S = k.shape[1]
     DV = v.shape[-1]
-    H = n_heads
-    kv = _kv_row(BH // k.shape[0])
     n_k = S // block_k
+    offset = S - T + causal_offset
     grid = (BH, T // block_q, n_k)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale, n_k=n_k,
-                          offset=S - T + causal_offset, p_dtype=p_dtype,
+                          offset=offset, p_dtype=p_dtype,
                           has_bias=has_bias),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((None, block_k, DV), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((None, 1, block_k), lambda b, i, j: (b // H, 0, j)),
-        ],
+        in_specs=_k_innermost_specs(_kv_row(BH // k.shape[0]), n_heads,
+                                    block_q, block_k, D, DV, causal,
+                                    offset, n_k),
         out_specs=[
             pl.BlockSpec((None, block_q, DV), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
@@ -384,11 +495,29 @@ def _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
+def _p_and_ds(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref, mask, *,
+              scale, p_dtype, has_bias):
+    """The one recomputation every backward product reads: p [bq, bk] (in
+    p_dtype) and ds = p (dp - delta) in float32, from the block's
+    scores, the saved logsumexp and dp = dO v^T."""
+    lse = lse_ref[0, :][:, None]                         # [bq, 1]
+    delta = dl_ref[0, :][:, None]
+    s = _dot_t(q_ref[...], k_ref[...]) * scale
+    if has_bias:
+        s = s + b_ref[0, :].astype(jnp.float32)[None, :]
+    if mask is not None:
+        s = mask(s)
+    p = jnp.exp((s - lse).astype(p_dtype))               # [bq, bk]
+    dp = _dot_t(do_ref[...], v_ref[...])                 # [bq, bk]
+    return p, p * (dp - delta)
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
                dq_ref, acc_ref, *, causal, scale, n_k, offset,
                p_dtype=jnp.float32, has_bias=True):
     """Grid (B*H, n_q, n_k): recompute p block-wise, accumulate dq in
-    VMEM scratch, flush on the last k step."""
+    VMEM scratch, flush on the last k step. Runs only where the fused
+    kernel's resident dq does not fit (FUSED_BWD_VMEM)."""
     q_idx, k_idx = pl.program_id(1), pl.program_id(2)
     bq, bk = q_ref.shape[0], k_ref.shape[0]
 
@@ -396,48 +525,50 @@ def _dq_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    run = _causal_active(q_idx, k_idx, bq, bk, offset) if causal \
-        else (k_idx >= 0)
-
-    @pl.when(run)
-    def _compute():
-        lse = lse_ref[0, :][:, None]                     # [bq, 1]
-        delta = dl_ref[0, :][:, None]                    # [bq, 1]
-        s = _dot_t(q_ref[...], k_ref[...]) * scale
-        if has_bias:
-            s = s + b_ref[0, :].astype(jnp.float32)[None, :]
-        if causal:
-            s = _causal_mask(s, q_idx, k_idx, bq, bk, offset)
-        p = jnp.exp((s - lse).astype(p_dtype))           # [bq, bk]
-        dp = _dot_t(do_ref[...], v_ref[...])             # [bq, bk]
-        ds = p * (dp - delta)
+    def _compute(mask):
+        _, ds = _p_and_ds(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref,
+                          dl_ref, mask, scale=scale, p_dtype=p_dtype,
+                          has_bias=has_bias)
         acc_ref[...] = acc_ref[...] + _dot(
             ds.astype(k_ref.dtype), k_ref[...]) * scale
+
+    _on_block(causal, q_idx, k_idx, bq, bk, offset, _compute)
 
     @pl.when(k_idx == n_k - 1)
     def _flush():
         dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
-                *refs, causal, scale, n_q, offset,
+def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
+                *refs, causal, scale, n_q, n_k, offset, fused,
                 p_dtype=jnp.float32, has_bias=True):
-    """Grid (B*KVH, n_kv, group*n_q), q innermost: recompute p^T
-    block-wise, accumulate dk/dv in VMEM scratch over the q blocks of the
-    `group` query heads of this key-value head (group = 1: one head's). With has_bias, db_ref [1, bk] is
-    the per-head bias gradient row (d s / d bias = 1): its block index
-    is constant in the innermost q dim, so it stays resident in VMEM and
+    """Grid (B*KVH, n_kv, group*n_q), q innermost: recompute p and ds of
+    a block ONCE and give every product that reads them. dk/dv
+    accumulate in VMEM scratch over the q blocks of the `group` query
+    heads of this key-value head (group = 1: one head's). `fused`: dq
+    too, `ds k`, into dq_acc [group*T, D] float32, which holds the dq of
+    all the key-value head's query heads (they follow one another in
+    [B*H, T, D]) for the whole head; dq_ref, the same rows in the
+    operands' dtype, has a block index constant over both inner axes, so
+    it is written back once a key-value head. Not `fused`: this is the
+    dk / dv half beside _dq_kernel. With has_bias, db_ref [1, bk] is the
+    per-head bias gradient row (d s / d bias = 1): its block index is
+    constant in the innermost q dim, so it stays resident in VMEM and
     accumulates in-place across the q steps; without it, neither the
     bias add nor the db output exists (no-bias path pays nothing)."""
-    if has_bias:
-        dk_ref, dv_ref, db_ref, dk_acc, dv_acc = refs
-    else:
-        (dk_ref, dv_ref, dk_acc, dv_acc), db_ref = refs, None
+    # outputs dk, dv[, db][, dq], then scratch dk_acc, dv_acc[, dq_acc]
+    refs = list(refs)
+    dq_acc = refs.pop() if fused else None
+    dv_acc, dk_acc = refs.pop(), refs.pop()
+    dq_ref = refs.pop() if fused else None
+    db_ref = refs.pop() if has_bias else None
+    dk_ref, dv_ref = refs
     # the innermost axis walks the q blocks of every query head that
     # shares this key-value head: n_q steps a head, one head after another
     k_idx, step = pl.program_id(1), pl.program_id(2)
     q_idx = step % n_q
     bk, bq = k_ref.shape[0], q_ref.shape[0]
+    rows = pl.ds(pl.multiple_of(step * bq, bq), bq)      # of dq_acc
 
     @pl.when(step == 0)
     def _init():
@@ -446,33 +577,44 @@ def _dkv_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
         if has_bias:
             db_ref[...] = jnp.zeros_like(db_ref)
 
-    # under causal masking, q blocks strictly above this k block see none of it
-    run = _causal_active(q_idx, k_idx, bq, bk, offset) if causal \
-        else (k_idx >= 0)
+    if fused:
+        @pl.when(k_idx == 0)
+        def _init_dq():
+            dq_acc[rows, :] = jnp.zeros((bq, dq_acc.shape[1]), jnp.float32)
 
-    @pl.when(run)
-    def _compute():
-        lse = lse_ref[0, :][:, None]                     # [bq, 1]
-        delta = dl_ref[0, :][:, None]
-        s = _dot_t(q_ref[...], k_ref[...]) * scale
+    def _compute(mask):
+        p, ds = _p_and_ds(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref,
+                          dl_ref, mask, scale=scale, p_dtype=p_dtype,
+                          has_bias=has_bias)
+        dv_acc[...] = dv_acc[...] + _dot(
+            p.astype(do_ref.dtype).T, do_ref[...])
         if has_bias:
-            s = s + b_ref[0, :].astype(jnp.float32)[None, :]
-        if causal:
-            s = _causal_mask(s, q_idx, k_idx, bq, bk, offset)
-        p = jnp.exp((s - lse).astype(p_dtype)).astype(
-            q_ref.dtype)                                 # [bq, bk]
-        dv_acc[...] = dv_acc[...] + _dot(p.T, do_ref[...])
-        dp = _dot_t(do_ref[...], v_ref[...])             # [bq, bk]
-        ds_f = p.astype(jnp.float32) * (dp - delta)
-        if has_bias:
-            db_ref[0, :] = db_ref[0, :] + jnp.sum(ds_f, axis=0)
-        ds = ds_f.astype(q_ref.dtype)
+            db_ref[0, :] = db_ref[0, :] + jnp.sum(ds, axis=0)
+        ds = ds.astype(q_ref.dtype)
         dk_acc[...] = dk_acc[...] + _dot(ds.T, q_ref[...]) * scale
+        if fused:
+            dq_acc[rows, :] = dq_acc[rows, :] + _dot(ds, k_ref[...]) * scale
+
+    _on_block(causal, q_idx, k_idx, bq, bk, offset, _compute)
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _flush():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    if fused:
+        @pl.when(k_idx == n_k - 1)
+        def _flush_dq():
+            dq_ref[rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
+
+
+def _bwd_resident_bytes(group, T, D, itemsize):
+    """VMEM the fused backward holds for a whole key-value head: dq of
+    its `group` query heads as the float32 accumulator and as the output
+    block in the operands' dtype (Pallas keeps two buffers of it), the
+    minor dimension padded to a vreg's 128 lanes. Which backward runs is
+    a function of the shapes alone: this against FUSED_BWD_VMEM."""
+    return group * T * -(-D // _LANES) * _LANES * (4 + 2 * itemsize)
 
 
 def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
@@ -484,7 +626,6 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
     DV = v.shape[-1]
     H = n_heads
     group = BH // BKV
-    kv = _kv_row(group)
     do = g.astype(jnp.float32)
     # delta_i = rowsum(dO * O): the softmax-normalization correction term.
     # An lse cotangent folds in here: d s_ij gets p_ij * g_lse_i, i.e.
@@ -495,29 +636,32 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
         delta = delta - g_lse.astype(jnp.float32)
     n_k = S // block_k
     n_q = T // block_q
+    offset = S - T + causal_offset
+    static = dict(causal=causal, scale=scale, offset=offset,
+                  p_dtype=p_dtype, has_bias=has_bias)
+    resident = _bwd_resident_bytes(group, T, D, q.dtype.itemsize)
+    fused = resident <= FUSED_BWD_VMEM
+    STATS["tiled_bwd_fused" if fused else "tiled_bwd_split"] += 1
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, scale=scale, n_k=n_k,
-                          offset=S - T + causal_offset, p_dtype=p_dtype,
-                          has_bias=has_bias),
-        grid=(BH, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((None, block_k, DV), lambda b, i, j: (kv(b), j, 0)),
-            pl.BlockSpec((None, 1, block_k), lambda b, i, j: (b // H, 0, j)),
-            pl.BlockSpec((None, block_q, DV), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
-            pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="flash_attention_dq",
-        interpret=interpret,
-    )(q, k, v, bias, g, lse, delta)
+    if not fused:
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, n_k=n_k, **static),
+            grid=(BH, n_q, n_k),
+            in_specs=_k_innermost_specs(_kv_row(group), H, block_q, block_k,
+                                        D, DV, causal, offset, n_k) + [
+                pl.BlockSpec((None, block_q, DV), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
+                pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
+            ],
+            out_specs=pl.BlockSpec((None, block_q, D),
+                                   lambda b, i, j: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((BH, T, D), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="flash_attention_dq",
+            interpret=interpret,
+        )(q, k, v, bias, g, lse, delta)
 
     out_specs = [
         pl.BlockSpec((None, block_k, D), lambda b, j, i: (b, j, 0)),
@@ -531,45 +675,62 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
         out_specs.append(
             pl.BlockSpec((None, 1, block_k), lambda b, j, i: (b, 0, j)))
         out_shape.append(jax.ShapeDtypeStruct((BKV, 1, S), jnp.float32))
+    scratch_shapes = [
+        pltpu.VMEM((block_k, D), jnp.float32),
+        pltpu.VMEM((block_k, DV), jnp.float32),
+    ]
+    if fused:
+        # the dq of a key-value head's query heads, [group * T, D], as one
+        # block: resident over both inner grid axes
+        out_specs.append(pl.BlockSpec((None, group * T, D),
+                                      lambda b, j, i: (b, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((BKV, group * T, D), q.dtype))
+        scratch_shapes.append(pltpu.VMEM((group * T, D), jnp.float32))
+
     # grid row b is a key-value head; step i of the innermost axis is q
-    # block i % n_q of query head b * group + i // n_q
-    if group == 1:
-        def qrow(b, i):
-            return b, i
-    else:
-        def qrow(b, i):
-            return b * group + i // n_q, i % n_q
+    # block i % n_q of query head b * group + i // n_q. Under a causal
+    # diagonal the steps above it (the first of each head) are handed the
+    # head's first active block
+    def qrow(b, j, i):
+        qi = i % n_q
+        if causal:
+            qi = jnp.maximum(qi, _first_q(j, block_q, block_k, offset, n_q))
+        return (b if group == 1 else b * group + i // n_q), qi
+
+    def qblock(b, j, i):             # of q, dO: [BH, T, D]
+        return qrow(b, j, i) + (0,)
+
+    def qvec(b, j, i):               # of lse, delta: [BH, 1, T]
+        row, qi = qrow(b, j, i)
+        return row, 0, qi
     KVH = H // group
     outs = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, scale=scale, n_q=n_q,
-                          offset=S - T + causal_offset, p_dtype=p_dtype,
-                          has_bias=has_bias),
+        functools.partial(_bwd_kernel, n_q=n_q, n_k=n_k, fused=fused,
+                          **static),
         grid=(BKV, n_k, group * n_q),
         in_specs=[
-            pl.BlockSpec((None, block_q, D),
-                         lambda b, j, i: qrow(b, i) + (0,)),
+            pl.BlockSpec((None, block_q, D), qblock),
             pl.BlockSpec((None, block_k, D), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((None, block_k, DV), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((None, 1, block_k),
                          lambda b, j, i: (b // KVH, 0, j)),
-            pl.BlockSpec((None, block_q, DV),
-                         lambda b, j, i: qrow(b, i) + (0,)),
-            pl.BlockSpec((None, 1, block_q),
-                         lambda b, j, i: (qrow(b, i)[0], 0, qrow(b, i)[1])),
-            pl.BlockSpec((None, 1, block_q),
-                         lambda b, j, i: (qrow(b, i)[0], 0, qrow(b, i)[1])),
+            pl.BlockSpec((None, block_q, DV), qblock),
+            pl.BlockSpec((None, 1, block_q), qvec),
+            pl.BlockSpec((None, 1, block_q), qvec),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, DV), jnp.float32),
-        ],
+        scratch_shapes=scratch_shapes,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="flash_attention_dkv",
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
+            if fused else ("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_TILED_VMEM + resident if fused else None),
+        name="flash_attention_bwd" if fused else "flash_attention_dkv",
         interpret=interpret,
     )(q, k, v, bias, g, lse, delta)
+    outs = list(outs)
+    if fused:
+        dq = outs.pop().reshape(BH, T, D)
     if not has_bias:
         dk, dv = outs
         return dq, dk, dv, None
@@ -659,8 +820,7 @@ def flash_attention_with_lse(q, k, v, bias=None, causal=False, scale=None,
     STATS["pallas_calls"] += 1
     B, H, T, _ = q.shape
     qr, kr, vr, br, H, scale, block_q, block_k = _prep(
-        q, k, v, bias, scale, block_q or DEFAULT_BLOCK_Q,
-        block_k or DEFAULT_BLOCK_K)
+        q, k, v, bias, scale, block_q, block_k, causal)
     p_dtype = jnp.dtype(softmax_dtype or _SOFTMAX_DTYPE)
     out, lse = _flash_lse(qr, kr, vr, br, H, bool(causal), scale, block_q,
                           block_k, bool(interpret), p_dtype,
@@ -1034,8 +1194,7 @@ def flash_attention_bthd(q, k, v, bias=None, causal=False, scale=None,
     return out.reshape(B, T, H, D)
 
 
-def supports(q, k, v, bias=None, block_q=DEFAULT_BLOCK_Q,
-             block_k=DEFAULT_BLOCK_K):
+def supports(q, k, v, bias=None, block_q=None, block_k=None):
     """True if (shapes, bias layout) can run on the Pallas path."""
     if not _HAS_PALLAS or q.ndim != 4:
         return False
@@ -1052,7 +1211,7 @@ def supports(q, k, v, bias=None, block_q=DEFAULT_BLOCK_Q,
     return _bias_ok(bias, B, S)
 
 
-def _prep(q, k, v, bias, scale, block_q, block_k):
+def _prep(q, k, v, bias, scale, block_q, block_k, causal):
     """Shared dispatch prep: block picking, [B,H,T,D]→[BH,T,D] flatten,
     [B,1,S] bias normalization — ONE place so flash_attention and
     flash_attention_with_lse (and supports()) cannot drift."""
@@ -1060,7 +1219,7 @@ def _prep(q, k, v, bias, scale, block_q, block_k):
     S = k.shape[2]
     scale = float(scale) if scale is not None else D ** -0.5
     block_q, block_k = _choose_blocks(T, S, D, v.shape[-1],
-                                      block_q, block_k)
+                                      block_q, block_k, causal)
     if not block_q or not block_k:
         raise NotImplementedError("seq len must tile")
     qr = q.reshape(B * H, T, D)
@@ -1070,8 +1229,8 @@ def _prep(q, k, v, bias, scale, block_q, block_k):
 
 
 def flash_attention(q, k, v, bias=None, causal=False, scale=None,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=False, softmax_dtype=None, causal_offset=0):
+                    block_q=None, block_k=None, interpret=False,
+                    softmax_dtype=None, causal_offset=0):
     """q/k/v: [B, H, T, D] → [B, H, T, D]. Differentiable (custom_vjp);
     bias is an additive key-padding bias [B, S] or [B,1,1,S]."""
     if not _HAS_PALLAS:
@@ -1079,7 +1238,7 @@ def flash_attention(q, k, v, bias=None, causal=False, scale=None,
     STATS["pallas_calls"] += 1
     B, H, T, _ = q.shape
     qr, kr, vr, br, H, scale, block_q, block_k = _prep(
-        q, k, v, bias, scale, block_q, block_k)
+        q, k, v, bias, scale, block_q, block_k, causal)
     # per-batch bias row is shared across heads via the kernel index_map
     p_dtype = jnp.dtype(softmax_dtype or _SOFTMAX_DTYPE)
     out = _flash(qr, kr, vr, br, H, bool(causal), scale, block_q, block_k,

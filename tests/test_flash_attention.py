@@ -244,6 +244,148 @@ def test_flash_softmax_dtype_global_knob():
 
 
 # ---------------------------------------------------------------------------
+# the tiled backward: one kernel that rebuilds the probabilities once
+# ---------------------------------------------------------------------------
+_BWD_CASES = {
+    # name: (causal, with_bias, group, T, S, causal_offset, lse cotangent)
+    "full": (False, False, 1, 64, 64, 0, False),
+    "full_bias_group4": (False, True, 4, 64, 64, 0, False),
+    "full_cross_bias_lse": (False, True, 1, 32, 64, 0, True),
+    "causal": (True, False, 1, 64, 64, 0, False),
+    "causal_bias": (True, True, 1, 64, 64, 0, False),
+    "causal_group4": (True, False, 4, 64, 64, 0, False),
+    "causal_group4_bias_lse": (True, True, 4, 64, 64, 0, True),
+    "causal_cross_bottom_right": (True, False, 1, 64, 128, 0, False),
+    "causal_cross_group4_bias": (True, True, 4, 32, 96, 0, False),
+    "causal_strict_lse": (True, False, 1, 64, 64, -1, True),
+    "causal_strict_group4": (True, False, 4, 64, 64, -1, False),
+    "causal_strict_cross_group4_bias_lse": (True, True, 4, 64, 128, -1, True),
+}
+
+
+def _attend_with_lse_reference(q, k, v, bias, causal, causal_offset):
+    """(out, lse) of the unfused composition in float32, grouped heads
+    and a shifted bottom-right diagonal included."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    group = q.shape[1] // k.shape[1]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if bias is not None:
+        s = s + bias[:, None, None, :]
+    if causal:
+        T, S = s.shape[-2:]
+        s = jnp.where(jnp.tril(jnp.ones((T, S), bool),
+                               k=S - T + causal_offset), s, -1e30)
+    lse = jax.scipy.special.logsumexp(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v), lse
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_BWD_CASES))
+def test_tiled_backward_is_one_kernel_and_matches(case, dtype, monkeypatch):
+    """dq, dk, dv (and db) of flash_attention_bwd, the one backward
+    kernel, against the unfused reference and against the two kernels it
+    replaced (which the same shapes take when nothing fits the VMEM
+    budget), at blocks of 16 x 32 so that blocks above, on and wholly
+    under the diagonal all occur; STATS says which ran."""
+    causal, with_bias, group, T, S, offset, with_lse = _BWD_CASES[case]
+    dt = jnp.dtype(dtype)
+    B, KVH, D = 2, 2 if group == 1 else 1, 16
+    rng = np.random.RandomState(33)
+    q = jnp.asarray(rng.randn(B, KVH * group, T, D), dt)
+    k = jnp.asarray(rng.randn(B, KVH, S, D), dt)
+    v = jnp.asarray(rng.randn(B, KVH, S, D), dt)
+    w = rng.randn(*q.shape).astype("float32")
+    u = rng.randn(*q.shape[:3]).astype("float32") * with_lse
+    if offset < 0:
+        # a strict triangle leaves the first query no key: its row is
+        # the caller's to weight to zero (ring attention's merge does)
+        w[:, :, :-offset], u[:, :, :-offset] = 0.0, 0.0
+    w, u = jnp.asarray(w), jnp.asarray(u)
+    bias = _pad_bias(rng, B, S) if with_bias else None
+
+    def tiled(q, k, v, b):
+        kw = dict(bias=b, causal=causal, block_q=16, block_k=32,
+                  interpret=True, causal_offset=offset)
+        if with_lse:
+            return fa.flash_attention_with_lse(q, k, v, **kw)
+        return fa.flash_attention(q, k, v, **kw), 0.0
+
+    def ref(q, k, v, b):
+        return _attend_with_lse_reference(q, k, v, b, causal, offset)
+
+    def loss(fn):
+        def f(q, k, v, b):
+            out, lse = fn(q, k, v, b)
+            return jnp.sum(out.astype(jnp.float32) * w) + jnp.sum(lse * u)
+        return jax.grad(f, argnums=(0, 1, 2, 3) if with_bias else (0, 1, 2))
+
+    before = dict(fa.STATS)
+    fused = loss(tiled)(q, k, v, bias)
+    assert fa.STATS["tiled_bwd_fused"] == before["tiled_bwd_fused"] + 1
+    assert fa.STATS["tiled_bwd_split"] == before["tiled_bwd_split"]
+    monkeypatch.setattr(fa, "FUSED_BWD_VMEM", 0)
+    split = loss(tiled)(q, k, v, bias)
+    assert fa.STATS["tiled_bwd_split"] == before["tiled_bwd_split"] + 1
+    want = loss(ref)(q, k, v, bias)
+    tol = dict(rtol=1e-4, atol=1e-4) if dtype == "float32" \
+        else dict(rtol=3e-2, atol=3e-2)
+    same = dict(rtol=1e-6, atol=1e-6) if dtype == "float32" \
+        else dict(rtol=1e-2, atol=1e-2)
+    for name, a, b, c in zip(("dq", "dk", "dv", "db"), fused, split, want):
+        assert a.shape == c.shape and a.dtype == b.dtype, name
+        a, b, c = (np.asarray(x, np.float32) for x in (a, b, c))
+        np.testing.assert_allclose(a, c, err_msg=name, **tol)
+        np.testing.assert_allclose(a, b, err_msg=name, **same)
+
+
+def test_tiled_backward_over_the_vmem_budget_is_two_kernels():
+    """Which backward runs is a function of the shapes alone: where the
+    dq of a key-value head's query heads ([group * T, D] float32 and the
+    output's two buffers, 128 lanes wide in VMEM) is over FUSED_BWD_VMEM,
+    the trace holds flash_attention_dq and flash_attention_dkv; at the
+    benchmark cell's shape, and one power of two under the budget, it
+    holds flash_attention_bwd. Traced, not run."""
+    def kernels(H, KVH, T, D, dtype):
+        q = jax.ShapeDtypeStruct((1, H, T, D), dtype)
+        kv = jax.ShapeDtypeStruct((1, KVH, T, D), dtype)
+        before = dict(fa.STATS)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, interpret=True).astype(
+                    jnp.float32).sum(), argnums=(0, 1, 2)))(q, kv, kv))
+        names = sorted({n for n in ("flash_attention_bwd",
+                                    "flash_attention_dq",
+                                    "flash_attention_dkv") if n in text})
+        return names, {key: fa.STATS[key] - before[key]
+                       for key in ("tiled_bwd_fused", "tiled_bwd_split")}
+
+    assert fa._bwd_resident_bytes(4, 8192, 64, 2) == 32 * 1024 * 1024
+    assert kernels(32, 8, 8192, 64, jnp.bfloat16) == (
+        ["flash_attention_bwd"], {"tiled_bwd_fused": 1, "tiled_bwd_split": 0})
+    assert kernels(1, 1, 65536, 128, jnp.bfloat16) == (
+        ["flash_attention_bwd"], {"tiled_bwd_fused": 1, "tiled_bwd_split": 0})
+    assert kernels(1, 1, 131072, 128, jnp.bfloat16) == (
+        ["flash_attention_dkv", "flash_attention_dq"],
+        {"tiled_bwd_fused": 0, "tiled_bwd_split": 1})
+    assert kernels(8, 2, 32768, 64, jnp.float32)[0] == [
+        "flash_attention_dkv", "flash_attention_dq"]
+
+
+def test_causal_key_blocks_follow_the_sweep():
+    """_choose_blocks: 1024 keys a block under a causal diagonal, 2048
+    without one (the sweep above DEFAULT_BLOCK_Q); a caller's own blocks
+    win; what is legal does not depend on `causal`."""
+    assert fa._choose_blocks(8192, 8192, 64, 64) == (1024, 2048)
+    assert fa._choose_blocks(8192, 8192, 64, 64, causal=True) == (1024, 1024)
+    assert fa._choose_blocks(8192, 8192, 64, 64, 512, 2048, True) \
+        == (512, 2048)
+    # 1100 keys: one block of the whole axis either way
+    assert fa._choose_blocks(128, 1100, 64, 64, causal=True) == (128, 1100)
+    assert fa._choose_blocks(128, 1100, 64, 64) == (128, 1100)
+
+
+# ---------------------------------------------------------------------------
 # the short-sequence kernel: the op's own [B, T, H, D] layout, one tile
 # ---------------------------------------------------------------------------
 _SHORT_CASES = {
@@ -449,6 +591,10 @@ def test_bench_attention_tool_refuses_without_a_chip(tmp_path, monkeypatch,
     spec.loader.exec_module(tool)
     monkeypatch.chdir(tmp_path)
     assert tool.main(["[[2, 32, 32, 2, 16]]", "short,sdpa,tiled", "1"]) == 2
+    # a sixth number (key-value heads), blocks for the tiled kernel and
+    # the flags are read and still nothing is timed
+    assert tool.main(["[[2, 32, 32, 4, 16, 2]]", "sdpa,tiled:16x32", "1",
+                      "causal,nobias"]) == 2
     said = capsys.readouterr()
     assert "ms" not in said.out and "not a TPU" in said.err
     assert not (tmp_path / "chiprun_out").exists()

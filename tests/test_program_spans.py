@@ -206,17 +206,24 @@ def _kernel_lowerings():
     x = jnp.zeros((64, 128), f32)
     g = jnp.ones((128,), f32)
     q = jnp.zeros((1, 2, 256, 64), bf16)
+    # the dq of one head is over the fused backward's VMEM budget here
+    # (flash_attention.FUSED_BWD_VMEM): dq and dk / dv are two kernels
+    long = jax.ShapeDtypeStruct((1, 1, 131072, 64), bf16)
     ln = lambda a, s, b: layer_norm.layer_norm(a, s, b)          # noqa: E731
     fa = lambda a, b, c: flash_attention.flash_attention(a, b, c)  # noqa
     return {
         "layer_norm_fwd": (ln, (x, g, g)),
         "layer_norm_bwd": (jax.grad(lambda *a: ln(*a).sum()), (x, g, g)),
         "flash_attention_fwd": (fa, (q, q, q)),
+        "flash_attention_bwd": (
+            jax.grad(lambda *a: fa(*a).astype(f32).sum(), argnums=(0, 1, 2)),
+            (q, q, q)),
         "flash_attention_dq": (
-            jax.grad(lambda *a: fa(*a).astype(f32).sum()), (q, q, q)),
+            jax.grad(lambda *a: fa(*a).astype(f32).sum()),
+            (long, long, long)),
         "flash_attention_dkv": (
             jax.grad(lambda *a: fa(*a).astype(f32).sum(), argnums=(1, 2)),
-            (q, q, q)),
+            (long, long, long)),
         "embedding_lookup": (
             lambda t, i: embedding.lookup_pool(t, i, None),
             (jnp.zeros((512, 128), f32), jnp.zeros((64, 4), jnp.int32))),
@@ -231,7 +238,8 @@ def _kernel_lowerings():
 
 @pytest.mark.parametrize("kernel", [
     "layer_norm_fwd", "layer_norm_bwd", "flash_attention_fwd",
-    "flash_attention_dq", "flash_attention_dkv", "embedding_lookup",
+    "flash_attention_bwd", "flash_attention_dq", "flash_attention_dkv",
+    "embedding_lookup",
     "decode_attention", "quant_int8"])
 def test_each_pallas_kernel_has_its_name_in_its_lowering(kernel):
     """Lowered for the TPU without one: Mosaic lowering needs no device,
