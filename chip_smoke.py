@@ -196,7 +196,7 @@ def phase_train(cfg, batch, seq, steps, place, watch, kernels="compiled"):
 def chip_kernel_cases():
     """Each registered kernel at a shape its own hardware gate accepts
     (base width where the kernel has one): name -> (args, kwargs,
-    grad_argnums)."""
+    grad_argnums); `name@tag` is one more shape of kernel `name`."""
     import jax.numpy as jnp
     rng = np.random.RandomState(SEED)
 
@@ -208,6 +208,25 @@ def chip_kernel_cases():
     # groups them: the backward is the one kernel with dq resident
     flash = tuple(f32(1, heads, 4096, 64).astype(jnp.bfloat16)
                   for heads in (8, 2, 2))
+
+    # the cell solar_train_1chip's: head 128, 8 query heads over ONE
+    # key-value head at 8192, whose dq fills the one backward kernel's
+    # resident buffer to the byte
+    flash128 = tuple(f32(1, heads, 8192, 128).astype(jnp.bfloat16)
+                     for heads in (8, 1, 1))
+
+    def unit(*shape):
+        x = f32(*shape)
+        return (x / jnp.linalg.norm(x, axis=-1, keepdims=True)).astype(
+            jnp.bfloat16)
+
+    # that cell's scan: bf16 q, k, v, a float32 log-decay down to -1.6 a
+    # step (-100 over a chunk), steps in (0, 2); float32 state inside
+    kda = (unit(1, 8192, 8, 128), unit(1, 8192, 8, 128),
+           f32(1, 8192, 8, 128).astype(jnp.bfloat16),
+           jnp.asarray(-rng.uniform(0.001, 1.6, (1, 8192, 8, 128)),
+                       jnp.float32),
+           jnp.asarray(rng.uniform(0.0, 2.0, (1, 8192, 8)), jnp.float32))
 
     def int8(*shape):
         return jnp.asarray(rng.randint(-127, 128, size=shape), jnp.int8)
@@ -224,6 +243,8 @@ def chip_kernel_cases():
                         1.0 + 0.1 * f32(512), 0.1 * f32(512), 1e-5, 2),
                        {}, (0, 1, 2)),
         "flash_attention": (flash, {"causal": True}, (0, 1, 2)),
+        "flash_attention@head128": (flash128, {"causal": True}, (0, 1, 2)),
+        "kda_attention": (kda, {}, (0, 1, 2, 3, 4)),
         "lookup_pool": ((f32(512, 128),
                          jnp.asarray(rng.randint(-1, 512, size=(256, 8)),
                                      jnp.int32)),
@@ -254,7 +275,8 @@ def example_kernel_cases():
     """The registry's own small examples (interpret-runnable)."""
     from paddle_tpu.ops import kern
     rng = np.random.RandomState(0)
-    grads = {"layer_norm": (0, 1, 2), "flash_attention": (0, 1, 2)}
+    grads = {"layer_norm": (0, 1, 2), "flash_attention": (0, 1, 2),
+             "kda_attention": (0, 1, 2, 3, 4)}
     return {s.name: s.example(rng) + (grads.get(s.name, ()),)
             for s in kern.specs()}
 
@@ -290,19 +312,20 @@ def phase_kernels(cases, watch):
     from paddle_tpu.ops import kern
     from paddle_tpu.ops.pallas import flash_attention as fa
 
-    check(sorted(cases) == kern.names(),
+    check(sorted({c.split("@")[0] for c in cases}) == kern.names(),
           f"cases {sorted(cases)} != registered {kern.names()}")
     _use, interpret = fa.active()
     info = {}
-    for name in kern.names():
+    for name in sorted(cases):
         args, kwargs, argnums = cases[name]
+        kernel = name.split("@")[0]
         c0 = watch.compiles
         t0 = time.perf_counter()
-        ok, detail = kern.parity_check(name, args, kwargs)
+        ok, detail = kern.parity_check(kernel, args, kwargs)
         check(ok is True, f"{name}: parity {ok}: {detail}")
         line = f"fwd ok ({detail})"
         if argnums:
-            ok, detail = _grad_parity(kern.get(name), args, kwargs,
+            ok, detail = _grad_parity(kern.get(kernel), args, kwargs,
                                       argnums)
             check(ok is True, f"{name}: grad parity: {detail}")
             line += f"; grad ok ({detail})"

@@ -20,12 +20,14 @@ _KEEP_FP32_PARAM_SUFFIX = ("batch_norm", "layer_norm", "group_norm")
 # an op reads or writes through one of these slots keeps float32 (the
 # norms' scales and shifts: their kernels compute the statistics in
 # float32; a router's weight and the routing weights it gives: a router
-# that rounds its scores picks other experts). A name can be anything a
-# ParamAttr says.
+# that rounds its scores picks other experts; a linear-attention layer's
+# log-decay and what it is made from: the scan sums it over a chunk). A
+# name can be anything a ParamAttr says.
 _KEEP_FP32_SLOTS = {
     "batch_norm": ("Scale", "Bias"), "layer_norm": ("Scale", "Bias"),
     "group_norm": ("Scale", "Bias"), "rms_norm": ("Scale",),
     "moe_route": ("Weight", "TopkW"),
+    "kda_gate": ("ALog", "DtBias", "Out"),
 }
 
 

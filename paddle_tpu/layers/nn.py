@@ -39,6 +39,7 @@ __all__ = [
     "scaled_dot_product_attention", "multi_head_attention",
     "flash_attention", "rms_norm", "rotary_embedding", "short_conv",
     "swiglu", "moe_route", "moe_expert_ffn",
+    "l2_norm", "kda_gate", "kda_attention",
     "add_position_encoding", "lod_reset", "im2sequence",
     "logsumexp", "bilinear_tensor_product", "isfinite", "cos_sim",
     "unique_with_counts_stub", "maxout", "pixel_shuffle",
@@ -1493,7 +1494,9 @@ def multi_head_attention(queries, keys, values, attn_bias=None, d_key=64,
 
 
 # ---------------------------------------------------------------------------
-# decoder-only language-model blocks
+# decoder-only language-model blocks (models/lfm2_moe.py, solar_open2.py):
+# rms_norm, rotary_embedding, short_conv, swiglu, moe_route, moe_expert_ffn,
+# and the linear-attention layer's l2_norm, kda_gate, kda_attention
 # ---------------------------------------------------------------------------
 def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
     """y = x * rsqrt(mean(x^2, last axis) + epsilon) * w, statistics and
@@ -1592,6 +1595,57 @@ def moe_expert_ffn(input, topk_idx, topk_w, experts_held, first_expert,
         {"Out": [out], "LocalPairs": [pairs], "MaxExpertPairs": [fullest]},
         {"first_expert": int(first_expert)})
     return out, pairs, fullest
+
+
+def l2_norm(input, epsilon=1e-6, name=None):
+    """x / sqrt(sum(x^2, last axis) + epsilon), statistics in float32: the
+    norm a linear-attention layer puts on each head's q and k."""
+    return _same_shape_out(LayerHelper("l2_norm", name=name), input,
+                           "l2_norm", {"epsilon": float(epsilon)})
+
+
+def kda_gate(input, a_log_attr=None, dt_bias_attr=None, name=None):
+    """The log-decay of a gated delta-rule layer over [B, T, H, D]:
+    g = -exp(A_log) * softplus(x + dt_bias), <= 0, one per key channel;
+    `A_log` [H] (`<name>.w_0`) and `dt_bias` [H, D] (`<name>.w_1`) are
+    float32 parameters and so is g, whatever the program is cast to.
+    Defaults: A_log = log 8 (the middle of the public U(1, 16)), dt_bias
+    the inverse softplus of a step of 0.01."""
+    helper = LayerHelper("kda_gate", name=name)
+    H, D = int(input.shape[2]), int(input.shape[3])
+    a_log = helper.create_parameter(
+        a_log_attr, [H], "float32",
+        default_initializer=ConstantInitializer(float(np.log(8.0))))
+    dt_bias = helper.create_parameter(
+        dt_bias_attr, [H, D], "float32",
+        default_initializer=ConstantInitializer(
+            float(np.log(np.expm1(0.01)))))
+    out = helper.create_variable_for_type_inference("float32", input.shape)
+    helper.append_op("kda_gate",
+                     {"X": [input], "ALog": [a_log], "DtBias": [dt_bias]},
+                     {"Out": [out]})
+    return out
+
+
+def kda_attention(q, k, v, g, beta, scale=None, name=None):
+    """Gated delta-rule linear attention with a per-channel decay (Kimi
+    Delta Attention) over heads kept as [B, T, H, D]: per head a float32
+    state S [Dk, Dv], zero before the sequence,
+
+        S_t = (I - beta_t k_t k_t^T) diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t * scale           (scale: Dk ** -0.5)
+
+    g [B, T, H, Dk] is the log-decay (`kda_gate`), beta [B, T, H] the
+    step. One op, run in chunks of 64 tokens (ops/kernels_scan.py) and
+    differentiated by the tracer; -> [B, T, H, Dv] in q's dtype."""
+    helper = LayerHelper("kda_attention", name=name)
+    out = helper.create_variable_for_type_inference(
+        q.dtype, tuple(q.shape[:3]) + (v.shape[3],))
+    helper.append_op("kda_attention",
+                     {"Q": [q], "K": [k], "V": [v], "G": [g],
+                      "Beta": [beta]}, {"Out": [out]},
+                     {"scale": scale or int(q.shape[3]) ** -0.5})
+    return out
 
 
 def add_position_encoding(input, alpha=1.0, beta=1.0, name=None):

@@ -11,4 +11,5 @@ from . import kernels_vision
 from . import kernels_control
 from . import kernels_extra
 from . import kernels_moe
+from . import kernels_scan
 from .registry import KERNELS, get_kernel, has_kernel

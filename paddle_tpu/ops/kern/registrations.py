@@ -1,4 +1,7 @@
-"""Every Pallas kernel, declared to the registry.
+"""Every Pallas kernel, declared to the registry (and the one chunked
+scan that is a jnp composition, `kda_attention`: it takes every shape on
+every backend, and is here for its reference, its parity gate and its
+count of calls).
 
 One KernelSpec per kernel: the try_* dispatch entry, the jnp reference
 composition it must match, a STATIC capability probe (runs on
@@ -13,6 +16,7 @@ subsystems can ask "would this kernel take these shapes?" statically.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..pallas import flash_attention as fa
 from ..pallas import layer_norm as ln
@@ -20,6 +24,7 @@ from ..pallas import embedding as emb
 from ..pallas import grouped_matmul as gm
 from . import decode_attention as da
 from . import quant
+from .. import kernels_scan as scan
 from .registry import KernelSpec, register
 
 
@@ -265,4 +270,32 @@ register(KernelSpec(
     example=_moe_example,
     note="no-drop expert FFN of the experts held here: grouped products "
          "over pairs sorted by expert, fwd+bwd (custom_vjp)",
+))
+
+
+# ----------------------------------------------------------- kda_attention
+def _kda_example(rng):
+    B, T, H, D = 1, 80, 2, 16          # T off a multiple of the chunk
+    def unit(*shape):
+        x = rng.standard_normal(shape)
+        return jnp.asarray(x / np.linalg.norm(x, axis=-1, keepdims=True),
+                           jnp.float32)
+    q, k = unit(B, T, H, D), unit(B, T, H, D)
+    v = jnp.asarray(rng.standard_normal((B, T, H, D)), jnp.float32)
+    g = jnp.asarray(-rng.uniform(0.0, 3.0, size=(B, T, H, D)), jnp.float32)
+    beta = jnp.asarray(rng.uniform(0.0, 2.0, size=(B, T, H)), jnp.float32)
+    return (q, k, v, g, beta), {}
+
+
+register(KernelSpec(
+    name="kda_attention",
+    fn=scan.kda_chunked,
+    reference=scan.kda_recurrent,
+    probe=scan.kda_shapes_ok,
+    tol=(1e-4, 1e-4),
+    example=_kda_example,
+    note="gated delta rule, per-channel decay: chunks of 64 (WY / UT "
+         "transform, sub-blocks of 16), a jnp composition on every "
+         "backend, differentiated by the tracer; reference: the "
+         "token-by-token recurrence",
 ))

@@ -69,7 +69,8 @@ def test_kernels_phase_runs_every_registered_kernel(smoke, watch,
     info = smoke.phase_kernels(smoke.example_kernel_cases(), watch)
     assert sorted(info) == ["decode_attend", "dequant_attend_int8",
                             "flash_attention", "int8_quant",
-                            "layer_norm", "lookup_pool", "moe_expert_ffn"]
+                            "kda_attention", "layer_norm", "lookup_pool",
+                            "moe_expert_ffn"]
 
 
 def test_kernels_phase_fails_on_a_rejected_shape(smoke, watch):
@@ -84,9 +85,13 @@ def test_chip_kernel_cases_pass_their_hardware_gates(smoke):
     hardware gate (interpret=False) accepts."""
     from paddle_tpu.ops import kern
     cases = smoke.chip_kernel_cases()
-    assert sorted(cases) == kern.names()
+    assert sorted({c.split("@")[0] for c in cases}) == kern.names()
+    assert {"flash_attention@head128", "kda_attention"} <= set(cases)
     for name, (args, kwargs, _argnums) in cases.items():
-        assert kern.get(name).probe(*args, **kwargs), name
+        assert kern.get(name.split("@")[0]).probe(*args, **kwargs), name
+    q, k, v = cases["flash_attention@head128"][0]
+    assert (q.shape, k.shape) == ((1, 8, 8192, 128), (1, 1, 8192, 128))
+    assert cases["kda_attention"][0][0].shape == (1, 8192, 8, 128)
 
 
 def test_dp_phase_over_the_virtual_mesh(smoke, watch, interpret):

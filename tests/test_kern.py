@@ -15,7 +15,8 @@ from paddle_tpu.ops.kern import registry as kreg
 from paddle_tpu.ops.pallas import flash_attention as fa
 
 KERNELS = ("decode_attend", "dequant_attend_int8", "flash_attention",
-           "int8_quant", "layer_norm", "lookup_pool", "moe_expert_ffn")
+           "int8_quant", "kda_attention", "layer_norm", "lookup_pool",
+           "moe_expert_ffn")
 
 
 @pytest.fixture
@@ -28,7 +29,7 @@ def interpret_mode():
 
 
 # ------------------------------------------------------------- parity
-def test_the_seven_kernels_are_registered():
+def test_the_eight_kernels_are_registered():
     assert tuple(kreg.names()) == KERNELS
     for name in kreg.names():
         spec = kreg.get(name)

@@ -226,3 +226,57 @@ def test_moe_combine_compiles_at_the_cells_shapes(one_chip, weighted):
     assert out.shape == (N, Hd) and out.dtype == jnp.bfloat16
     # no [N, k, Hd] intermediate: the plan's index arrays and little else
     assert compiled.memory_analysis().temp_size_in_bytes < N * Hd * 2
+
+
+def test_tiled_attention_at_head_128_with_one_key_value_head_compiles(
+        one_chip):
+    """The cell solar_train_1chip's attention: [1, 8192, 8 x 128] queries
+    over ONE key-value head, causal, bf16. The dq of the key-value head's
+    eight query heads is 64 MiB with the output's buffers, FUSED_BWD_VMEM
+    to the byte: the one backward kernel runs, and Mosaic takes it."""
+    Bq, T, Hq, KV, Dh = 1, 8192, 8, 1, 128
+    sds = jax.ShapeDtypeStruct
+    q = sds((Bq, T, Hq, Dh), jnp.bfloat16, sharding=one_chip)
+    kv = sds((Bq, T, KV, Dh), jnp.bfloat16, sharding=one_chip)
+    assert not fa.picks_short(q, kv, kv, None, layout="bthd")
+    assert fa._bwd_resident_bytes(Hq // KV, T, Dh, 2) == fa.FUSED_BWD_VMEM
+
+    def step(q, k, v, g):
+        def attend(q, k, v):
+            return fa.flash_attention(
+                q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
+                causal=True).swapaxes(1, 2)
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out,) + vjp(g)
+
+    compiled = jax.jit(step).lower(q, kv, kv, q).compile()
+    names = _kernel_names(compiled)
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd"):
+        assert any(kernel in n for n in names), names
+    assert not any("flash_attention_dq" in n or "flash_attention_dkv" in n
+                   for n in names), names
+    _, dq, dk, dv = jax.eval_shape(step, q, kv, kv, q)
+    assert dk.shape == dv.shape == (Bq, T, KV, Dh) and dq.shape == q.shape
+
+
+def test_the_chunked_scan_compiles_at_the_cells_shape(one_chip):
+    """`kda_attention` as solar_train_1chip calls it: [1, 8192, 8 x 128]
+    bf16 q, k, v, a float32 log-decay, forward and backward (the tracer's
+    own differentiation of the jnp composition: no Mosaic kernel). What
+    the backward keeps at once stays near a gigabyte: the [.., 16, 16,
+    128] tensors of the sub-blocks' explicit differences are fused into
+    their sums, not kept a chunk each."""
+    from paddle_tpu.ops import kernels_scan as scan
+    Bq, T, Hq, Dh = 1, 8192, 8, 128
+    sds = jax.ShapeDtypeStruct
+    x = sds((Bq, T, Hq, Dh), jnp.bfloat16, sharding=one_chip)
+    g = sds((Bq, T, Hq, Dh), jnp.float32, sharding=one_chip)
+    beta = sds((Bq, T, Hq), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return jnp.sum(scan.kda_chunked(q, k, v, g, beta).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, g, beta).compile()
+    assert not _kernel_names(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9

@@ -452,6 +452,16 @@ SPECS["moe_expert_ffn"] = S(
      "W1": (2, 4, 5), "W3": (2, 4, 5), "W2": (2, 5, 4)},
     {"first_expert": 1}, f32=True)
 
+# the linear-attention layer: a log-decay below zero, a step in (0, 2);
+# 70 tokens are one chunk and a part of the next
+SPECS["l2_norm"] = S({"X": (2, 3, 8)}, {"epsilon": 1e-6}, f32=True)
+SPECS["kda_gate"] = S({"X": (1, 4, 2, 3), "ALog": (2,), "DtBias": (2, 3)},
+                      f32=True)
+SPECS["kda_attention"] = S(
+    {"Q": (1, 70, 2, 4), "K": (1, 70, 2, 4), "V": (1, 70, 2, 4),
+     "G": -np.random.RandomState(34).uniform(0.05, 2.0, (1, 70, 2, 4)),
+     "Beta": ("pos", (1, 70, 2))}, {"scale": 0.5}, f32=True)
+
 # recurrent (weights + input grads through lax.scan)
 SPECS["lstm"] = S(
     {"Input": (2, 5, 4), "WeightIH": (4, 12), "WeightHH": (3, 12)},
