@@ -1636,8 +1636,11 @@ def kda_attention(q, k, v, g, beta, scale=None, name=None):
         o_t = S_t^T q_t * scale           (scale: Dk ** -0.5)
 
     g [B, T, H, Dk] is the log-decay (`kda_gate`), beta [B, T, H] the
-    step. One op, run in chunks of 64 tokens (ops/kernels_scan.py) and
-    differentiated by the tracer; -> [B, T, H, Dv] in q's dtype."""
+    step. One op, run in chunks of 64 tokens (ops/kernels_scan.py): on a
+    TPU at Dk, Dv multiples of 128 the Mosaic kernels of
+    ops/pallas/kda.py with their own backward, elsewhere a jnp
+    composition differentiated by the tracer; -> [B, T, H, Dv] in q's
+    dtype."""
     helper = LayerHelper("kda_attention", name=name)
     out = helper.create_variable_for_type_inference(
         q.dtype, tuple(q.shape[:3]) + (v.shape[3],))

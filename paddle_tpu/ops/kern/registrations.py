@@ -1,7 +1,4 @@
-"""Every Pallas kernel, declared to the registry (and the one chunked
-scan that is a jnp composition, `kda_attention`: it takes every shape on
-every backend, and is here for its reference, its parity gate and its
-count of calls).
+"""Every Pallas kernel, declared to the registry.
 
 One KernelSpec per kernel: the try_* dispatch entry, the jnp reference
 composition it must match, a STATIC capability probe (runs on
@@ -22,6 +19,7 @@ from ..pallas import flash_attention as fa
 from ..pallas import layer_norm as ln
 from ..pallas import embedding as emb
 from ..pallas import grouped_matmul as gm
+from ..pallas import kda
 from . import decode_attention as da
 from . import quant
 from .. import kernels_scan as scan
@@ -275,7 +273,7 @@ register(KernelSpec(
 
 # ----------------------------------------------------------- kda_attention
 def _kda_example(rng):
-    B, T, H, D = 1, 80, 2, 16          # T off a multiple of the chunk
+    B, T, H, D = 1, 80, 2, 128         # T off a multiple of the chunk
     def unit(*shape):
         x = rng.standard_normal(shape)
         return jnp.asarray(x / np.linalg.norm(x, axis=-1, keepdims=True),
@@ -289,13 +287,14 @@ def _kda_example(rng):
 
 register(KernelSpec(
     name="kda_attention",
-    fn=scan.kda_chunked,
+    fn=kda.try_kda,
     reference=scan.kda_recurrent,
-    probe=scan.kda_shapes_ok,
+    probe=kda.supports,
     tol=(1e-4, 1e-4),
     example=_kda_example,
-    note="gated delta rule, per-channel decay: chunks of 64 (WY / UT "
-         "transform, sub-blocks of 16), a jnp composition on every "
-         "backend, differentiated by the tracer; reference: the "
-         "token-by-token recurrence",
+    note="gated delta rule, per-channel decay: chunks of 64 in VMEM (WY / "
+         "UT transform, sub-blocks of 16), the state resident across a "
+         "head's chunks, fwd+bwd (custom_vjp, hand-derived); float32 at "
+         "HIGHEST; Dk, Dv multiples of 128, else the op's jnp composition; "
+         "reference: the token-by-token recurrence",
 ))

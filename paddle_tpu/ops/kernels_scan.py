@@ -8,9 +8,13 @@ sequence), a decay g_t <= 0 per key channel and a step beta_t:
     o_t = S_t^T q_t * scale
 
 `kda_recurrent` walks the tokens one at a time (the definition, and the
-registry's reference). `kda_chunked` is what the op runs: chunks of
-`CHUNK` tokens, inside a chunk the WY / UT transform, between chunks one
-state a head. With G_i the decay summed from the chunk's start to row i,
+registry's reference). The op runs the chunked form: chunks of `CHUNK`
+tokens, inside a chunk the WY / UT transform, between chunks one state a
+head. Where the trace lowers for a TPU and Dk, Dv are multiples of 128 it
+is the Mosaic kernels of ops/pallas/kda.py (`try_kda`: a chunk in VMEM,
+forward and a hand-derived backward); everywhere else (off the TPU, any
+other width) it is `kda_chunked` below, a jnp composition of the same
+rule. With G_i the decay summed from the chunk's start to row i,
 
     A_ij = beta_i sum_d k_id k_jd exp(G_id - G_jd)      j <  i
     P_ij =        sum_d q_id k_jd exp(G_id - G_jd)      j <= i
@@ -27,12 +31,12 @@ and k_j exp(G_r - G_j), both exponents <= 0. Inside a sub-block the
 exponent is the explicit difference G_i - G_j, masked to j <= i before
 the exponential. Nothing is ever raised to a positive power.
 
-Only the chain of chunk states is sequential (`lax.scan` over T / CHUNK
-products of [Dk, Dk] with [Dk, Dv]); everything else is batched over the
-chunks. `jax.value_and_grad` goes through all of it: there is no
-hand-written backward. The whole function is rematerialised in the
-backward pass (`jax.checkpoint`), so a layer keeps q, k, v, g and beta and
-none of the chunk tensors.
+In `kda_chunked` only the chain of chunk states is sequential (`lax.scan`
+over T / CHUNK products of [Dk, Dk] with [Dk, Dv]); everything else is
+batched over the chunks. `jax.value_and_grad` goes through all of it:
+the composition has no hand-written backward. The whole function is
+rematerialised in the backward pass (`jax.checkpoint`), so a layer keeps
+q, k, v, g and beta and none of the chunk tensors.
 """
 import functools
 
@@ -167,24 +171,21 @@ def kda_chunked(q, k, v, g, beta, scale=None):
     return _kda_chunked(q, k, v, g, beta, float(scale)).astype(q.dtype)
 
 
-def kda_shapes_ok(q, k, v, g, beta, scale=None, interpret=False):
-    """The registry's static probe: five arrays of one batch, length and
-    head count."""
-    return (q.ndim == 4 and q.shape == k.shape == g.shape
-            and v.shape[:3] == q.shape[:3] and beta.shape == q.shape[:3])
-
-
 @kernel("kda_attention")
 def _kda_attention(ctx, ins, attrs):
     """Q, K, G [B, T, H, Dk], V [B, T, H, Dv], Beta [B, T, H] -> Out [B,
     T, H, Dv]: the gated delta rule with a per-channel decay, causal, zero
-    state before the sequence, in chunks (`kda_chunked`); float32 inside,
-    Out in Q's dtype. Dispatched through the kern registry, whose STATS
-    count the calls and whose reference is the token-by-token recurrence."""
+    state before the sequence, in chunks; float32 inside, Out in Q's dtype.
+    Dispatched through the kern registry (whose STATS count the calls and
+    whose reference is the token-by-token recurrence) to the Mosaic
+    kernels, `pallas.kda.try_kda`; where that says None, `kda_chunked`."""
     args = (ins["Q"][0], ins["K"][0], ins["V"][0], ins["G"][0],
             ins["Beta"][0])
-    return {"Out": [ctx.accel("kda_attention")(*args,
-                                               scale=attrs.get("scale"))]}
+    scale = attrs.get("scale")
+    out = ctx.accel("kda_attention")(*args, scale=scale)
+    if out is None:
+        out = kda_chunked(*args, scale=scale)
+    return {"Out": [out]}
 
 
 @kernel("kda_gate")

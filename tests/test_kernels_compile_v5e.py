@@ -261,12 +261,15 @@ def test_tiled_attention_at_head_128_with_one_key_value_head_compiles(
 
 def test_the_chunked_scan_compiles_at_the_cells_shape(one_chip):
     """`kda_attention` as solar_train_1chip calls it: [1, 8192, 8 x 128]
-    bf16 q, k, v, a float32 log-decay, forward and backward (the tracer's
-    own differentiation of the jnp composition: no Mosaic kernel). What
-    the backward keeps at once stays near a gigabyte: the [.., 16, 16,
-    128] tensors of the sub-blocks' explicit differences are fused into
-    their sums, not kept a chunk each."""
-    from paddle_tpu.ops import kernels_scan as scan
+    bf16 q, k, v, a float32 log-decay, forward and gradient through the
+    dispatch entry under a TPU lowering: Mosaic takes `kda_fwd` and
+    `kda_bwd`, no XLA `while` walks the chunk states or the solve's rows
+    (the sequence is the kernels' last grid axis), and what the module
+    keeps beside its operands is the state every chunk starts on
+    (8 x 128 x [128, 128] float32 = 67.1e6 B) and little else: 84.7e6 B
+    read at PR 35 (the composition's was near a gigabyte)."""
+    from paddle_tpu.ops import registry
+    from paddle_tpu.ops.pallas import kda
     Bq, T, Hq, Dh = 1, 8192, 8, 128
     sds = jax.ShapeDtypeStruct
     x = sds((Bq, T, Hq, Dh), jnp.bfloat16, sharding=one_chip)
@@ -274,9 +277,18 @@ def test_the_chunked_scan_compiles_at_the_cells_shape(one_chip):
     beta = sds((Bq, T, Hq), jnp.bfloat16, sharding=one_chip)
 
     def loss(q, k, v, g, beta):
-        return jnp.sum(scan.kda_chunked(q, k, v, g, beta).astype(jnp.float32))
+        return jnp.sum(kda.try_kda(q, k, v, g, beta).astype(jnp.float32))
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
-        x, x, x, g, beta).compile()
-    assert not _kernel_names(compiled)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    taken = kda.STATS["pallas_calls"]
+    with registry.lowering_for("tpu"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+            x, x, x, g, beta).compile()
+    assert kda.STATS["pallas_calls"] == taken + 1
+    names = _kernel_names(compiled)
+    assert len(names) == 2, names
+    assert any("kda_fwd" in n for n in names), names
+    assert any("kda_bwd" in n for n in names), names
+    opcodes = {i[2] for i in _instructions(compiled.as_text())}
+    assert not opcodes & {"while", "triangular-solve", "dot",
+                          "convolution"}, opcodes
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e8
