@@ -244,6 +244,13 @@ def chip_kernel_cases():
                        {}, (0, 1, 2)),
         "flash_attention": (flash, {"causal": True}, (0, 1, 2)),
         "flash_attention@head128": (flash128, {"causal": True}, (0, 1, 2)),
+        # one key-value head of the cell mellum2_train_1chip's sliding
+        # layers (it has four such rows of the grid; the composition's
+        # float32 scores of all 32 query heads would not fit beside their
+        # gradient): a window of 1024, the band's kernels
+        "flash_attention@window": (flash128,
+                                   {"causal": True, "window": 1024},
+                                   (0, 1, 2)),
         "kda_attention": (kda, {}, (0, 1, 2, 3, 4)),
         "lookup_pool": ((f32(512, 128),
                          jnp.asarray(rng.randint(-1, 512, size=(256, 8)),

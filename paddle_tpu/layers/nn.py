@@ -1393,12 +1393,18 @@ def scaled_dot_product_attention(queries, keys, values, num_heads=1,
 
 
 def flash_attention(q, k, v, attn_bias=None, causal=False, scale=None,
-                    use_flash=True, name=None):
+                    use_flash=True, name=None, window=None):
     """The attention itself over heads kept as [B, T, H, Dh] (the
     `bthd` layout of the `flash_attention` op): softmax(q k^T * scale +
     attn_bias) v -> [B, T, H, Dv]. k and v may have fewer heads than q
     (grouped-query attention: H / KVH query heads that follow one another
-    share each key-value head)."""
+    share each key-value head). `window` (with `causal`; a sliding
+    window, as Mellum 2's `sliding_attention` layers have it): query t
+    sees the `window` keys `t - window < s <= t` only, its own among
+    them; an attribute of the one op, None by default."""
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError("window is a causal band of at least one key: "
+                         f"causal={causal}, window={window}")
     helper = LayerHelper("multi_head_attention", name=name)
     out = helper.create_variable_for_type_inference(
         q.dtype, tuple(q.shape[:3]) + (v.shape[3],))
@@ -1411,7 +1417,8 @@ def flash_attention(q, k, v, attn_bias=None, causal=False, scale=None,
                      ins, {"Out": [out], "Weights": [wvar]},
                      {"causal": causal,
                       "scale": scale or int(q.shape[3]) ** -0.5,
-                      "layout": "bthd"})
+                      "layout": "bthd",
+                      **({} if window is None else {"window": int(window)})})
     return out
 
 
@@ -1512,11 +1519,34 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(input, theta=10000.0, name=None):
+def rotary_embedding(input, theta=10000.0, name=None, rope_type="default",
+                     factor=None, original_max_position_embeddings=None,
+                     beta_fast=32.0, beta_slow=1.0, attention_factor=None):
     """Rotary positions (rotate-half form) over [B, T, H, Dh]; position t
-    is the index along axis 1."""
+    is the index along axis 1, pair j turns by t * theta^(-2j/Dh).
+    `rope_type="yarn"` (a published `rope_parameters` block's keys, as
+    Mellum 2's full-attention layers carry them): the pairs that turn
+    more than `beta_fast` times over `original_max_position_embeddings`
+    keep that frequency, those that turn less than `beta_slow` times are
+    slowed by `factor`, a linear ramp between them (its ends rounded
+    outwards), and cos and sin are scaled by `attention_factor`. The
+    default call is the plain power law, as it was."""
+    attrs = {"theta": float(theta)}
+    if rope_type == "yarn":
+        if not (factor and original_max_position_embeddings
+                and attention_factor):
+            raise ValueError("yarn needs factor, attention_factor and "
+                             "original_max_position_embeddings")
+        attrs.update(
+            rope_type="yarn", factor=float(factor),
+            original_max_position_embeddings=int(
+                original_max_position_embeddings),
+            beta_fast=float(beta_fast), beta_slow=float(beta_slow),
+            attention_factor=float(attention_factor))
+    elif rope_type != "default":
+        raise ValueError(f"rope_type is default or yarn, not {rope_type!r}")
     return _same_shape_out(LayerHelper("rotary_embedding", name=name), input,
-                           "rotary_embedding", {"theta": float(theta)})
+                           "rotary_embedding", attrs)
 
 
 def short_conv(input, filter_size=3, param_attr=None, name=None):
@@ -1538,14 +1568,17 @@ def swiglu(x, y, name=None):
 
 def moe_route(input, num_experts, k, use_expert_bias=True,
               norm_topk_prob=True, routed_scaling_factor=1.0,
-              param_attr=None, name=None):
+              param_attr=None, name=None, scoring="sigmoid"):
     """The router of a sparse expert layer over [..., H]: sigmoid scores
-    of ALL `num_experts`, the k experts chosen by score + bias, their
-    weights the chosen scores renormalised and scaled; float32
+    of ALL `num_experts` (`scoring="softmax"`: their softmax over all of
+    them, as Mellum 2's router), the k experts chosen by score + bias,
+    their weights the chosen scores renormalised and scaled; float32
     throughout. Returns (topk_idx [..., k] int32, topk_w [..., k]
     float32). The bias is a persistable variable that is no Parameter
     (it only selects; no gradient, no optimizer state), `<name>.bias`,
     zero until something sets it."""
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"scoring is sigmoid or softmax, not {scoring!r}")
     helper = LayerHelper("moe_route", name=name)
     w = helper.create_parameter(
         param_attr, shape=[int(input.shape[-1]), int(num_experts)],
@@ -1563,7 +1596,9 @@ def moe_route(input, num_experts, k, use_expert_bias=True,
     tw = helper.create_variable_for_type_inference("float32", lead + (k,))
     helper.append_op("moe_route", ins, {"TopkIdx": [idx], "TopkW": [tw]},
                      {"k": int(k), "norm_topk_prob": bool(norm_topk_prob),
-                      "routed_scaling_factor": float(routed_scaling_factor)})
+                      "routed_scaling_factor": float(routed_scaling_factor),
+                      **({} if scoring == "sigmoid"
+                         else {"scoring": scoring})})
     return idx, tw
 
 

@@ -94,13 +94,18 @@ def _flash_bhtd(q, k, v, layout="bhtd"):
 
 def _flash_probe(q, k, v, bias=None, causal=False, scale=None,
                  with_lse=False, causal_offset=0, *, interpret=False,
-                 layout="bhtd", **kw):
+                 layout="bhtd", window=None, **kw):
     """try_flash's own tests, less the backend gate: the short kernel's
-    pick, then the tiled kernel's gate and shapes."""
+    pick, then the tiled kernel's gate and shapes (a window is the tiled
+    kernel's alone, on a causal, unshifted diagonal, without the lse)."""
     if getattr(q, "ndim", 0) != 4:
         return False
+    if window is not None:
+        if with_lse or causal_offset or not causal:
+            return False
+        window = fa._window(window, causal, fa._tiled_dims(q, k, layout)[1])
     if fa.picks_short(q, k, v, bias, with_lse, causal_offset, layout,
-                      interpret):
+                      interpret, window):
         return True
     if not interpret \
             and fa._tiled_dims(q, k, layout)[1] < fa.tiled_min_len(

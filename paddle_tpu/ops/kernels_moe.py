@@ -23,7 +23,8 @@ def _moe_route(ctx, ins, attrs):
     """X [..., H], Weight [H, E], optional Bias [E] -> TopkIdx [..., k]
     (int32, ids over all E experts), TopkW [..., k] (float32).
 
-    s = sigmoid(x Weight); the k experts are chosen by s + Bias (the
+    s = sigmoid(x Weight), or with `scoring: softmax` the softmax of
+    x Weight over all E; the k experts are chosen by s + Bias (the
     load-balancing bias only selects; among equal values the lower id
     wins), the weights are s of the chosen, renormalised to sum to one
     (`norm_topk_prob`) and scaled. All of it float32: a router that
@@ -32,7 +33,12 @@ def _moe_route(ctx, ins, attrs):
     logits = jnp.einsum("...h,he->...e", x.astype(jnp.float32),
                         w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.sigmoid(logits)
+    scoring = attrs.get("scoring", "sigmoid")
+    if scoring not in ("sigmoid", "softmax"):
+        raise ValueError(f"moe_route scores by sigmoid or softmax, not "
+                         f"{scoring!r}")
+    s = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
     bias = ins.get("Bias")
     sel = s + bias[0].astype(jnp.float32) if bias else s
     _, idx = jax.lax.top_k(jax.lax.stop_gradient(sel), attrs["k"])

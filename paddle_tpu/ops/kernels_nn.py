@@ -8,6 +8,7 @@ control flow), and sequence (LoD) ops act on padded arrays + length masks
 (static shapes, SURVEY §6).
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -795,6 +796,11 @@ def _sdpa(ctx, ins, attrs):
     if attrs.get("causal", False):
         T, S = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((T, S), dtype=bool), k=S - T)
+        window = attrs.get("window")
+        if window is not None:
+            # a sliding window: query t sees keys t - window < s <= t
+            cm = cm & ~jnp.tril(jnp.ones((T, S), dtype=bool),
+                                k=S - T - window)
         logits = jnp.where(cm, logits, -jnp.inf)
     w = _attn_softmax(logits).astype(q.dtype)
     if bthd:
@@ -925,7 +931,11 @@ def _flash_attention(ctx, ins, attrs):
     and writes [B, T, H*D] as the model keeps it, and _sdpa's dots
     contract with H as a middle batch dim. Only the tiled
     long-sequence kernel still wants bhtd; try_flash transposes for it
-    where it picks it."""
+    where it picks it.
+
+    window attr (with causal): query t sees the `window` keys
+    `t - window < s <= t` only. None (the default) compiles out of every
+    kernel, as a missing Mask does."""
     q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     mask = _opt(ins, "Mask")
     causal = attrs.get("causal", False)
@@ -937,7 +947,8 @@ def _flash_attention(ctx, ins, attrs):
     fused = ctx.accel("flash_attention")
     if fused is not None:
         out = fused(q, k, v, bias=mask, causal=causal, scale=scale,
-                    layout=attrs.get("layout", "bhtd"))
+                    layout=attrs.get("layout", "bhtd"),
+                    window=attrs.get("window"))
         if out is not None:
             return {"Out": [out], "Weights": [jnp.zeros((0,), q.dtype)]}
     # no kernel wins at this shape (the measured table is in PERF.md
@@ -964,19 +975,46 @@ def _rms_norm(ctx, ins, attrs):
     return {"Y": [y.astype(x.dtype)]}
 
 
+def _yarn_inv_freq(inv, attrs, D):
+    """YaRN's frequencies from the power law's `inv` [D/2]: the pairs
+    that turn more than `beta_fast` times over the original context keep
+    their frequency, those that turn less than `beta_slow` times are
+    slowed by `factor`, and a linear ramp blends the pairs between."""
+    base = float(attrs.get("theta", 10000.0))
+    original = float(attrs["original_max_position_embeddings"])
+
+    def pair(rotations):      # the pair that turns so often over `original`
+        return D * math.log(original / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair(attrs.get("beta_fast", 32.0))), 0)
+    high = min(math.ceil(pair(attrs.get("beta_slow", 1.0))), D - 1)
+    ramp = jnp.clip((jnp.arange(D // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inv / jnp.float32(attrs["factor"]) * ramp + inv * (1.0 - ramp)
+
+
 @kernel("rotary_embedding")
 def _rotary_embedding(ctx, ins, attrs):
     """Rotary positions in the rotate-half form over X [B, T, H, D]:
-    position t turns the pair (x[i], x[i + D/2]) by t * theta^(-2i/D).
-    The angles are float32 whatever X is."""
+    position t turns the pair (x[i], x[i + D/2]) by t * theta^(-2i/D),
+    or with `rope_type: yarn` by t times YaRN's blend of that frequency
+    and its `factor`-th, cos and sin scaled by `attention_factor`. The
+    angles are float32 whatever X is."""
     x = _x(ins)
     T, D = x.shape[1], x.shape[-1]
     half = D // 2
     inv = jnp.power(jnp.float32(attrs.get("theta", 10000.0)),
                     -jnp.arange(half, dtype=jnp.float32) * 2.0 / D)
+    yarn = attrs.get("rope_type", "default") == "yarn"
+    if yarn:
+        inv = _yarn_inv_freq(inv, attrs, D)
     ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
+    if yarn:
+        m = jnp.float32(attrs["attention_factor"])
+        cos, sin = cos * m, sin * m
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :half], xf[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)

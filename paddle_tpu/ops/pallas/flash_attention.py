@@ -73,6 +73,23 @@ Supported extras (covers the flagship transformer end-to-end):
   ~-1e30, so (out, lse)-merging callers (ring attention) weight it to
   zero. Do not read fully-masked rows from the plain `flash_attention`
   output.
+- `window` (with `causal`, on an unshifted diagonal): a sliding window,
+  query t sees the keys `t - window < s <= t` only. The band of blocks
+  has a lower edge as well as the diagonal: a q block has a FIRST k block
+  (_first_k) as well as a last (_last_k), a k block a LAST q block
+  (_last_q) as well as a first (_first_q), and the innermost grid axis
+  walks the band's blocks only (_band_steps: two k blocks a q block of
+  eight at 8192 with a window of 1024 and 1024 x 1024 blocks), so a
+  block outside the band is neither computed, fetched nor stepped over;
+  a block that either edge crosses is masked, one wholly inside takes
+  the body without the mask. In the one backward kernel a q block's
+  rows of the resident dq open at the first k block of its band and
+  close at the last. The kernels of a windowed call carry names of
+  their own (flash_attention_win_fwd, _win_bwd; _win_dq / _win_dkv over
+  FUSED_BWD_VMEM) and STATS["tiled_window"] counts its backwards.
+  window=None statically compiles all of it out: the kernels, their
+  names, grids and index maps are then what they were. A window at or
+  over the key length is no window (_window).
 
 The tiled kernel's blocks default to 1024 queries x 2048 keys, 1024 x
 1024 under a causal diagonal (clamped to a VMEM budget per head dim, see
@@ -180,7 +197,10 @@ MIN_SEQ_LEN_BTHD = 1024
 STATS = {"pallas_calls": 0,
          # which backward the tiled kernel traced: one kernel with dq
          # resident in VMEM, or dq and dk / dv apart (FUSED_BWD_VMEM)
-         "tiled_bwd_fused": 0, "tiled_bwd_split": 0}
+         "tiled_bwd_fused": 0, "tiled_bwd_split": 0,
+         # backwards traced with a window (a band of blocks, kernels
+         # named flash_attention_win_*)
+         "tiled_window": 0}
 
 # m/l scratch rows are stored lane-replicated at this width (1-lane
 # vectors are not a legal VMEM tile).
@@ -207,6 +227,26 @@ _LANES = 128
 # 8192 where blocks of 1024 compute 1.125 x. Without one, 2048 keys a
 # block are 2-5% ahead at 2048. Keys in blocks of 512 double the
 # forward's time. So the key block follows `causal` (_choose_blocks).
+# A window keeps the causal pair. Swept on the chip (v5e,
+# [1, 8192, 32 over 4, 128] bf16, `bthd` arrays, causal, a window of 1024,
+# no bias, tools/bench_attention.py ... causal,nobias,window1024; PERF.md
+# section 6, PR 36): ms forward / backward (the vjp alone) / both in one
+# program, and the blocks of 1024^2 score elements that the pair computes
+# for a band of 7.5 a head:
+#   1024x1024   2.98 / 4.60 /  7.48   15
+#   512x1024    3.31 / 4.70 /  7.80   15
+#   512x512     4.35 / 3.97 /  8.12   11.25
+#   256x512     4.69 / 4.91 /  9.45   11.25
+#   1024x512    5.00 / 4.82 /  9.62   15
+#   512x256     7.53 / 5.41 / 12.77   11.25
+#   256x256     6.97 / 5.99 / 12.93   9.375
+# (the same arrays with no window, 1024 x 1024: 5.65 / 9.66 / 15.12, 36
+# blocks a head.) The pair that computes the least loses: as without a
+# window, keys in blocks of 512 or 256 cost the forward more in steps than
+# they save in masked area (its online softmax rescales the accumulator
+# every k step), and only the backward, which has no such state, is
+# faster at 512 x 512 (3.97 against 4.60). One pair serves both, and it
+# is the causal one: a window changes no block that `causal` picks.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 2048
 DEFAULT_BLOCK_K_CAUSAL = 1024
@@ -265,12 +305,13 @@ def _pick_block(n, pref):
 def _choose_blocks(T, S, D, DV, pref_q=None, pref_k=None, causal=False):
     """The ONE block-selection policy (supports() and _prep share it):
     pick legal tiles (the defaults where the caller names none; the key
-    block by `causal`, as the sweep above DEFAULT_BLOCK_Q found), then
-    shrink — re-legalizing through _pick_block at every step — until the
-    fp32 scores tile fits the VMEM budget (measured on v5e: 2M elements
-    compiles at head dim <= 64, 4M does not; halved budget for wider
-    heads). Returns (0, 0) if no legal in-budget pair exists; what is
-    legal does not depend on `causal`."""
+    block by `causal`, as the sweeps above DEFAULT_BLOCK_Q found, with
+    a window or without), then shrink — re-legalizing through
+    _pick_block at every step — until the fp32 scores tile fits the
+    VMEM budget (measured on v5e: 2M elements compiles at head dim
+    <= 64, 4M does not; halved budget for wider heads). Returns (0, 0)
+    if no legal in-budget pair exists; what is legal does not depend on
+    `causal`."""
     bq = _pick_block(T, pref_q or DEFAULT_BLOCK_Q)
     bk = _pick_block(S, DEFAULT_BLOCK_K_CAUSAL) \
         if causal and not pref_k else 0
@@ -308,44 +349,109 @@ def _causal_whole(q_idx, k_idx, block_q, block_k, offset):
     return (k_idx + 1) * block_k - 1 <= q_idx * block_q + offset
 
 
-def _causal_mask(s, q_idx, k_idx, block_q, block_k, offset):
+def _window_active(q_idx, k_idx, block_q, block_k, offset, window):
+    """Does k block k_idx reach into the window of q block q_idx (its
+    last key inside the window of the block's first query)? Query t sees
+    the keys s with t + offset - window < s <= t + offset."""
+    return (k_idx + 1) * block_k - 1 > q_idx * block_q + offset - window
+
+
+def _window_whole(q_idx, k_idx, block_q, block_k, offset, window):
+    """Does k block k_idx lie wholly over the window's lower edge (its
+    first key inside the window of the block's last query)?"""
+    return k_idx * block_k > (q_idx + 1) * block_q - 1 + offset - window
+
+
+def _causal_mask(s, q_idx, k_idx, block_q, block_k, offset, window=None):
     q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     k_pos = k_idx * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    return jnp.where(q_pos + offset >= k_pos, s, _NEG_INF)
+    if window is None:
+        return jnp.where(q_pos + offset >= k_pos, s, _NEG_INF)
+    # both edges in one compare: 0 <= (t + offset) - s < window, the
+    # difference read as unsigned
+    ahead = jax.lax.bitcast_convert_type(q_pos + offset - k_pos, jnp.uint32)
+    return jnp.where(ahead < jnp.uint32(window), s, _NEG_INF)
 
 
-def _on_block(causal, q_idx, k_idx, block_q, block_k, offset, body):
+def _on_block(causal, q_idx, k_idx, block_q, block_k, offset, body,
+              window=None, inside=None):
     """Run `body(mask)` on block (q_idx, k_idx) for what the causal
-    diagonal leaves of it: not at all above the diagonal, with `mask`
-    (s -> masked s) where the diagonal crosses it, and with mask = None
-    where it lies wholly under it (28 of the 36 active blocks of a head
-    at 8192 with 1024 x 1024 blocks) or nothing is causal."""
+    diagonal (and, with `window`, the band's lower edge) leaves of it:
+    not at all outside, with `mask` (s -> masked s) where an edge crosses
+    it, and with mask = None where it lies wholly inside (28 of the 36
+    active blocks of a head at 8192 with 1024 x 1024 blocks) or nothing
+    is causal. `inside` (windowed kernels, whose grid walks the band's
+    steps only): is this step one of the band's at all."""
     if not causal:
         body(None)
         return
     at = (q_idx, k_idx, block_q, block_k, offset)
     whole = _causal_whole(*at)
-    pl.when(whole)(lambda: body(None))
-    pl.when(_causal_active(*at) & jnp.logical_not(whole))(
-        lambda: body(lambda s: _causal_mask(s, *at)))
+    if window is None:
+        pl.when(whole)(lambda: body(None))
+        pl.when(_causal_active(*at) & jnp.logical_not(whole))(
+            lambda: body(lambda s: _causal_mask(s, *at)))
+        return
+    active = inside & _causal_active(*at) & _window_active(*at, window)
+    whole = whole & _window_whole(*at, window)
+    pl.when(active & whole)(lambda: body(None))
+    pl.when(active & jnp.logical_not(whole))(
+        lambda: body(lambda s: _causal_mask(s, *at, window)))
+
+
+# The four edges of the band of blocks, each for Python ints (the grid's
+# size, counted where the call is made) and for the traced indices of an
+# index map or a kernel body alike.
+def _imax(a, b):
+    return max(a, b) if isinstance(a, int) else jnp.maximum(a, b)
+
+
+def _imin(a, b):
+    return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
+
+
+def _idiv(a, b):
+    return a // b if isinstance(a, int) else jax.lax.div(a, b)
 
 
 def _last_k(i, block_q, block_k, offset, n_k):
     """The last k block that q block i reads under the causal diagonal.
     An index map of k / v hands a skipped step (j past it) this block
     again, so that Mosaic does not fetch what the body will not read."""
-    reach = jnp.maximum((i + 1) * block_q - 1 + offset, 0)
-    return jnp.minimum(jax.lax.div(reach, block_k), n_k - 1)
+    reach = _imax((i + 1) * block_q - 1 + offset, 0)
+    return _imin(_idiv(reach, block_k), n_k - 1)
 
 
 def _first_q(j, block_q, block_k, offset, n_q):
     """The first q block that reads k block j: the q-side twin of
     _last_k, for the kernel that walks the q blocks innermost (its
     skipped steps come first and are handed the block that follows)."""
-    start = jnp.maximum(j * block_k - offset, 0)
-    return jnp.minimum(jax.lax.div(start, block_q), n_q - 1)
+    start = _imax(j * block_k - offset, 0)
+    return _imin(_idiv(start, block_q), n_q - 1)
+
+
+def _first_k(i, block_q, block_k, offset, window):
+    """The first k block that q block i reads under a window: the one
+    that holds the lowest key of its first query."""
+    return _idiv(_imax(i * block_q + offset - window + 1, 0), block_k)
+
+
+def _last_q(j, block_q, block_k, offset, window, n_q):
+    """The last q block that reads k block j under a window: the one
+    that holds the last query whose window reaches the block's last
+    key."""
+    reach = _imax((j + 1) * block_k - 1 - offset + window - 1, 0)
+    return _imin(_idiv(reach, block_q), n_q - 1)
+
+
+def _band_steps(n_outer, first, last):
+    """The inner grid axis of a windowed kernel: the most blocks that one
+    outer block's band holds (15 of a head's 64 blocks lie in the band at
+    8192 with a window of 1024 and 1024 x 1024 blocks, two a row; a grid
+    over all 64 would step over the other 49)."""
+    return max(last(i) - first(i) + 1 for i in range(n_outer))
 
 
 def _precision(a):
@@ -381,16 +487,28 @@ def _kv_row(group):
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
+def _band_k(q_idx, j, block_q, block_k, offset, n_k, window):
+    """(k block, is it one of the band's) of step j of q block q_idx in a
+    grid whose innermost axis walks the k blocks: all of them, or with a
+    window the band's only, from the q block's first."""
+    if window is None:
+        return j, None
+    k_idx = _first_k(q_idx, block_q, block_k, offset, window) + j
+    return k_idx, k_idx <= _last_k(q_idx, block_q, block_k, offset, n_k)
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *, causal, scale, n_k, offset,
-                p_dtype=jnp.float32, has_bias=True):
-    """Grid (B*H, n_q, n_k), k innermost. q_ref [bq, D]; k/v_ref [bk, D];
-    b_ref [1, bk]; scratch m/l [bq, _LANES] (lane-replicated), acc [bq, DV].
+                steps, p_dtype=jnp.float32, has_bias=True, window=None):
+    """Grid (B*H, n_q, steps), k innermost: steps = n_k, or with a window
+    the band's k blocks only. q_ref [bq, D]; k/v_ref [bk, D]; b_ref
+    [1, bk]; scratch m/l [bq, _LANES] (lane-replicated), acc [bq, DV].
     """
-    q_idx, k_idx = pl.program_id(1), pl.program_id(2)
+    q_idx, j = pl.program_id(1), pl.program_id(2)
     bq, bk = q_ref.shape[0], k_ref.shape[0]
+    k_idx, inside = _band_k(q_idx, j, bq, bk, offset, n_k, window)
 
-    @pl.when(k_idx == 0)
+    @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -409,7 +527,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         # the full-tile exp is the dominant VPU cost; p_dtype=bf16 runs
         # it at the packed rate while m/alpha/l stay f32 (argument is
-        # <= 0, so bf16's mantissa bounds the element error at ~0.4%)
+        # <= 0, so bf16's mantissa bounds the element error at ~0.4%).
+        # A row that the band's lower edge masks out of its first block
+        # sums exp(0) there; the next block's alpha = 0 wipes that
         p = jnp.exp((s - m_new).astype(p_dtype))
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True,
@@ -419,9 +539,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         acc_ref[...] = acc_ref[...] * alpha + _dot(
             p.astype(v_ref.dtype), v_ref[...])
 
-    _on_block(causal, q_idx, k_idx, bq, bk, offset, _compute)
+    _on_block(causal, q_idx, k_idx, bq, bk, offset, _compute, window, inside)
 
-    @pl.when(k_idx == n_k - 1)
+    @pl.when(j == steps - 1)
     def _flush():
         m = m_ref[...][:, :1]
         l = jnp.maximum(l_ref[...][:, :1], 1e-20)
@@ -429,11 +549,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
         lse_ref[0, :] = (m + jnp.log(l))[:, 0]
 
 
-def _k_innermost_specs(kv, H, block_q, block_k, D, DV, causal, offset, n_k):
-    """The q, k, v and bias BlockSpecs of a grid (B*H, n_q, n_k): k / v /
-    bias blocks follow j, held at the row's last active block under a
-    causal diagonal."""
-    if causal:
+def _k_innermost_specs(kv, H, block_q, block_k, D, DV, causal, offset, n_k,
+                       window=None):
+    """The q, k, v and bias BlockSpecs of a grid (B*H, n_q, steps): k / v
+    / bias blocks follow j, held at the row's last active block under a
+    causal diagonal; with a window they start at the row's first."""
+    if window is not None:
+        def kj(i, j):
+            return jnp.minimum(
+                _first_k(i, block_q, block_k, offset, window) + j,
+                _last_k(i, block_q, block_k, offset, n_k))
+    elif causal:
         def kj(i, j):
             return jnp.minimum(j, _last_k(i, block_q, block_k, offset, n_k))
     else:
@@ -450,9 +576,19 @@ def _k_innermost_specs(kv, H, block_q, block_k, D, DV, causal, offset, n_k):
     ]
 
 
+def _k_steps(n_q, n_k, block_q, block_k, offset, window):
+    """The innermost axis of a grid that walks the k blocks of a q
+    block: n_k, or the band's most."""
+    if window is None:
+        return n_k
+    return _band_steps(
+        n_q, lambda i: _first_k(i, block_q, block_k, offset, window),
+        lambda i: _last_k(i, block_q, block_k, offset, n_k))
+
+
 def _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
               interpret, p_dtype=jnp.float32, causal_offset=0,
-              has_bias=True):
+              has_bias=True, window=None):
     """q [BH, T, D]; k/v [BH, S, D]; bias [B, 1, S] (mapped to the batch
     row b // n_heads by the index_map — no per-head materialization).
     has_bias=False statically skips the bias add (the operand is still
@@ -460,17 +596,18 @@ def _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
     BH, T, D = q.shape
     S = k.shape[1]
     DV = v.shape[-1]
-    n_k = S // block_k
+    n_q, n_k = T // block_q, S // block_k
     offset = S - T + causal_offset
-    grid = (BH, T // block_q, n_k)
+    steps = _k_steps(n_q, n_k, block_q, block_k, offset, window)
+    grid = (BH, n_q, steps)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, causal=causal, scale=scale, n_k=n_k,
                           offset=offset, p_dtype=p_dtype,
-                          has_bias=has_bias),
+                          has_bias=has_bias, window=window, steps=steps),
         grid=grid,
         in_specs=_k_innermost_specs(_kv_row(BH // k.shape[0]), n_heads,
                                     block_q, block_k, D, DV, causal,
-                                    offset, n_k),
+                                    offset, n_k, window),
         out_specs=[
             pl.BlockSpec((None, block_q, DV), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
@@ -486,10 +623,16 @@ def _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="flash_attention_fwd",
+        name=_kernel_name("fwd", window),
         interpret=interpret,
     )(q, k, v, bias)
     return out, lse
+
+
+def _kernel_name(which, window):
+    """A windowed call's kernels carry names of their own, so that a
+    trace tells the band's calls from a full layer's."""
+    return f"flash_attention_{'win_' if window is not None else ''}{which}"
 
 
 # ---------------------------------------------------------------------------
@@ -513,15 +656,16 @@ def _p_and_ds(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref, mask, *,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
-               dq_ref, acc_ref, *, causal, scale, n_k, offset,
-               p_dtype=jnp.float32, has_bias=True):
-    """Grid (B*H, n_q, n_k): recompute p block-wise, accumulate dq in
-    VMEM scratch, flush on the last k step. Runs only where the fused
-    kernel's resident dq does not fit (FUSED_BWD_VMEM)."""
-    q_idx, k_idx = pl.program_id(1), pl.program_id(2)
+               dq_ref, acc_ref, *, causal, scale, n_k, offset, steps,
+               p_dtype=jnp.float32, has_bias=True, window=None):
+    """Grid (B*H, n_q, steps) as the forward's: recompute p block-wise,
+    accumulate dq in VMEM scratch, flush on the last k step. Runs only
+    where the fused kernel's resident dq does not fit (FUSED_BWD_VMEM)."""
+    q_idx, j = pl.program_id(1), pl.program_id(2)
     bq, bk = q_ref.shape[0], k_ref.shape[0]
+    k_idx, inside = _band_k(q_idx, j, bq, bk, offset, n_k, window)
 
-    @pl.when(k_idx == 0)
+    @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -532,18 +676,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
         acc_ref[...] = acc_ref[...] + _dot(
             ds.astype(k_ref.dtype), k_ref[...]) * scale
 
-    _on_block(causal, q_idx, k_idx, bq, bk, offset, _compute)
+    _on_block(causal, q_idx, k_idx, bq, bk, offset, _compute, window, inside)
 
-    @pl.when(k_idx == n_k - 1)
+    @pl.when(j == steps - 1)
     def _flush():
         dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
-                *refs, causal, scale, n_q, n_k, offset, fused,
-                p_dtype=jnp.float32, has_bias=True):
-    """Grid (B*KVH, n_kv, group*n_q), q innermost: recompute p and ds of
-    a block ONCE and give every product that reads them. dk/dv
+                *refs, causal, scale, n_q, n_k, offset, fused, steps,
+                p_dtype=jnp.float32, has_bias=True, window=None):
+    """Grid (B*KVH, n_kv, group*steps), q innermost: recompute p and ds
+    of a block ONCE and give every product that reads them. dk/dv
     accumulate in VMEM scratch over the q blocks of the `group` query
     heads of this key-value head (group = 1: one head's). `fused`: dq
     too, `ds k`, into dq_acc [group*T, D] float32, which holds the dq of
@@ -555,7 +699,11 @@ def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
     per-head bias gradient row (d s / d bias = 1): its block index is
     constant in the innermost q dim, so it stays resident in VMEM and
     accumulates in-place across the q steps; without it, neither the
-    bias add nor the db output exists (no-bias path pays nothing)."""
+    bias add nor the db output exists (no-bias path pays nothing).
+    steps = n_q, or with a `window` the most q blocks that one k block's
+    band holds: a head's steps then start at the k block's first q
+    block, and a q block's rows of dq open at the first k block of ITS
+    band and close at the last."""
     # outputs dk, dv[, db][, dq], then scratch dk_acc, dv_acc[, dq_acc]
     refs = list(refs)
     dq_acc = refs.pop() if fused else None
@@ -564,11 +712,21 @@ def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
     db_ref = refs.pop() if has_bias else None
     dk_ref, dv_ref = refs
     # the innermost axis walks the q blocks of every query head that
-    # shares this key-value head: n_q steps a head, one head after another
+    # shares this key-value head: `steps` a head, one head after another
     k_idx, step = pl.program_id(1), pl.program_id(2)
-    q_idx = step % n_q
     bk, bq = k_ref.shape[0], q_ref.shape[0]
-    rows = pl.ds(pl.multiple_of(step * bq, bq), bq)      # of dq_acc
+    if window is None:
+        q_idx, inside = step % n_q, None
+        rows = pl.ds(pl.multiple_of(step * bq, bq), bq)  # of dq_acc
+        opens, closes = k_idx == 0, k_idx == n_k - 1
+    else:
+        q_idx = _first_q(k_idx, bq, bk, offset, n_q) + step % steps
+        inside = q_idx <= _last_q(k_idx, bq, bk, offset, window, n_q)
+        q_idx = jnp.minimum(q_idx, n_q - 1)
+        rows = pl.ds(pl.multiple_of(
+            ((step // steps) * n_q + q_idx) * bq, bq), bq)
+        opens = inside & (k_idx == _first_k(q_idx, bq, bk, offset, window))
+        closes = inside & (k_idx == _last_k(q_idx, bq, bk, offset, n_k))
 
     @pl.when(step == 0)
     def _init():
@@ -578,7 +736,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
             db_ref[...] = jnp.zeros_like(db_ref)
 
     if fused:
-        @pl.when(k_idx == 0)
+        @pl.when(opens)
         def _init_dq():
             dq_acc[rows, :] = jnp.zeros((bq, dq_acc.shape[1]), jnp.float32)
 
@@ -595,7 +753,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
         if fused:
             dq_acc[rows, :] = dq_acc[rows, :] + _dot(ds, k_ref[...]) * scale
 
-    _on_block(causal, q_idx, k_idx, bq, bk, offset, _compute)
+    _on_block(causal, q_idx, k_idx, bq, bk, offset, _compute, window, inside)
 
     @pl.when(step == pl.num_programs(2) - 1)
     def _flush():
@@ -603,7 +761,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
     if fused:
-        @pl.when(k_idx == n_k - 1)
+        @pl.when(closes)
         def _flush_dq():
             dq_ref[rows, :] = dq_acc[rows, :].astype(dq_ref.dtype)
 
@@ -619,7 +777,7 @@ def _bwd_resident_bytes(group, T, D, itemsize):
 
 def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
               g_lse=None, p_dtype=jnp.float32, causal_offset=0,
-              has_bias=True):
+              has_bias=True, window=None):
     q, k, v, bias, out, lse = res
     BH, T, D = q.shape
     BKV, S = k.shape[:2]
@@ -638,17 +796,21 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
     n_q = T // block_q
     offset = S - T + causal_offset
     static = dict(causal=causal, scale=scale, offset=offset,
-                  p_dtype=p_dtype, has_bias=has_bias)
+                  p_dtype=p_dtype, has_bias=has_bias, window=window)
     resident = _bwd_resident_bytes(group, T, D, q.dtype.itemsize)
     fused = resident <= FUSED_BWD_VMEM
     STATS["tiled_bwd_fused" if fused else "tiled_bwd_split"] += 1
+    if window is not None:
+        STATS["tiled_window"] += 1
 
     if not fused:
+        k_steps = _k_steps(n_q, n_k, block_q, block_k, offset, window)
         dq = pl.pallas_call(
-            functools.partial(_dq_kernel, n_k=n_k, **static),
-            grid=(BH, n_q, n_k),
+            functools.partial(_dq_kernel, n_k=n_k, steps=k_steps, **static),
+            grid=(BH, n_q, k_steps),
             in_specs=_k_innermost_specs(_kv_row(group), H, block_q, block_k,
-                                        D, DV, causal, offset, n_k) + [
+                                        D, DV, causal, offset, n_k,
+                                        window) + [
                 pl.BlockSpec((None, block_q, DV), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
                 pl.BlockSpec((None, 1, block_q), lambda b, i, j: (b, 0, i)),
@@ -659,7 +821,7 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
-            name="flash_attention_dq",
+            name=_kernel_name("dq", window),
             interpret=interpret,
         )(q, k, v, bias, g, lse, delta)
 
@@ -690,12 +852,26 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
     # grid row b is a key-value head; step i of the innermost axis is q
     # block i % n_q of query head b * group + i // n_q. Under a causal
     # diagonal the steps above it (the first of each head) are handed the
-    # head's first active block
+    # head's first active block. With a window a head has the band's
+    # steps only: they start at the k block's first q block, and those
+    # past its last are handed the last
+    if window is None:
+        steps = n_q
+    else:
+        def last_q(j):
+            return _last_q(j, block_q, block_k, offset, window, n_q)
+        steps = _band_steps(
+            n_k, lambda j: _first_q(j, block_q, block_k, offset, n_q),
+            last_q)
+
     def qrow(b, j, i):
-        qi = i % n_q
-        if causal:
+        qi = i % steps
+        if window is not None:
+            qi = jnp.minimum(
+                qi + _first_q(j, block_q, block_k, offset, n_q), last_q(j))
+        elif causal:
             qi = jnp.maximum(qi, _first_q(j, block_q, block_k, offset, n_q))
-        return (b if group == 1 else b * group + i // n_q), qi
+        return (b if group == 1 else b * group + i // steps), qi
 
     def qblock(b, j, i):             # of q, dO: [BH, T, D]
         return qrow(b, j, i) + (0,)
@@ -706,8 +882,8 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
     KVH = H // group
     outs = pl.pallas_call(
         functools.partial(_bwd_kernel, n_q=n_q, n_k=n_k, fused=fused,
-                          **static),
-        grid=(BKV, n_k, group * n_q),
+                          steps=steps, **static),
+        grid=(BKV, n_k, group * steps),
         in_specs=[
             pl.BlockSpec((None, block_q, D), qblock),
             pl.BlockSpec((None, block_k, D), lambda b, j, i: (b, j, 0)),
@@ -725,7 +901,7 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
             dimension_semantics=("parallel", "arbitrary", "arbitrary")
             if fused else ("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_TILED_VMEM + resident if fused else None),
-        name="flash_attention_bwd" if fused else "flash_attention_dkv",
+        name=_kernel_name("bwd" if fused else "dkv", window),
         interpret=interpret,
     )(q, k, v, bias, g, lse, delta)
     outs = list(outs)
@@ -744,29 +920,29 @@ def _bwd_call(res, g, n_heads, causal, scale, block_q, block_k, interpret,
 # custom_vjp wrapper (flat [BH, T, D] layout)
 # ---------------------------------------------------------------------------
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12))
+                   nondiff_argnums=(4, 5, 6, 7, 8, 9, 10, 11, 12, 13))
 def _flash(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
-           interpret, p_dtype, causal_offset, has_bias):
+           interpret, p_dtype, causal_offset, has_bias, window):
     out, _ = _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q,
                        block_k, interpret, p_dtype, causal_offset,
-                       has_bias)
+                       has_bias, window)
     return out
 
 
 def _flash_fwd(q, k, v, bias, n_heads, causal, scale, block_q, block_k,
-               interpret, p_dtype, causal_offset, has_bias):
+               interpret, p_dtype, causal_offset, has_bias, window):
     out, lse = _fwd_call(q, k, v, bias, n_heads, causal, scale, block_q,
                          block_k, interpret, p_dtype, causal_offset,
-                         has_bias)
+                         has_bias, window)
     return out, (q, k, v, bias, out, lse)
 
 
 def _flash_bwd(n_heads, causal, scale, block_q, block_k, interpret, p_dtype,
-               causal_offset, has_bias, res, g):
+               causal_offset, has_bias, window, res, g):
     dq, dk, dv, db = _bwd_call(res, g, n_heads, causal, scale, block_q,
                                block_k, interpret, p_dtype=p_dtype,
                                causal_offset=causal_offset,
-                               has_bias=has_bias)
+                               has_bias=has_bias, window=window)
     if db is None:  # fabricated zeros bias: no gradient to report
         return dq, dk, dv, jnp.zeros_like(res[3])
     return dq, dk, dv, db.astype(res[3].dtype)
@@ -1228,32 +1404,51 @@ def _prep(q, k, v, bias, scale, block_q, block_k, causal):
     return qr, kr, vr, _bias_rows(bias, B, S), H, scale, block_q, block_k
 
 
+def _window(window, causal, S, causal_offset=0):
+    """The window as the kernels take it: None where there is none or it
+    covers every key anyway (a window at or over the key length is no
+    window). A window is the causal band `t - window < s <= t`; the
+    kernels take it on an unshifted diagonal only."""
+    if window is None:
+        return None
+    window = int(window)
+    if window < 1 or not causal or causal_offset:
+        raise NotImplementedError(
+            "a window is a causal band of at least one key on an "
+            "unshifted diagonal")
+    return None if window >= S else window
+
+
 def flash_attention(q, k, v, bias=None, causal=False, scale=None,
                     block_q=None, block_k=None, interpret=False,
-                    softmax_dtype=None, causal_offset=0):
+                    softmax_dtype=None, causal_offset=0, window=None):
     """q/k/v: [B, H, T, D] → [B, H, T, D]. Differentiable (custom_vjp);
-    bias is an additive key-padding bias [B, S] or [B,1,1,S]."""
+    bias is an additive key-padding bias [B, S] or [B,1,1,S]. `window`
+    (with `causal`): query t sees the `window` keys `t - window < s <= t`
+    only, and the kernels (flash_attention_win_*) neither compute nor
+    fetch a block outside that band."""
     if not _HAS_PALLAS:
         raise NotImplementedError("pallas unavailable")
     STATS["pallas_calls"] += 1
     B, H, T, _ = q.shape
+    window = _window(window, causal, k.shape[2], causal_offset)
     qr, kr, vr, br, H, scale, block_q, block_k = _prep(
         q, k, v, bias, scale, block_q, block_k, causal)
     # per-batch bias row is shared across heads via the kernel index_map
     p_dtype = jnp.dtype(softmax_dtype or _SOFTMAX_DTYPE)
     out = _flash(qr, kr, vr, br, H, bool(causal), scale, block_q, block_k,
                  bool(interpret), p_dtype, int(causal_offset),
-                 bias is not None)
+                 bias is not None, window)
     return out.reshape(B, H, T, vr.shape[-1])
 
 
 def flash_attention_reference(q, k, v, bias=None, causal=False, scale=None,
-                              causal_offset=0, layout="bhtd"):
+                              causal_offset=0, layout="bhtd", window=None):
     """Unfused jnp reference (for tests), in the caller's `layout`."""
     if layout == "bthd":
         return flash_attention_reference(
             q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2), bias,
-            causal, scale, causal_offset).swapaxes(1, 2)
+            causal, scale, causal_offset, window=window).swapaxes(1, 2)
     D = q.shape[-1]
     scale = scale if scale is not None else D ** -0.5
     group = q.shape[1] // k.shape[1]
@@ -1267,6 +1462,9 @@ def flash_attention_reference(q, k, v, bias=None, causal=False, scale=None,
         T, S = s.shape[-2], s.shape[-1]
         cm = jnp.tril(jnp.ones((T, S), dtype=bool),
                       k=S - T + causal_offset)
+        if window is not None:
+            cm = cm & ~jnp.tril(jnp.ones((T, S), dtype=bool),
+                                k=S - T + causal_offset - window)
         s = jnp.where(cm, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p, v).astype(q.dtype)
@@ -1279,13 +1477,13 @@ def _tiled_dims(q, k, layout):
 
 
 def picks_short(q, k, v, bias=None, with_lse=False, causal_offset=0,
-                layout="bhtd", interpret=False):
+                layout="bhtd", interpret=False, window=None):
     """try_flash's first choice, as a static test (the kern registry's
     probe asks it too): `bthd` arrays the short-sequence kernel can take,
     at lengths where the chip compiled it and it won (the table above
     SHORT_MIN_SEQ_LEN; interpret mode skips that gate), from a caller
-    that wants neither the lse nor a shifted diagonal."""
-    if layout != "bthd" or with_lse or causal_offset:
+    that wants neither the lse, a shifted diagonal nor a window."""
+    if layout != "bthd" or with_lse or causal_offset or window is not None:
         return False
     T, S = _tiled_dims(q, k, layout)
     wins = SHORT_MIN_SEQ_LEN <= min(T, S) \
@@ -1300,7 +1498,7 @@ def tiled_min_len(with_lse=False, layout="bhtd"):
 
 
 def try_flash(q, k, v, bias=None, causal=False, scale=None, with_lse=False,
-              causal_offset=0, layout="bhtd"):
+              causal_offset=0, layout="bhtd", window=None):
     """THE dispatch policy, in one place (used by ops/kernels_nn.py,
     parallel/ring_attention.py, parallel/ulysses.py): returns a Pallas
     kernel's result (`out`, or `(out, lse)` with `with_lse`) in the
@@ -1319,13 +1517,19 @@ def try_flash(q, k, v, bias=None, causal=False, scale=None, with_lse=False,
 
     Interpret mode (CPU tests) bypasses the performance gates, not the
     shape tests. `with_lse` and `causal_offset` callers (ring attention)
-    are served by the tiled kernel only."""
+    are served by the tiled kernel only, and so is a `window` (a causal
+    band; one at or over the key length is no window), which neither of
+    the other two can carry."""
     use_pallas, interpret = active()
     if not use_pallas:
         return None
     bthd = layout == "bthd"
+    if window is not None:
+        if with_lse or causal_offset or not causal:
+            return None
+        window = _window(window, causal, _tiled_dims(q, k, layout)[1])
     if picks_short(q, k, v, bias, with_lse, causal_offset, layout,
-                   interpret):
+                   interpret, window):
         return flash_attention_bthd(q, k, v, bias=bias, causal=causal,
                                     scale=scale, interpret=interpret)
     if not interpret \
@@ -1343,5 +1547,5 @@ def try_flash(q, k, v, bias=None, causal=False, scale=None, with_lse=False,
         return (out.swapaxes(1, 2) if bthd else out), lse
     out = flash_attention(q, k, v, bias=bias, causal=causal, scale=scale,
                           interpret=interpret,
-                          causal_offset=causal_offset)
+                          causal_offset=causal_offset, window=window)
     return out.swapaxes(1, 2) if bthd else out

@@ -86,12 +86,16 @@ def test_chip_kernel_cases_pass_their_hardware_gates(smoke):
     from paddle_tpu.ops import kern
     cases = smoke.chip_kernel_cases()
     assert sorted({c.split("@")[0] for c in cases}) == kern.names()
-    assert {"flash_attention@head128", "kda_attention"} <= set(cases)
+    assert {"flash_attention@head128", "flash_attention@window",
+            "kda_attention"} <= set(cases)
     for name, (args, kwargs, _argnums) in cases.items():
         assert kern.get(name.split("@")[0]).probe(*args, **kwargs), name
     q, k, v = cases["flash_attention@head128"][0]
     assert (q.shape, k.shape) == ((1, 8, 8192, 128), (1, 1, 8192, 128))
     assert cases["kda_attention"][0][0].shape == (1, 8192, 8, 128)
+    args, kwargs, argnums = cases["flash_attention@window"]
+    assert kwargs == {"causal": True, "window": 1024} and argnums == (0, 1, 2)
+    assert args[0].shape == (1, 8, 8192, 128)
 
 
 def test_dp_phase_over_the_virtual_mesh(smoke, watch, interpret):

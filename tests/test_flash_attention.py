@@ -5,6 +5,9 @@ jnp reference (bias x causal grid), and proof that the kernel — not the
 jnp fallback — is on the flagship transformer's training path under
 jax.value_and_grad (trace-time counter + loss parity with the fallback).
 """
+import inspect
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -383,6 +386,194 @@ def test_causal_key_blocks_follow_the_sweep():
     # 1100 keys: one block of the whole axis either way
     assert fa._choose_blocks(128, 1100, 64, 64, causal=True) == (128, 1100)
     assert fa._choose_blocks(128, 1100, 64, 64) == (128, 1100)
+
+
+# ---------------------------------------------------------------------------
+# a sliding window: the band of blocks, forward and the one backward kernel
+# ---------------------------------------------------------------------------
+_WINDOW_CASES = {
+    # name: (T, S, window, group, block_q, block_k, with_bias)
+    "smaller_than_a_block": (384, 384, 64, 1, 128, 128, False),
+    "equal_to_a_block": (384, 384, 128, 2, 128, 128, False),
+    "not_a_multiple_of_a_block": (384, 384, 200, 2, 128, 128, True),
+    "larger_than_a_block_group4": (512, 512, 300, 4, 128, 256, False),
+    "two_k_blocks_a_q_block": (512, 512, 256, 1, 256, 128, True),
+    "one_key": (256, 256, 1, 2, 128, 128, False),
+    "cross_bottom_right": (256, 512, 130, 2, 128, 128, False),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_WINDOW_CASES))
+def test_windowed_kernels_match_the_masked_composition(case, dtype,
+                                                        monkeypatch):
+    """flash_attention_win_fwd / _win_bwd in the interpreter against the
+    unfused composition with the band as an explicit mask: the output and
+    dq, dk, dv (and db), with the window smaller than, equal to, not a
+    multiple of and larger than a block, grouped heads, and a key length
+    over the query's; the two kernels the backward falls to over the VMEM
+    budget take the same band."""
+    T, S, window, group, bq, bk, with_bias = _WINDOW_CASES[case]
+    dt = jnp.dtype(dtype)
+    B, KVH, D = 2, 2 if group == 1 else 1, 16
+    rng = np.random.RandomState(36)
+    q = jnp.asarray(rng.randn(B, KVH * group, T, D), dt)
+    k = jnp.asarray(rng.randn(B, KVH, S, D), dt)
+    v = jnp.asarray(rng.randn(B, KVH, S, D), dt)
+    w = jnp.asarray(rng.randn(*q.shape).astype("float32"))
+    # every seventh key padded away, whatever window it falls in (no row
+    # is left without a key: the windows here are 200 and 256 wide)
+    bias = jnp.where(jnp.arange(S)[None] % 7 == 3, -1e9, 0.0) \
+        * jnp.ones((B, 1)) if with_bias else None
+
+    def tiled(q, k, v, b):
+        return fa.flash_attention(q, k, v, bias=b, causal=True,
+                                  window=window, block_q=bq, block_k=bk,
+                                  interpret=True)
+
+    def ref(q, k, v, b):
+        return fa.flash_attention_reference(
+            q.astype(jnp.float32), k.astype(jnp.float32),
+            v.astype(jnp.float32), b, causal=True, window=window)
+
+    def both(fn):
+        def f(q, k, v, b):
+            out = fn(q, k, v, b)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        return jax.value_and_grad(
+            f, argnums=(0, 1, 2, 3) if with_bias else (0, 1, 2),
+            has_aux=True)
+
+    before = dict(fa.STATS)
+    (_, out), fused = both(tiled)(q, k, v, bias)
+    assert fa.STATS["tiled_window"] == before["tiled_window"] + 1
+    assert fa.STATS["tiled_bwd_fused"] == before["tiled_bwd_fused"] + 1
+    monkeypatch.setattr(fa, "FUSED_BWD_VMEM", 0)
+    (_, _), split = both(tiled)(q, k, v, bias)
+    assert fa.STATS["tiled_bwd_split"] == before["tiled_bwd_split"] + 1
+    assert fa.STATS["tiled_window"] == before["tiled_window"] + 2
+    (_, want_out), want = both(ref)(q, k, v, bias)
+    tol = dict(rtol=1e-4, atol=1e-4) if dtype == "float32" \
+        else dict(rtol=3e-2, atol=3e-2)
+    same = dict(rtol=1e-6, atol=1e-6) if dtype == "float32" \
+        else dict(rtol=1e-2, atol=1e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want_out), **tol)
+    for name, a, b, c in zip(("dq", "dk", "dv", "db"), fused, split, want):
+        assert a.shape == c.shape, name
+        a, b, c = (np.asarray(x, np.float32) for x in (a, b, c))
+        np.testing.assert_allclose(a, c, err_msg=name, **tol)
+        np.testing.assert_allclose(a, b, err_msg=name, **same)
+
+
+def test_the_band_is_what_the_windowed_grid_walks():
+    """The inner grid axis of a windowed call is the band's blocks only,
+    the kernels carry names of their own, and a call with no window (or
+    one at or over the key length) traces to the kernels, the names, the
+    grid and the STATS it had."""
+    sds = jax.ShapeDtypeStruct
+    q = sds((1, 8, 2048, 64), jnp.float32)
+    kv = sds((1, 2, 2048, 64), jnp.float32)
+
+    def traced(**kw):
+        before = dict(fa.STATS)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, block_q=256, block_k=256,
+                interpret=True, **kw).sum(), argnums=(0, 1, 2)))(q, kv, kv))
+        names = sorted(set(re.findall(r"flash_attention_\w+", text)))
+        grids = re.findall(r"grid=\((\d+), (\d+), (\d+)\)", text)
+        return names, sorted(set(grids)), {
+            key: fa.STATS[key] - before[key] for key in before}
+
+    plain = traced()
+    assert plain[0] == ["flash_attention_bwd", "flash_attention_fwd"]
+    assert plain[1] == [("2", "8", "32"), ("8", "8", "8")]
+    assert plain[2]["tiled_window"] == 0 and plain[2]["tiled_bwd_fused"] == 1
+    assert traced(window=None) == plain and traced(window=2048) == plain
+    assert traced(window=5000) == plain
+    names, grids, stats = traced(window=300)
+    assert names == ["flash_attention_win_bwd", "flash_attention_win_fwd"]
+    # 300 keys under 256 x 256 blocks: three k blocks a q block, three q
+    # blocks a k block (times the group's four heads), of eight
+    assert grids == [("2", "8", "12"), ("8", "8", "3")]
+    assert stats["tiled_window"] == 1 and stats["tiled_bwd_fused"] == 1
+    assert traced(window=256)[1] == [("2", "8", "8"), ("8", "8", "2")]
+    # the block ranges, by hand at the mellum2 cell's shape: window 1024
+    # over 1024 x 1024 blocks touches 15 blocks a head where the causal
+    # half touches 36
+    at = (1024, 1024, 0)
+    band = [(fa._first_k(i, *at, 1024), fa._last_k(i, *at, 8)) for i in
+            range(8)]
+    assert band == [(0, 0)] + [(i - 1, i) for i in range(1, 8)]
+    assert sum(b - a + 1 for a, b in band) == 15
+    assert sum(fa._last_k(i, *at, 8) + 1 for i in range(8)) == 36
+    assert [(fa._first_q(j, *at, 8), fa._last_q(j, *at, 1024, 8))
+            for j in range(8)] == [(j, min(j + 1, 7)) for j in range(8)]
+    assert fa._k_steps(8, 8, 1024, 1024, 0, 1024) == 2
+    assert fa._k_steps(16, 16, 512, 512, 0, 1024) == 3
+
+
+def test_try_flash_alone_decides_the_windowed_path():
+    """A window goes to the tiled kernel where it takes the lengths, and
+    to the caller's composition (None) where it does not: a length off a
+    multiple of the block, the lse, a shifted or missing diagonal; the
+    short kernel never takes one; the op's composition masks the same
+    band."""
+    rng = np.random.RandomState(7)
+
+    def qkv(T, H=4, KVH=2, D=16):
+        return (jnp.asarray(rng.randn(1, T, H, D), jnp.float32),
+                jnp.asarray(rng.randn(1, T, KVH, D), jnp.float32),
+                jnp.asarray(rng.randn(1, T, KVH, D), jnp.float32))
+
+    q, k, v = qkv(256)
+    want = fa.flash_attention_reference(q, k, v, causal=True, window=40,
+                                        layout="bthd")
+    assert fa.try_flash(q, k, v, causal=True, layout="bthd",
+                        window=40) is None            # the CPU: no kernel
+    fa.set_mode("interpret")
+    try:
+        got = fa.try_flash(q, k, v, causal=True, layout="bthd", window=40)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+        same = qkv(256, KVH=4)      # the short kernel's: heads not shared
+        assert not fa.picks_short(*same, layout="bthd", interpret=True,
+                                  window=40)
+        assert fa.picks_short(*same, layout="bthd", interpret=True)
+        for kw in (dict(with_lse=True), dict(causal_offset=-1)):
+            assert fa.try_flash(q, k, v, causal=True, layout="bthd",
+                                window=40, **kw) is None
+        assert fa.try_flash(q, k, v, causal=False, layout="bthd",
+                            window=40) is None
+        # 1100 is over one block and no multiple of a legal one: the
+        # composition's
+        q3, k3, v3 = qkv(1100)
+        assert fa.try_flash(q3, k3, v3, causal=True, layout="bthd",
+                            window=40) is None
+    finally:
+        fa.set_mode("auto")
+    from paddle_tpu.ops import kernels_nn
+    out = kernels_nn._sdpa(None, {"Q": [q3], "K": [k3], "V": [v3]},
+                           {"layout": "bthd", "causal": True, "window": 40,
+                            "scale": 0.25})["Out"][0]
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(fa.flash_attention_reference(
+            q3, k3, v3, causal=True, window=40, layout="bthd")),
+        atol=2e-5, rtol=2e-5)
+    with pytest.raises(NotImplementedError):
+        fa.flash_attention(q.swapaxes(1, 2), k.swapaxes(1, 2),
+                           v.swapaxes(1, 2), window=40, interpret=True)
+
+
+def test_a_windowed_call_keeps_the_causal_blocks():
+    """The band's sweep found the causal pair again, so a window is no
+    argument of the block policy; at head 128 the pair fills the budget."""
+    assert fa._choose_blocks(8192, 8192, 128, 128, causal=True) \
+        == (1024, 1024)
+    assert fa._choose_blocks(8192, 8192, 128, 128, 512, 256, True) \
+        == (512, 256)
+    assert "window" not in inspect.signature(fa._choose_blocks).parameters
 
 
 # ---------------------------------------------------------------------------

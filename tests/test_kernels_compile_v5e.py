@@ -259,6 +259,62 @@ def test_tiled_attention_at_head_128_with_one_key_value_head_compiles(
     assert dk.shape == dv.shape == (Bq, T, KV, Dh) and dq.shape == q.shape
 
 
+@pytest.mark.parametrize("blocks", [None, (512, 512)])
+def test_windowed_attention_at_the_mellum2_cells_shape_compiles(one_chip,
+                                                                 blocks):
+    """The cell mellum2_train_1chip's sliding layers: [1, 8192, 32 x 128]
+    queries over 4 key-value heads, causal, a window of 1024, bf16,
+    through try_flash under a TPU lowering as the op reaches it: the
+    tiled kernels under the names a windowed call carries, the one
+    backward kernel (the dq of a key-value head's eight query heads is
+    FUSED_BWD_VMEM to the byte, as solar's), and a grid that walks the
+    band's blocks only. With `window=None` the names are the full
+    layer's."""
+    from paddle_tpu.ops import registry
+    Bq, T, Hq, KV, Dh, W = 1, 8192, 32, 4, 128, 1024
+    sds = jax.ShapeDtypeStruct
+    q = sds((Bq, T, Hq, Dh), jnp.bfloat16, sharding=one_chip)
+    kv = sds((Bq, T, KV, Dh), jnp.bfloat16, sharding=one_chip)
+    assert fa._bwd_resident_bytes(Hq // KV, T, Dh, 2) == fa.FUSED_BWD_VMEM
+
+    def step(window):
+        def run(q, k, v, g):
+            def attend(q, k, v):
+                if blocks is None:
+                    return fa.try_flash(q, k, v, causal=True, layout="bthd",
+                                        window=window)
+                return fa.flash_attention(
+                    q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
+                    causal=True, window=window, block_q=blocks[0],
+                    block_k=blocks[1]).swapaxes(1, 2)
+            out, vjp = jax.vjp(attend, q, k, v)
+            return (out,) + vjp(g)
+        return run
+
+    counted = dict(fa.STATS)
+    with registry.lowering_for("tpu"):
+        compiled = jax.jit(step(W)).lower(q, kv, kv, q).compile()
+        plain = jax.jit(step(None)).lower(q, kv, kv, q)
+        _, dq, dk, dv = jax.eval_shape(step(W), q, kv, kv, q)
+    assert fa.STATS["tiled_window"] == counted["tiled_window"] + 2
+    assert fa.STATS["tiled_bwd_fused"] == counted["tiled_bwd_fused"] + 3
+    names = _kernel_names(compiled)
+    assert len(names) == 2, names
+    for kernel in ("flash_attention_win_fwd", "flash_attention_win_bwd"):
+        assert any(kernel in n for n in names), names
+    text = plain.as_text()
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert "flash_attention_win" not in text
+    assert dk.shape == dv.shape == (Bq, T, KV, Dh) and dq.shape == q.shape
+    # the band's steps: at 1024 x 1024 two k blocks a q block of eight,
+    # and two q blocks a k block; at 512 x 512 three
+    bq, bk = blocks or fa._choose_blocks(T, T, Dh, Dh, causal=True)
+    n = T // bq
+    steps = fa._k_steps(n, T // bk, bq, bk, 0, W)
+    assert steps == (2 if bq == bk == 1024 else 3), (bq, bk, steps)
+    assert steps < T // bk
+
+
 def test_the_chunked_scan_compiles_at_the_cells_shape(one_chip):
     """`kda_attention` as solar_train_1chip calls it: [1, 8192, 8 x 128]
     bf16 q, k, v, a float32 log-decay, forward and gradient through the

@@ -15,13 +15,18 @@ above DEFAULT_BLOCK_Q (PR 33) were measured:
     chiprun --chips 1 -- python tools/bench_attention.py \\
         '[[2,8192,8192,32,64,8]]' tiled,tiled:512x512,tiled:1024x1024 \\
         20 causal,nobias
+    chiprun --chips 1 -- python tools/bench_attention.py \
+        '[[1,8192,8192,32,128,4]]' tiled,tiled:512x512,tiled:256x512 \
+        20 causal,nobias,window1024
 
 Shapes are [B, T, S, H, D] or, with key-value heads shared by groups of
 query heads (the tiled kernel and the composition's reference only),
 [B, T, S, H, D, KVH]. `tiled:<block_q>x<block_k>` is the tiled kernel at
 those blocks (`tiled`: at its defaults). The fourth argument is a comma
-list of `causal` / `full` (one masking only; both by default) and
-`nobias` (no key-padding bias). One JSON line per (shape, causal,
+list of `causal` / `full` (one masking only; both by default), `nobias`
+(no key-padding bias) and `window<n>` (a sliding window of n keys under
+the causal diagonal: the band's block sweep above DEFAULT_BLOCK_Q). One
+JSON line per (shape, causal,
 implementation) with `fwd_ms`, `bwd_ms` (the vjp alone, the residuals
 kept) and `ms` (both in one program); all of them again in
 chiprun_out/bench_attention.json. A time means something on the chip
@@ -38,9 +43,10 @@ import numpy as np
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _implementation(impl):
+def _implementation(impl, window=None):
     """`impl` (short, sdpa, tiled, tiled:<bq>x<bk>) as a function of
-    `bthd` q, k, v, bias and causal."""
+    `bthd` q, k, v, bias and causal under `window` (the caller's: none
+    where nothing is causal; the short kernel has none)."""
     from paddle_tpu.ops import kernels_nn
     from paddle_tpu.ops.pallas import flash_attention as fa
     name, _, blocks = impl.partition(":")
@@ -54,13 +60,15 @@ def _implementation(impl):
         ins = {"Q": [q], "K": [k], "V": [v],
                "Mask": [] if bias is None else [bias]}
         attrs = {"layout": "bthd", "causal": causal,
-                 "scale": q.shape[-1] ** -0.5}
+                 "scale": q.shape[-1] ** -0.5,
+                 "window": window}
         return kernels_nn._sdpa(None, ins, attrs)["Out"][0]
 
     def tiled(q, k, v, bias, causal):
         return fa.flash_attention(
             q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
-            bias=bias, causal=causal, **blocks).swapaxes(1, 2)
+            bias=bias, causal=causal, window=window,
+            **blocks).swapaxes(1, 2)
 
     def short(q, k, v, bias, causal):
         return fa.flash_attention_bthd(q, k, v, bias=bias, causal=causal)
@@ -68,12 +76,13 @@ def _implementation(impl):
     return {"short": short, "sdpa": sdpa, "tiled": tiled}[name]
 
 
-def bench(impl, B, T, S, H, D, causal, KVH=None, with_bias=True, n=20):
+def bench(impl, B, T, S, H, D, causal, KVH=None, with_bias=True, n=20,
+          window=None):
     """{fwd_ms, bwd_ms, ms} a call of `impl` on seeded bf16 arrays with a
     key-padding bias; the backward gets a random cotangent."""
     import jax
     import jax.numpy as jnp
-    fn = _implementation(impl)
+    fn = _implementation(impl, window)
     rng = np.random.RandomState(0)
     KVH = KVH or H
 
@@ -124,6 +133,8 @@ def main(argv):
               "TPU; nothing measured", file=sys.stderr)
         return 2
     only = {"causal", "full"} & set(flags)
+    window = next((int(f[len("window"):]) for f in flags
+                   if f.startswith("window")), None)
     maskings = [c for c in (False, True)
                 if not only or ("causal" if c else "full") in only]
     lines = []
@@ -133,10 +144,12 @@ def main(argv):
                 line = {"platform": platform, "impl": impl, "B": B,
                         "T": T, "S": S, "H": H, "D": D,
                         "KVH": kvh[0] if kvh else H, "causal": causal,
-                        "bias": "nobias" not in flags}
+                        "bias": "nobias" not in flags,
+                        "window": window}
                 try:
                     line.update(bench(impl, B, T, S, H, D, causal,
-                                      line["KVH"], line["bias"], n))
+                                      line["KVH"], line["bias"], n,
+                                      line["window"]))
                 except Exception as e:   # out of memory, no tiling
                     line["error"] = str(e)[:300]
                 print(json.dumps(line), flush=True)
