@@ -43,7 +43,15 @@ and a fifth on the way back to the tokens:
                      which the other chips' experts hold most.
 
 The gather into the buffer is XLA's, a tile at a time over the tiles in
-use (PERF.md section 7).
+use, and the buffer it fills starts unwritten (`_gather_rows`: the output
+of a Mosaic call with an empty body, `moe_gather_buffer`): nothing of the
+worst case's rows is written but the tiles in use. Until PR 37 the loop
+started from zeros, which XLA wrote out whole before every gather (570 MB
+at 69632 x 4096 bf16, two a layer). A Mosaic kernel that copies a token's
+row by DMA cannot be built: a 2-D array lies in HBM in tiles of 8 (bf16:
+16) rows x 128 columns, a row is not contiguous there, and Mosaic refuses
+a slice of one row ("must be aligned to tiling (8)"). PERF.md section 6,
+PR 37, and 7.7.
 """
 import functools
 
@@ -61,7 +69,11 @@ except Exception:  # pragma: no cover
 from ..registry import active
 
 __all__ = ["expert_ffn", "expert_ffn_reference", "try_expert_ffn",
-           "supports", "make_plan", "DEFAULT_TILE_ROWS"]
+           "supports", "make_plan", "DEFAULT_TILE_ROWS", "STATS"]
+
+# traces of a gather into a buffer that starts unwritten (`_gather_rows`:
+# three a layer, forward and backward), as flash_attention.STATS
+STATS = {"gather_kernel": 0}
 
 # Rows of one tile. An expert's group is padded to whole tiles, so a
 # larger tile wastes more rows (half a tile an expert on average) and a
@@ -299,13 +311,10 @@ def _tgmm(plan, lhs, rhs, n_held, tm, interpret):
 # ---------------------------------------------------------------------------
 # the expert FFN over the sorted buffer, with its backward
 # ---------------------------------------------------------------------------
-def _gather_rows(x, plan, tm):
-    """x [N, H] -> the sorted buffer [M, H], a tile an iteration over the
-    tiles in use only (a loop whose trip count is `n_active`: the time
-    follows the pairs routed here; one gather over the worst case's M
-    rows took 1.38 ms where this takes 0.82: PERF.md section 6, PR 30).
-    A padding row (src = N) reads zeros; the tiles past the last used one
-    are zeros that no kernel reads."""
+def _fill_tiles(out, x, plan, tm):
+    """out [M, H] with the tiles in use written from x [N, H], a tile an
+    iteration: row r of a tile gets x[src[r]], a padding row (src = N)
+    zeros."""
     src = plan["src"]
 
     def tile(t, out):
@@ -313,8 +322,56 @@ def _gather_rows(x, plan, tm):
         blk = jnp.take(x, rows, axis=0, mode="fill", fill_value=0)
         return jax.lax.dynamic_update_slice(out, blk, (t * tm, 0))
 
-    return jax.lax.fori_loop(0, plan["n_active"][0], tile,
-                             jnp.zeros((src.shape[0], x.shape[1]), x.dtype))
+    return jax.lax.fori_loop(0, plan["n_active"][0], tile, out)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _gather(x, plan, tm, interpret):
+    # A buffer that nothing has written: the output of a Mosaic call whose
+    # body is empty, left in HBM. x goes in unread, so that two buffers
+    # asked for over one array are one call to XLA (the backward's gather
+    # of x merges with the forward's, buffer and loop, as the loops did
+    # when they started from zeros) and two over two arrays are two.
+    unwritten = pl.pallas_call(
+        lambda x_ref, out_ref: None,
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((plan["src"].shape[0], x.shape[1]),
+                                       x.dtype),
+        name="moe_gather_buffer", interpret=interpret)(x)
+    return _fill_tiles(unwritten, x, plan, tm)
+
+
+def _gather_rows(x, plan, tm, interpret=False):
+    """x [N, H] -> the sorted buffer [M, H], filled where it is used and
+    nowhere else: a tile an iteration over the tiles in use (a loop whose
+    trip count is `n_active`: the time follows the pairs routed here),
+    into a buffer that starts unwritten. A padding row inside a used tile
+    (src = N) reads zeros, since `moe_tgmm` sums over it and `moe_combine`
+    multiplies it by 0; the rows past the last used tile hold whatever
+    the memory held, and no kernel reads them (their grids skip those
+    tiles, `moe_combine` clamps its last chunk inside the used ones).
+    Until PR 37 the loop started from `jnp.zeros((M, H))`, which XLA
+    wrote out whole before every gather. One gather alone on the chip,
+    device time from its trace, zeros / unwritten, ms (my chip runs, PR
+    37; `tools/bench_expert_ffn.py`'s `take_gather` / `gather` read the
+    same with 0.04 more of dispatch): [16384, 2048] -> [69632, 2048],
+    19 tiles in use: 0.737 (fill 0.436 + loop 0.301) / 0.301; [8192,
+    4096] -> [69632, 4096], 8 tiles: 1.070 (0.973 + 0.097) / 0.217;
+    [8192, 2304] -> [73728, 2304], 40 tiles: 0.862 (0.536 + 0.327) /
+    0.716. The loop's own time is XLA's choice of where x lies while it
+    runs: with x brought into VMEM its gather takes 0.035 ms for 8 tiles
+    and 0.13 for 40, from HBM 0.155 and 0.52. Alone, XLA prefetches x
+    under the fill and not without it; inside the cells' steps the loops
+    ran at the slow rate behind the fills and run faster without them (a
+    loop of mellum2_train_1chip 0.91 -> 0.44 ms, of lfm2_train_1chip
+    0.40 -> 0.29, of solar_train_1chip 0.15 -> 0.13, `copy-done` up 0.9
+    ms a step: PERF.md section 6, PR 37), so a piece timed alone says
+    little about the loop. Two and more tiles an iteration are slower
+    alone (0.359, 0.275, 0.813 at two).
+    Jitted so that a program's expert layers share one trace."""
+    STATS["gather_kernel"] += 1
+    return _gather(x, plan, tm, interpret)
 
 
 def _combine_tiling(n_tokens, tm):
@@ -539,7 +596,7 @@ def _ffn(x, topk_w, w1, w3, w2, plan, n_held, tm, interpret):
 
 
 def _ffn_fwd(x, topk_w, w1, w3, w2, plan, n_held, tm, interpret):
-    xs = _gather_rows(x, plan, tm)
+    xs = _gather_rows(x, plan, tm, interpret)
     g = _gmm_swiglu(plan, xs, w1, w3, tm, interpret)
     y = _gmm(plan, [(g, w2)], False, tm, interpret)
     out = _combine(y, plan, topk_w, n_held, tm, interpret)
@@ -550,8 +607,9 @@ def _ffn_bwd(n_held, tm, interpret, res, dout):
     x, topk_w, w1, w3, w2, plan = res
     M = plan["src"].shape[0]
     dest = plan["dest"]
-    xs = _gather_rows(x, plan, tm)
-    dys = _gather_rows(dout.astype(x.dtype), plan, tm)     # unweighted
+    xs = _gather_rows(x, plan, tm, interpret)
+    dys = _gather_rows(dout.astype(x.dtype), plan, tm,
+                       interpret)                          # unweighted
     w_rows = jnp.zeros((M,), jnp.float32).at[dest.reshape(-1)].set(
         topk_w.astype(jnp.float32).reshape(-1), mode="drop")
     w_rows = jnp.broadcast_to(w_rows[:, None], (M, _LANES))

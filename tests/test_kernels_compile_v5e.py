@@ -186,6 +186,10 @@ def test_grouped_expert_products_compile_at_the_cells_widths(one_chip):
     for kernel in ("moe_gmm_swiglu", "moe_gmm", "moe_swiglu_bwd",
                    "moe_tgmm", "moe_combine"):
         assert any(kernel in n for n in names), names
+    # three gathers are traced and XLA keeps two: the backward's gather of
+    # x is the forward's, buffer and loop (PERF.md section 6, PR 37)
+    assert sum("moe_gather_buffer" in n for n in names) == 2, names
+    assert len(re.findall(r" while\(", compiled.as_text())) == 2
     # the buffer is the worst case's (every token's 4 pairs here) and the
     # module keeps a handful of buffers of it, not one a product
     rows = gm.buffer_tiles(N, k, E, gm.DEFAULT_TILE_ROWS) \
@@ -195,6 +199,41 @@ def test_grouped_expert_products_compile_at_the_cells_widths(one_chip):
     # kept a [N, k, Hd] intermediate (268 MB): 1997129728 without it
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 2265923584 - N * k * Hd * 2 // 2
+
+
+@pytest.mark.parametrize("N,Hd,E,k", [
+    (16384, 2048, 8, 4), (8192, 4096, 8, 8), (8192, 2304, 16, 8)],
+    ids=["lfm2", "solar", "mellum2"])
+def test_the_gather_writes_no_buffer_of_the_worst_case(one_chip, N, Hd, E,
+                                                       k):
+    """The way into the sorted buffer alone, at the three decoder cells'
+    shapes: the buffer is the output of the Mosaic call `moe_gather_buffer`
+    (an empty body: nothing of the worst case's M rows is written), the
+    loop over the tiles in use updates it in place, and no `broadcast` of
+    [M, Hd] zeros is left (570 MB a gather in solar_train_1chip until
+    PR 37)."""
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    tm = gm.DEFAULT_TILE_ROWS
+    M = gm.buffer_tiles(N, k, E, tm) * tm
+    assert M == {2048: 69632, 4096: 69632, 2304: 73728}[Hd]
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def into(x, idx):
+        return gm._gather_rows(x, gm.make_plan(idx, 0, E, tm), tm)
+
+    compiled = jax.jit(into).lower(S((N, Hd), jnp.bfloat16),
+                                   S((N, k), jnp.int32)).compile()
+    assert any("moe_gather_buffer" in n for n in _kernel_names(compiled))
+    out = jax.eval_shape(into, S((N, Hd), jnp.bfloat16), S((N, k), jnp.int32))
+    assert out.shape == (M, Hd) and out.dtype == jnp.bfloat16
+    whole = [i for i in _instructions(compiled.as_text())
+             if (M, Hd) in _dims(i[1])]
+    assert whole and not [i[3] for i in whole
+                          if i[2] in ("broadcast", "copy", "constant")]
+    # the plan's index arrays and a tile's rows: no second buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < M * Hd * 2 // 8
 
 
 @pytest.mark.parametrize("weighted", [True, False],

@@ -318,6 +318,8 @@ def _combine_case(routing, N=40, k=2):
         idx[:, 0], idx[:, 1] = 3, 7
     elif routing == "none held":
         idx[:, 0], idx[:, 1] = 0, 6
+    elif routing == "a held expert with no pair":
+        idx[idx == 3] = 6
     elif routing == "one expert twice":
         idx = _twice(idx)
     return idx
@@ -365,11 +367,139 @@ def test_moe_combine_matches_the_gather_it_replaced(routing, N, weighted,
                                np.asarray(want, "float32"), **tol)
 
 
+def _take_gather(x, plan, tm):
+    """What `_gather_rows` did until PR 37, kept as its oracle: the same
+    loop of `jnp.take` over the tiles in use, into a buffer of zeros that
+    XLA wrote out whole first."""
+    src = plan["src"]
+
+    def tile(t, out):
+        rows = jax.lax.dynamic_slice(src, (t * tm,), (tm,))
+        blk = jnp.take(x, rows, axis=0, mode="fill", fill_value=0)
+        return jax.lax.dynamic_update_slice(out, blk, (t * tm, 0))
+
+    return jax.lax.fori_loop(0, plan["n_active"][0], tile,
+                             jnp.zeros((src.shape[0], x.shape[1]), x.dtype))
+
+
+@pytest.fixture
+def rng_put_back():
+    """A case draws from the module's generator and puts it back, so that
+    the tests after it see the numbers they were written against."""
+    state = RNG.bit_generator.state
+    yield
+    RNG.bit_generator.state = state
+
+
+GATHER_ROUTINGS = [("random", 40), ("random", 301),   # off a multiple
+                   ("all to one held expert", 77),
+                   ("a held expert with no pair", 50), ("none held", 24)]
+
+
+@pytest.mark.parametrize("tile_rows", [8, 16])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("routing,N", GATHER_ROUTINGS)
+@pytest.mark.usefixtures("rng_put_back")
+def test_gather_rows_fills_the_tiles_in_use_as_the_loop_it_replaced(
+        routing, N, dtype, tile_rows):
+    """The sorted buffer starts unwritten (PR 37): inside the tiles in use
+    it holds, bit for bit, what the loop over a zero-filled buffer wrote
+    (a copy of the tokens' rows, zeros in the padding rows); past them it
+    holds whatever the memory held (the interpreter: NaN), and the count
+    of tiles in use is all that decides where that starts."""
+    idx = _combine_case(routing, N)
+    plan = gm.make_plan(jnp.asarray(idx), 2, 3, tile_rows)
+    M, in_use = plan["src"].shape[0], int(plan["n_active"][0]) * tile_rows
+    x = jnp.asarray(_f32(N, 16), dtype)
+    before = gm.STATS["gather_kernel"]
+    got = gm._gather_rows(x, plan, tile_rows, True)
+    assert gm.STATS["gather_kernel"] == before + 1
+    want = _take_gather(x, plan, tile_rows)
+    assert got.shape == want.shape == (M, 16) and got.dtype == x.dtype
+    np.testing.assert_array_equal(np.asarray(got[:in_use], "float32"),
+                                  np.asarray(want[:in_use], "float32"))
+    src = np.asarray(plan["src"])
+    held = ((idx >= 2) & (idx < 5)).sum()
+    assert (src[:in_use] < N).sum() == held and (src[in_use:] == N).all()
+    assert not np.asarray(got[:in_use], "float32")[src[:in_use] == N].any()
+    if routing == "none held":
+        assert in_use == 3 * tile_rows       # a tile an expert, all padding
+        assert not np.asarray(got[:in_use], "float32").any()
+    if routing == "a held expert with no pair":
+        assert int(plan["counts"][1]) == 0
+    # the worst case still fits, and the zero-filled rows are gone
+    assert in_use < M
+    assert np.isnan(np.asarray(got[in_use:], "float32")).all()
+
+
+@pytest.mark.parametrize("routing,N", GATHER_ROUTINGS)
+@pytest.mark.usefixtures("rng_put_back")
+def test_no_kernel_reads_the_buffer_past_the_tiles_in_use(routing, N,
+                                                          monkeypatch):
+    """Every gather's rows past `n_active * tile_rows` are set to NaN on
+    the way out (on the chip they hold what the memory held): the layer's
+    output and all five gradients are finite and the composition's."""
+    x, _, tw, w1, w3, w2 = _expert_case(N=N)
+    idx = _combine_case(routing, N)
+    real, poisoned = gm._gather_rows, []
+
+    def gather_then_poison(x, plan, tm, interpret=False):
+        out = real(x, plan, tm, interpret)
+        row = jnp.arange(out.shape[0])[:, None]
+        poisoned.append(out.shape)
+        return jnp.where(row < plan["n_active"][0] * tm, out, jnp.nan)
+
+    monkeypatch.setattr(gm, "_gather_rows", gather_then_poison)
+
+    def run(fn):
+        def loss(x, tw, w1, w3, w2):
+            out, _ = fn(x, jnp.asarray(idx), tw, w1, w3, w2, first_expert=2)
+            return jnp.sum(out * jnp.cos(out)), out
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                  has_aux=True)(
+            *(jnp.asarray(a) for a in (x, tw, w1[2:5], w3[2:5], w2[2:5])))
+
+    (_, out), grads = run(lambda *a, **kw: gm.expert_ffn(
+        *a, tile_rows=8, interpret=True, **kw))
+    # the forward's xs, the backward's xs and dys
+    M = gm.buffer_tiles(N, 2, 3, 8) * 8
+    assert poisoned == [(M, x.shape[1])] * 3
+    (_, want), want_g = run(gm.expert_ffn_reference)
+    for g, w in zip((out,) + grads, (want,) + want_g):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(g, w, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.usefixtures("rng_put_back")
+def test_the_gathers_of_a_layer_are_counted_where_they_are_traced():
+    """`STATS["gather_kernel"]`: three a layer (the forward's xs, the
+    backward's xs and dys), through the op and the registry, beside the
+    registry's own count of the op's traces; the composition moves
+    neither."""
+    from paddle_tpu.ops import registry as ops_registry
+    from paddle_tpu.ops.kern import registry as kreg
+
+    def counts():
+        per = kreg.STATS["by_kernel"].get("moe_expert_ffn", {})
+        return gm.STATS["gather_kernel"], per.get("accepted", 0)
+
+    x, idx, tw, w1, w3, w2 = _expert_case()
+    before = counts()
+    _expert_program(x, idx, tw, w1[:2], w3[:2], w2[:2], 0)
+    assert counts() == before               # the CPU: the composition
+    ops_registry.set_mode("interpret")
+    try:
+        _expert_program(x, idx, tw, w1[:2], w3[:2], w2[:2], 0)
+    finally:
+        ops_registry.set_mode("auto")
+    assert counts() == (before[0] + 3, before[1] + 1)
+
+
 def test_bench_expert_ffn_tool_refuses_without_a_chip(tmp_path, monkeypatch,
-                                                      capsys):
+                                                      capsys, rng_put_back):
     """tools/bench_expert_ffn.py (how the one-layer timings of PERF.md
     were measured) times nothing off the chip: no `ms` line, no file,
-    exit code 2; its copy of the replaced gather is the oracle's sum."""
+    exit code 2; its copies of the replaced gathers are the oracles'."""
     import importlib.util
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "tools", "bench_expert_ffn.py")
@@ -385,6 +515,10 @@ def test_bench_expert_ffn_tool_refuses_without_a_chip(tmp_path, monkeypatch,
     w = jnp.asarray(_f32(2, 2))
     np.testing.assert_array_equal(tool.take_combine(rows, dest, w),
                                   _take_combine(rows, dest, w))
+    plan = gm.make_plan(jnp.asarray(_combine_case("random", 40)), 2, 3, 8)
+    x = jnp.asarray(_f32(40, 16))
+    np.testing.assert_array_equal(tool.take_gather(x, plan, 8),
+                                  _take_gather(x, plan, 8))
 
 
 def test_the_buffer_holds_the_worst_case_and_a_tile_has_one_expert():

@@ -1,15 +1,20 @@
 #!/usr/bin/env python
 """Time one expert layer alone (`ops/pallas/grouped_matmul.py`) and its
-pieces: the plan, the gather into the sorted buffer, the way back to the
-tokens (`moe_combine`, with and without the routing weights), XLA's
-gather over every (token, choice) that `moe_combine` replaced, and the
-whole layer forward and forward + backward, under a random router.
+pieces: the plan, the gather into the sorted buffer (`gather`: the loop
+over the tiles in use into a buffer that starts unwritten) and the loop
+over a zero-filled buffer that it replaced (`take_gather`), the way back
+to the tokens (`moe_combine`, with and without the routing weights),
+XLA's gather over every (token, choice) that `moe_combine` replaced, and
+the whole layer forward and forward + backward, under a random router.
 
-This is how the one-layer numbers of PERF.md section 5 and 6 (PR 31) were
-measured, at the shapes of the cell lfm2_train_1chip:
+This is how the one-layer numbers of PERF.md section 5 and 6 (PR 31, PR
+37) were measured, at the shapes of the cells lfm2_train_1chip,
+solar_train_1chip and mellum2_train_1chip:
 
     chiprun --chips 1 -- python tools/bench_expert_ffn.py \\
         '[[16384,4,2048,1536,8,64]]'
+    ... '[[8192,8,4096,1280,8,320]]'
+    ... '[[8192,8,2304,896,16,64]]'
 
 Shapes are [tokens, k, hidden, expert width, experts held, experts]. One
 JSON line per (shape, piece); all of them again in
@@ -36,6 +41,16 @@ def take_combine(rows, dest, weights=None):
     if weights is not None:
         picked = picked * weights[..., None]
     return jnp.sum(picked, axis=1).astype(rows.dtype)
+
+
+def take_gather(x, plan, tm):
+    """What `_gather_rows` was until PR 37: the same loop over the tiles
+    in use, into a buffer of the worst case's rows that XLA filled with
+    zeros first."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import grouped_matmul as gm
+    zeros = jnp.zeros((plan["src"].shape[0], x.shape[1]), x.dtype)
+    return gm._fill_tiles(zeros, x, plan, tm)
 
 
 def pieces(N, k, H, F, held, experts):
@@ -68,7 +83,8 @@ def pieces(N, k, H, F, held, experts):
 
     return {
         "plan": (lambda i: gm.make_plan(i, 0, held, tm), (idx,)),
-        "gather_rows": (lambda x, p: gm._gather_rows(x, p, tm), (x, plan)),
+        "gather": (lambda x, p: gm._gather_rows(x, p, tm), (x, plan)),
+        "take_gather": (lambda x, p: take_gather(x, p, tm), (x, plan)),
         "combine": (lambda r, p: gm._combine(r, p, None, held, tm, False),
                     (rows, plan)),
         "combine_weighted": (
