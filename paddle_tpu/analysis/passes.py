@@ -211,6 +211,7 @@ def check_shape_dtype(ctx):
     import jax
     import jax.numpy as jnp
     from ..core.dtypes import as_jnp_dtype
+    from ..core.trace import kernel_attrs
     from ..ops.registry import get_kernel, has_kernel, KernelCtx
 
     program = ctx.program
@@ -402,7 +403,7 @@ def check_shape_dtype(ctx):
                 continue
             ins = {slot: [env[n] for n in names]
                    for slot, names in op.inputs.items() if names}
-            attrs = dict(op.attrs)
+            attrs = kernel_attrs(op)[0]
             attrs.setdefault("_op_type", op.type)
             kern = get_kernel(op.type)
             try:

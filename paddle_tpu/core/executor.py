@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from .framework import default_main_program, Program
 from .place import core_place_of
 from .scope import global_scope
-from .trace import build_step_fn
+from .trace import build_step_fn, op_scope
 from .dtypes import as_jnp_dtype
 from .. import telemetry as _tm
 from ..resilience import chaos as _chaos
@@ -597,8 +597,10 @@ class Executor:
                 # counter rather than host-side fold_in: a host-side
                 # jax.random call is an extra dispatch per step
                 def stepped(persist, feed, step):
-                    key = jax.random.fold_in(
-                        jax.random.PRNGKey(seed), step.astype(jnp.uint32))
+                    with op_scope("rng_key"):
+                        key = jax.random.fold_in(
+                            jax.random.PRNGKey(seed),
+                            step.astype(jnp.uint32))
                     fetches, new_persist = step_fn(persist, feed, key)
                     return fetches, new_persist, step + 1
 
@@ -609,7 +611,8 @@ class Executor:
                 # (telemetry.compiled_text): shapes only, nothing runs
                 _tm.compiles.register_program(
                     f"executor:{program._version}", fn,
-                    (persist, feed_arrays, step_dev))
+                    (persist, feed_arrays, step_dev),
+                    program.name_scopes())
                 if tm_on:
                     # AOT-compile here (still inside the compile span)
                     # to capture this ckey's FLOPs from cost_analysis

@@ -8,6 +8,8 @@ core/trace.py) — whole-program compilation instead of per-op kernel
 dispatch, which is the TPU-native execution model.
 """
 import contextlib
+import re
+
 import numpy as np
 
 from .. import unique_name
@@ -97,6 +99,13 @@ class Operator:
         self.inputs = self._normalize_slots(inputs)
         self.outputs = self._normalize_slots(outputs)
         self.attrs = dict(attrs or {})
+        # the site this op was declared at (ref framework.py: the same
+        # attribute): the tracer writes it under the op type in the
+        # device trace's name stack; a kernel's attrs leave it out
+        # (core/trace.py::kernel_attrs, the shape pass too)
+        if _name_scope_stack:
+            self.attrs.setdefault("op_namescope",
+                                  "/".join(_name_scope_stack))
 
     @staticmethod
     def _normalize_slots(slots):
@@ -235,6 +244,11 @@ class Program:
         self._device_counters[name] = (var.name, kind)
         self._bump_version()
 
+    def name_scopes(self):
+        """The sites its ops were declared at (`op_namescope`)."""
+        return {op.attrs["op_namescope"] for b in self.blocks
+                for op in b.ops if op.attrs.get("op_namescope")}
+
     def list_vars(self):
         for b in self.blocks:
             yield from b.vars.values()
@@ -282,7 +296,8 @@ class Program:
                 attrs = dict(op.attrs)
                 if for_test and op.type in ("dropout", "batch_norm"):
                     attrs["is_test"] = True
-                nop = Operator(nb, op.type, {}, {}, attrs)
+                nop = Operator(nb, op.type)
+                nop.attrs = attrs   # op_namescope rides along as any other
                 nop.inputs = {k: list(v) for k, v in op.inputs.items()}
                 nop.outputs = {k: list(v) for k, v in op.outputs.items()}
                 # fluid interop: proto-declared attr types (INT vs
@@ -422,11 +437,26 @@ def program_guard(main_program, startup_program=None):
 
 
 _name_scope_stack = []
+_SCOPE_ELEMENT = re.compile(r"[\w.\-]+")
+
+
+def valid_name_scope(prefix):
+    """Whether every `/`-separated element of `prefix` can stand in a
+    device trace's name stack."""
+    return isinstance(prefix, str) and all(
+        _SCOPE_ELEMENT.fullmatch(e) for e in prefix.split("/"))
 
 
 @contextlib.contextmanager
 def name_scope(prefix):
-    """Cosmetic op-name scoping (ref framework.py:name_scope)."""
+    """Every op declared inside carries the stack of open scopes, joined
+    by `/`, as its `op_namescope` attribute (ref framework.py:name_scope).
+    Under a profiler session the device time of the op's kernels is
+    reported by that site (`fluid.profiler`): which `mul` was slow."""
+    if not valid_name_scope(prefix):
+        raise ValueError(
+            f"name_scope({prefix!r}): each '/'-separated element must "
+            f"be made of letters, digits, '_', '.' and '-'")
     _name_scope_stack.append(prefix)
     try:
         yield

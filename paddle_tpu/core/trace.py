@@ -15,6 +15,8 @@ handled here: the forward segment is replayed inside jax.value_and_grad
 batch-norm stat updates survive), replacing the reference's symbolic
 per-op grad ops (python/paddle/fluid/backward.py).
 """
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -22,7 +24,7 @@ from ..ops.registry import get_kernel, KernelCtx, accel, lowering_for
 from .framework import grad_var_name
 from .dtypes import is_float
 
-__all__ = ["build_step_fn", "exec_op"]
+__all__ = ["build_step_fn", "exec_op", "op_scope"]
 
 # Fuse the per-param optimizer tail (SURVEY §5 headroom note): maximal
 # consecutive runs of adam ops with identical hyperparams+LR are
@@ -36,6 +38,28 @@ __all__ = ["build_step_fn", "exec_op"]
 # toggles so benchmarks can A/B.
 FUSE_OPTIMIZER_TAIL = True
 FUSE_MAX_ELEMS = 1 << 18
+
+
+def op_scope(op_type, site=None):
+    """The scope of everything one Fluid op emits: the op type names it,
+    so a device trace can say which Fluid op a fusion belongs to, and
+    under it the site the op was declared at (`op_namescope`), so the
+    trace can say which `mul`. Inside value_and_grad JAX's name stack
+    adds the phase by itself: `jvp(<op>)/<site>` forward,
+    `transpose(jvp(<op>))/<site>` backward, the bare op type after the
+    gradient. Metadata only: the module is the same."""
+    stack = contextlib.ExitStack()
+    stack.enter_context(jax.named_scope(op_type))
+    if site:
+        stack.enter_context(jax.named_scope(site))
+    return stack
+
+
+def kernel_attrs(op):
+    """(the attributes as a kernel gets them, the op's site):
+    `op_namescope` is the trace's, no kernel's."""
+    attrs = dict(op.attrs)
+    return attrs, attrs.pop("op_namescope", None)
 
 
 def _adam_sig(op):
@@ -92,7 +116,7 @@ def _exec_adam_group(env, ops_, is_test, place):
         "LearningRate": [env[ops_[0].inputs["LearningRate"][0]]],
     }
     ctx = KernelCtx(is_test=is_test, place=place)
-    out = get_kernel("adam")(ctx, ins, ops_[0].attrs)
+    out = get_kernel("adam")(ctx, ins, kernel_attrs(ops_[0])[0])
     for i, op in enumerate(ops_):
         env[op.outputs["ParamOut"][0]] = out["ParamOut"][0][i]
         env[op.outputs["Moment1Out"][0]] = out["Moment1Out"][0][i]
@@ -123,7 +147,7 @@ def _exec_adam_run(env, run, key, is_test, place, block):
         for s in gkey[0]:
             n_elems *= s
         if len(members) >= 2 and n_elems <= FUSE_MAX_ELEMS:
-            with jax.named_scope("adam"):
+            with op_scope("adam"):
                 _exec_adam_group(env, [op for op, _ in members], is_test,
                                  place)
         else:
@@ -244,18 +268,14 @@ def exec_op(env, op, op_idx, base_key, is_test, place, block, program=None):
                     f"did you run the startup program / feed it?")
             vals.append(env[n])
         ins[slot] = vals
-    key = jax.random.fold_in(base_key, op_idx) if base_key is not None else None
-    # trace-time lowering consults the kern registry through the one
-    # accel seam (ops.registry.accel) — op kernels never import pallas
-    ctx = KernelCtx(key=key, is_test=is_test, place=place, accel=accel)
-    attrs = dict(op.attrs)
+    attrs, site = kernel_attrs(op)
     attrs.setdefault("_op_type", op.type)
-    # the op type names the scope of everything the kernel emits, so a
-    # device trace can say which Fluid op a fusion belongs to; inside
-    # value_and_grad JAX's name stack adds the phase by itself:
-    # jvp(<op>) forward, transpose(jvp(<op>)) backward, the bare op
-    # type in the optimizer tail. Metadata only: the module is the same.
-    with jax.named_scope(op.type):
+    with op_scope(op.type, site):
+        key = jax.random.fold_in(base_key, op_idx) \
+            if base_key is not None else None
+        # trace-time lowering consults the kern registry through the one
+        # accel seam (ops.registry.accel) — op kernels never import pallas
+        ctx = KernelCtx(key=key, is_test=is_test, place=place, accel=accel)
         outs = kern(ctx, ins, attrs)
     for slot, names in op.outputs.items():
         vals = outs.get(slot)
@@ -373,6 +393,17 @@ def build_step_fn(program, fetch_names, is_test, place,
     ops = _prune_ops(program, list(block.ops), fetch_names)
     persist_names = [v.name for v in program.persistable_vars()]
     bi = _find_backward(ops)
+    loss_scope = contextlib.nullcontext
+    if bi is not None:
+        # the float32 sum the gradient is taken of belongs to the op that
+        # computes the loss
+        loss_op = next((op for op in reversed(ops[:bi])
+                        if ops[bi].attrs["loss_name"]
+                        in op.output_names()), None)
+        if loss_op is not None:
+            def loss_scope():
+                return op_scope(loss_op.type,
+                                loss_op.attrs.get("op_namescope"))
     sparse_deltas = _collect_sparse_deltas(program, ops)
     eng = sparse_engine
 
@@ -415,7 +446,8 @@ def build_step_fn(program, fetch_names, is_test, place,
                 for i, op in enumerate(ops[:bi]):
                     run_op(e, op, i, key)
                 loss = e[loss_name]
-                return jnp.sum(loss.astype(jnp.float32)), e
+                with loss_scope():
+                    return jnp.sum(loss.astype(jnp.float32)), e
 
             if getattr(program, "_remat", False):
                 # transpiler.memory_optimize: recompute forward activations
@@ -457,12 +489,15 @@ def build_step_fn(program, fetch_names, is_test, place,
                         ishape + (wv.shape[-1],), wv.dtype)
                     tap_grads[tap["delta"]] = tap["grad"]
             (_, env), grads = jax.value_and_grad(fwd, has_aux=True)(pvals)
-            if grad_transform is not None:
-                synced, extra_persist = grad_transform(dict(grads), env)
-                grads = dict(grads, **synced)
-            for n in pnames:
-                env[grad_var_name(n)] = grads[n].astype(env[n].dtype) \
-                    if hasattr(grads[n], "astype") else grads[n]
+            # what the step emits here belongs to no kernel: the Fluid op
+            # it stands for names it
+            with op_scope("backward_macro"):
+                if grad_transform is not None:
+                    synced, extra_persist = grad_transform(dict(grads), env)
+                    grads = dict(grads, **synced)
+                for n in pnames:
+                    env[grad_var_name(n)] = grads[n].astype(env[n].dtype) \
+                        if hasattr(grads[n], "astype") else grads[n]
             for dname, gname in tap_grads.items():
                 env[gname] = grads[dname]
             tail = [(op, i) for i, op in

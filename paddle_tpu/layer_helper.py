@@ -7,7 +7,8 @@ variables, and appends activations/bias ops.
 import numpy as np
 
 from . import unique_name
-from .core.framework import default_main_program, default_startup_program
+from .core.framework import (default_main_program, default_startup_program,
+                             name_scope, valid_name_scope)
 from .param_attr import ParamAttr
 from .initializer import XavierInitializer, ConstantInitializer
 
@@ -20,6 +21,9 @@ class LayerHelper:
         self.layer_type = layer_type
         name = kwargs.get("name")
         self.name = name if name else unique_name.generate(layer_type)
+        # a layer the model named is a site: its ops carry the name as
+        # the last element of their `op_namescope`
+        self._site = name if name and valid_name_scope(name) else None
 
     @property
     def main_program(self):
@@ -34,8 +38,12 @@ class LayerHelper:
         return self.main_program.current_block()
 
     def append_op(self, type, inputs=None, outputs=None, attrs=None):
-        return self.block.append_op(type=type, inputs=inputs,
-                                    outputs=outputs, attrs=attrs)
+        if self._site is None:
+            return self.block.append_op(type=type, inputs=inputs,
+                                        outputs=outputs, attrs=attrs)
+        with name_scope(self._site):
+            return self.block.append_op(type=type, inputs=inputs,
+                                        outputs=outputs, attrs=attrs)
 
     # ------------------------------------------------------------------
     def create_parameter(self, attr, shape, dtype="float32",

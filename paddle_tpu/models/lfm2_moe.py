@@ -164,7 +164,7 @@ def build_program(cfg, seq_len):
                 else layers.elementwise_add(fullest, f)
         h = layers.elementwise_add(h, y)
     h = layers.rms_norm(h, cfg.norm_eps, name="final_norm")
-    logits = layers.matmul(h, table, transpose_y=True)
+    logits = layers.matmul(h, table, transpose_y=True, name="lm_head")
     # the mean over the tokens in float32 whatever the logits are run in
     loss = layers.mean(layers.cast(layers.softmax_with_cross_entropy(
         logits, layers.unsqueeze(labels, [2])), "float32"))

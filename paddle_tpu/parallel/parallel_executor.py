@@ -637,7 +637,8 @@ class ParallelExecutor:
                 self._cache[ckey] = fn
                 _tm.compiles.register_program(
                     f"executor:{program._version}", fn,
-                    (persist, feed_arrays, key))
+                    (persist, feed_arrays, key),
+                    program.name_scopes())
         elif tm_on:
             _tm.counter("pexe.cache_hit_count").inc()
 

@@ -1,12 +1,11 @@
-"""tpuscope attribution: runtime MFU / goodput / step-budget / recompiles.
+"""tpuscope attribution: runtime MFU / goodput / recompiles.
 
 The registry (PR 2) records *what happened* — step counts, wall-time
 histograms, spans. This layer answers *how well*: it captures each
 compile key's FLOPs once at compile time via XLA's own
 ``cost_analysis`` (the same source bench.py trusts for its offline MFU)
 and folds step wall-time into live ``perf.mfu`` and
-``perf.goodput.{examples,tokens}_per_s`` gauges, decomposes each step's
-time budget from the spans the executor already emits, and — when a new
+``perf.goodput.{examples,tokens}_per_s`` gauges, and — when a new
 compile key shows up mid-run — diffs it field-by-field against its
 nearest previously-seen neighbor to say exactly which component busted
 the cache (the dynamic counterpart of proglint's static
@@ -30,8 +29,7 @@ from . import spans as _spans
 
 __all__ = ["peak_flops", "instrument_compile", "on_step",
            "reset_window", "explain_recompile", "executor_ckey_fields",
-           "pexe_ckey_fields", "step_budget", "compile_info",
-           "BUDGET_CATEGORIES"]
+           "pexe_ckey_fields", "compile_info"]
 
 _LOG = logging.getLogger("paddle_tpu.telemetry.attribution")
 
@@ -299,49 +297,3 @@ def explain_recompile(kind, fields, seen_fields, step=None):
                  kind, step, ", ".join(components) or "nothing visible",
                  detail)
     return out
-
-
-# ------------------------------------------------------------ step budgets
-
-# span name -> budget category (the per-step time decomposition).
-# "device compute" lives inside dispatch on synchronous backends (the
-# donated CPU execution runs inline on the dispatching thread) and
-# inside stall under async_steps (the deferred block_until_ready).
-_BUDGET_SPANS = {
-    "executor.feed_put": "feed_put",
-    "executor.step": "dispatch",
-    "executor.pending_wait": "stall",
-    "executor.fetch_readback": "readback",
-    "executor.finite_check": "check",
-}
-BUDGET_CATEGORIES = ("feed_put", "dispatch", "stall", "readback",
-                     "check")
-
-
-def step_budget(spans=None):
-    """Roll the executor's spans up into a per-step time budget.
-    Grouping is by each span's own `step` arg, so deferred readbacks
-    and finite checks under async_steps land on the step that
-    DISPATCHED them, not the step whose run() call materialized them.
-    Returns {"steps": {step: {cat_ms}}, "totals": {cat_ms},
-    "compile_steps": [...]}."""
-    spans = _spans.iter_spans() if spans is None else spans
-    steps = {}
-    totals = {c: 0.0 for c in BUDGET_CATEGORIES}
-    compile_steps = []
-    for s in spans:
-        cat = _BUDGET_SPANS.get(s.name)
-        if cat is None:
-            continue
-        args = s.args or {}
-        step = args.get("step")
-        if step is None:
-            continue
-        ms = s.dur_us / 1e3
-        steps.setdefault(step, dict.fromkeys(BUDGET_CATEGORIES, 0.0))
-        steps[step][cat] += ms
-        totals[cat] += ms
-        if s.name == "executor.step" and args.get("compile_run"):
-            compile_steps.append(step)
-    return {"steps": steps, "totals": totals,
-            "compile_steps": sorted(compile_steps)}

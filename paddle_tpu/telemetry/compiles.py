@@ -23,14 +23,18 @@ of the executable that owner runs (`compiled_text(owner)`). A device
 trace names an op by its HLO instruction and this runtime's profile
 reader (`jax.profiler.ProfileData`) does not give out the instruction's
 `op_name`, so a reader joins the two through this text: instruction
-name -> `metadata={op_name=...}`, which carries `jax.named_scope`.
+name -> `metadata={op_name=...}`, which carries `jax.named_scope`: the
+Fluid op's type and, under it, the site it was declared at. A site is
+told from a primitive's name in that stack by the program's own list of
+them (`program_sites(owner)`).
 """
 import collections
 import contextlib
 import time
 
 __all__ = ["compile_log", "owned", "install", "EVENTS", "CompileRecord",
-           "NO_OWNER", "register_program", "compiled_text"]
+           "NO_OWNER", "register_program", "compiled_text",
+           "program_sites"]
 
 TRACE = "/jax/core/compile/jaxpr_trace_duration"
 LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
@@ -107,12 +111,13 @@ class owned:
 NO_OWNER = contextlib.nullcontext()
 
 
-_programs = collections.OrderedDict()    # owner -> callable giving HLO text
+_programs = collections.OrderedDict()    # owner -> (HLO text's maker, sites)
 _MAX_PROGRAMS = 16
 
 
-def register_program(owner_name, jitted, args):
-    """Remember how to get the HLO text of what `jitted(*args)` runs.
+def register_program(owner_name, jitted, args, sites=()):
+    """Remember how to get the HLO text of what `jitted(*args)` runs, and
+    the `op_namescope`s its ops carry (`Program.name_scopes()`).
     Nothing is lowered or rendered here: only the arguments' shapes,
     dtypes and shardings are kept (no array), and `jitted` itself. The
     newest program of an owner replaces the one before; the oldest
@@ -125,7 +130,8 @@ def register_program(owner_name, jitted, args):
 
     avals = jax.tree_util.tree_map(aval, args)
     _programs.pop(owner_name, None)
-    _programs[owner_name] = lambda: jitted.lower(*avals).compile().as_text()
+    _programs[owner_name] = (
+        lambda: jitted.lower(*avals).compile().as_text(), frozenset(sites))
     while len(_programs) > _MAX_PROGRAMS:
         _programs.popitem(last=False)
 
@@ -135,8 +141,15 @@ def compiled_text(owner_name):
     text, or None. While JAX still holds the executable this costs the
     rendering only; after `jax.clear_caches()` it lowers again and
     loads the executable from the persistent cache."""
-    text = _programs.get(owner_name)
-    return text() if text is not None else None
+    entry = _programs.get(owner_name)
+    return entry[0]() if entry is not None else None
+
+
+def program_sites(owner_name):
+    """The name scopes (`op_namescope`) of `owner_name`'s newest
+    program's ops: the elements of a name stack that are sites."""
+    entry = _programs.get(owner_name)
+    return entry[1] if entry is not None else frozenset()
 
 
 def compile_log():

@@ -1,8 +1,8 @@
 """tpuscope — runtime performance attribution (telemetry/attribution.py,
 telemetry/slo.py) and its surfaces: histogram quantiles, the MFU /
-goodput gauges (pinned against bench.py's offline formula), step-time
-budgets with deferred-readback attribution under async_steps, the
-recompile explainer, the declarative SLO engine, the BENCH_history
+goodput gauges (pinned against bench.py's offline formula), the step a
+deferred read-back counts to under async_steps (the executor's tags and
+`fluid.profiler`'s per-step rows), the recompile explainer, the declarative SLO engine, the BENCH_history
 regression gate, per-request serving correlation ids, and the
 `tpustat --slo --selftest` CI wiring.
 """
@@ -337,31 +337,14 @@ def test_explainer_picks_nearest_neighbor():
     assert attr.explain_recompile("executor", new, []) is None
 
 
-# ------------------------------------------------------- step budgets
+# ------------------------------------- the step a deferred span counts to
 
-def test_step_budget_sync():
-    loss = _tiny_train_program()
-    exe = pt.Executor(pt.CPUPlace())
-    exe.run(pt.default_startup_program())
-    tm.enable()
-    tm.reset()
-    for _ in range(4):
-        exe.run(feed=_feed(8), fetch_list=[loss])
-    budget = attr.step_budget()
-    # training steps are 1..4 (startup ran off-clock as step 0)
-    assert set(budget["steps"]) == {1, 2, 3, 4}
-    assert budget["compile_steps"] == [1]
-    for step, cats in budget["steps"].items():
-        assert cats["dispatch"] > 0
-        assert cats["readback"] >= 0
-    assert budget["totals"]["dispatch"] > 0
-    assert budget["totals"]["feed_put"] > 0
-
-
-def test_step_budget_attributes_deferred_readback_async():
-    """async_steps=k: the pending_wait/fetch_readback spans a later
-    run() materializes must land on the step that DISPATCHED the work
-    (the budget groups by each span's own step arg, not wall order)."""
+def test_the_executor_tags_deferred_spans_with_the_step_that_dispatched_them():
+    """async_steps=k: the pending_wait / fetch_readback spans a later
+    run() materializes carry the step that DISPATCHED the work, which is
+    what `profiler.step_of` counts them by (here from the span ring; under
+    a session the same spans are the trace's `pt/` events)."""
+    from paddle_tpu import profiler
     feeds = [_feed(8, seed=i) for i in range(6)]
     main_p, startup_p = pt.Program(), pt.Program()
     with pt.program_guard(main_p, startup_p):
@@ -393,10 +376,53 @@ def test_step_budget_attributes_deferred_readback_async():
                for s in waits + readbacks
                if s.args["step"] + 1 in dispatch), \
         "no span materialized after a later step's dispatch"
-    budget = attr.step_budget(spans)
-    assert set(budget["steps"]) == set(dispatch)
-    assert budget["totals"]["stall"] > 0
-    assert budget["totals"]["readback"] > 0
+    # the report's per-step rows, from these very spans
+    rows = {r["step"]: r for r in profiler.step_rows(
+        [(s.name, 1e-6 * s.ts_us, 1e-6 * s.dur_us, s.tid, s.args or {})
+         for s in spans if s.cat != "counter"])}
+    assert set(rows) == set(dispatch)
+    for r in rows.values():
+        assert r["self_ms"]["executor.step"] > 0
+        assert r["self_ms"]["executor.fetch_readback"] > 0
+    # what ran inside a later step's run is counted to its own step
+    assert sum(r["deferred_ms"] for r in rows.values()) > 0
+
+
+def test_an_async_read_back_counts_to_the_step_that_dispatched_it():
+    from paddle_tpu import profiler
+
+    def _span(name, start, end, **stats):
+        return (name, start, end - start, "main", stats)
+
+    spans = [
+        _span("executor.run", 0.000, 0.010, program=7, step=5),
+        _span("executor.step", 0.001, 0.002, step=5),
+        # inside step 5's run, the deferred work of step 3
+        _span("executor.pending_wait", 0.002, 0.006, step=3),
+        _span("executor.fetch_readback", 0.006, 0.009, step=3),
+        _span("executor.feed_put", 0.0095, 0.0100),
+    ]
+    parent = profiler.parents_of(spans)
+    assert profiler.step_of(spans, parent) == [5, 5, 3, 3, 5]
+    rows = {r["step"]: r for r in profiler.step_rows(spans)}
+    assert rows[3]["self_ms"] == pytest.approx(
+        {"executor.pending_wait": 4.0, "executor.fetch_readback": 3.0})
+    assert rows[3]["deferred_ms"] == pytest.approx(7.0)
+    assert rows[5]["self_ms"] == pytest.approx(
+        {"executor.run": 1.5, "executor.step": 1.0,
+         "executor.feed_put": 0.5})
+    assert rows[5]["deferred_ms"] == 0.0 and not rows[5]["compile_run"]
+    spans[0][4]["compile_run"] = True
+    assert profiler.step_rows(spans)[1]["compile_run"]
+    # the report says so, in one line under the host part
+    text = profiler.render({
+        "header": dict.fromkeys(
+            ("program", "busy_share", "peak_bytes", "compiled", "xplane"),
+            None) | {"session_s": 0.01, "steps": 1, "compile_steps": [],
+                     "sorted_key": "total", "scoped": False},
+        "device": None, "host": profiler.host_rows(spans, []),
+        "steps": profiler.step_rows(spans)})
+    assert "Deferred work (async_steps): 7.000 ms of steps 3..3" in text
 
 
 # ------------------------------------------------- bench history spine

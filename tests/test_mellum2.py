@@ -166,7 +166,10 @@ def test_the_sigmoid_routers_op_is_as_it_was():
         x = layers.data("x", shape=[2, 8, 64], append_batch_size=False)
         layers.moe_route(x, 8, 2, name="r")
     op = [o for o in main.global_block().ops if o.type == "moe_route"][0]
-    assert set(op.attrs) == {"k", "norm_topk_prob", "routed_scaling_factor"}
+    # ... and the site its `name=` declares (PR 38), which no kernel sees
+    assert set(op.attrs) == {"k", "norm_topk_prob", "routed_scaling_factor",
+                             "op_namescope"}
+    assert op.attrs["op_namescope"] == "r"
     assert "Bias" in op.inputs
 
 

@@ -331,17 +331,23 @@ def test_inference_engine_latency_metrics():
 
 # ---------------------------------------------------------------- profiler
 
-def test_record_event_routes_through_telemetry():
+def test_record_event_routes_through_telemetry(tmp_path, capsys):
     from paddle_tpu import profiler
     profiler.reset_profiler()
+    assert profiler.summary() == []
     tm.enable()
-    with profiler.record_event("my_region"):
-        pass
+    with profiler.profiler("All", "total", log_dir=str(tmp_path)):
+        with profiler.record_event("my_region"):
+            pass
     spans = [s for s in tm.iter_spans() if s.name == "my_region"]
     assert len(spans) == 1 and spans[0].cat == "profiler"
-    assert tm.snapshot()["profiler.event_seconds"]["count"] == 1
-    # the legacy host-side record table still fills in parallel
-    assert "my_region" in profiler.summary()
+    # a region is a span and nothing else: no metric, no second table
+    assert "profiler.event_seconds" not in tm.snapshot()
+    assert not hasattr(profiler, "_records")
+    # summary() is the host part of the session's report, as rows
+    row, = [r for r in profiler.summary() if r["span"] == "my_region"]
+    assert row["calls"] == 1 and 0 <= row["self_ms"] <= row["total_ms"]
+    assert "my_region" in capsys.readouterr().out
 
 
 def test_device_memory_degrades_on_cpu():

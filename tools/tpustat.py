@@ -897,9 +897,10 @@ def main(argv=None):
     p.add_argument("--prom", action="store_true",
                    help="also print the Prometheus text exposition")
     p.add_argument("--profile-device", action="store_true",
-                   help="run a short device trace and merge per-op "
-                        "device times onto the timeline (needs a "
-                        "backend whose xplane layout we can decode)")
+                   help="run three more steps under a fluid.profiler "
+                        "session and print its report: device time by "
+                        "Fluid op and name scope, host spans with self "
+                        "time and the idle time under each")
     p.add_argument("--fleet", nargs="?", const="", default=None,
                    metavar="SPOOL_DIR",
                    help="fleet mode: merge a telemetry.fleet rank "
@@ -987,22 +988,21 @@ def main(argv=None):
             out = exe.run(main_p, feed=feed, fetch_list=[loss])
             losses.append(float(np.asarray(out[0]).ravel()[0]))
 
+    snap = telemetry.snapshot()   # the loop's: the profile's steps come after
     device_profile = None
     if args.profile_device:
+        import contextlib
         from paddle_tpu import profiler
         feed = feed_fn(args.batch_size, rng)
-        try:
-            per_step, ops = profiler.profile_step_fn(
-                lambda: exe.run(main_p, feed=feed, fetch_list=[loss]),
-                steps=3)
-            device_profile = {"device_step_seconds": per_step,
-                              "top_ops": dict(sorted(
-                                  ops.items(),
-                                  key=lambda kv: -kv[1])[:10])}
-        except Exception as e:
-            device_profile = {"error": f"{type(e).__name__}: {e}"}
+        # three more steps under a profiler session: its report (device
+        # time by Fluid op and name scope, host spans) is the profile
+        with contextlib.redirect_stdout(sys.stderr if args.as_json
+                                        else sys.stdout):
+            with profiler.profiler("All", "total"):
+                for _ in range(3):
+                    exe.run(main_p, feed=feed, fetch_list=[loss])
+        device_profile = {"host": profiler.summary()}
 
-    snap = telemetry.snapshot()
     problems = validate_metrics(snap, args.steps)
 
     trace_path = args.trace or f"/tmp/tpustat_{args.model}.trace.json"
@@ -1077,8 +1077,6 @@ def main(argv=None):
         for name in sorted(snap):
             print(f"  {name:<{width}}  {_fmt_value(snap[name])}")
         print(f"trace: {trace_path} ({span_events} span events)")
-        if device_profile:
-            print(f"device profile: {device_profile}")
         for prob in problems:
             print(f"MALFORMED: {prob}", file=sys.stderr)
     if args.prom:
