@@ -1422,6 +1422,26 @@ def flash_attention(q, k, v, attn_bias=None, causal=False, scale=None,
     return out
 
 
+def _packed_flash_attention(q, kv, packed, n_head, attn_bias, causal, name):
+    """The flash_attention op over a fused projection as it is: `packed`
+    "qkv": q is kv, one [B, T, 3*H*Dh] (q, k, v side by side); "kv": q
+    [B, T, H*Dh] and kv [B, S, 2*H*Dh]. -> [B, T, H*Dh]."""
+    helper = LayerHelper("multi_head_attention", name=name)
+    width = int(kv.shape[2]) // (3 if packed == "qkv" else 2)
+    out = helper.create_variable_for_type_inference(
+        q.dtype, (q.shape[0], q.shape[1], width))
+    wvar = helper.create_variable_for_type_inference(
+        q.dtype, (q.shape[0], n_head, q.shape[1], kv.shape[1]), True)
+    ins = {"QKV": [q]} if packed == "qkv" else {"Q": [q], "KV": [kv]}
+    if attn_bias is not None:
+        ins["Mask"] = [attn_bias]
+    helper.append_op("flash_attention", ins,
+                     {"Out": [out], "Weights": [wvar]},
+                     {"causal": causal, "scale": (width // n_head) ** -0.5,
+                      "layout": "bthd", "packed": packed, "n_head": n_head})
+    return out
+
+
 def multi_head_attention(queries, keys, values, attn_bias=None, d_key=64,
                          d_value=64, d_model=512, n_head=8, dropout_rate=0.0,
                          causal=False, param_attr=None, name=None,
@@ -1434,11 +1454,18 @@ def multi_head_attention(queries, keys, values, attn_bias=None, d_key=64,
     [d_model, d_key*H + d_value*H] k/v projection when keys is values
     — the cross-attention case): bigger MXU tiles, fewer fusion
     boundaries than three separate [d_model, d_head*H] matmuls.
+    With use_flash the projection's output goes to the flash_attention
+    op as it is, unsplit (`packed` "qkv": one [B, T, 3*H*Dh] input;
+    "kv": q and one [B, S, 2*H*Dh]); the short kernel reads q, k and v
+    out of it in place and writes its gradient the same way, so no
+    `split` and no pad-and-add of the gradient is left around it.
     Parameter NAMES differ from the unfused layout (one
     `..._qkv`/`..._kv` weight), so checkpoints are not interchangeable
-    between the two layouts — therefore OPT-IN (default off keeps every
-    existing model's names and checkpoints stable); the perf paths
-    (bench.py, chip_smoke.py) opt in with fused_qkv=True."""
+    between the two layouts (models/transformer.py's
+    convert_qkv_checkpoint converts) — therefore OPT-IN (default off
+    keeps every existing model's names and checkpoints stable); the
+    perf paths (bench.py, chip_smoke.py, the benchmark's nmt cell) opt
+    in with fused_qkv=True."""
     from . import tensor as _t
     if fused_qkv is None:
         fused_qkv = False
@@ -1446,12 +1473,16 @@ def multi_head_attention(queries, keys, values, attn_bias=None, d_key=64,
         raise ValueError(
             "fused_qkv shares one weight across q/k/v and cannot honor "
             "an explicit param_attr naming; pass fused_qkv=False")
+    packed = None
     if fused_qkv and d_key == d_value and queries is keys \
             and keys is values:
         qkv = fc(queries, 3 * d_key * n_head, num_flatten_dims=2,
                  param_attr=param_attr, bias_attr=False,
                  name=f"{name}_qkv" if name else None)
-        q, k, v = split(qkv, 3, dim=2)
+        if use_flash:
+            packed, q, kv = "qkv", qkv, qkv
+        else:
+            q, k, v = split(qkv, 3, dim=2)
     elif fused_qkv and d_key == d_value and keys is values:
         q = fc(queries, d_key * n_head, num_flatten_dims=2,
                param_attr=param_attr, bias_attr=False,
@@ -1459,7 +1490,10 @@ def multi_head_attention(queries, keys, values, attn_bias=None, d_key=64,
         kv = fc(keys, 2 * d_key * n_head, num_flatten_dims=2,
                 param_attr=param_attr, bias_attr=False,
                 name=f"{name}_kv" if name else None)
-        k, v = split(kv, 2, dim=2)
+        if use_flash:
+            packed = "kv"
+        else:
+            k, v = split(kv, 2, dim=2)
     else:
         if fused_qkv:
             import warnings
@@ -1483,16 +1517,21 @@ def multi_head_attention(queries, keys, values, attn_bias=None, d_key=64,
                param_attr=param_attr, bias_attr=False,
                name=f"{name}_v" if name else None)
 
-    # heads stay in [B, T, H, Dh] layout end-to-end: the reshape is free
-    # and the attention dots contract with H as a batch dim, so no head
-    # split/merge transposes ever materialize (profiled ~1.4 ms/step of
-    # copies in the bhtd->bhtd layout on the transformer bench)
-    q = reshape(q, [0, 0, n_head, d_key])
-    k = reshape(k, [0, 0, n_head, d_key])
-    v = reshape(v, [0, 0, n_head, d_value])
-    out = flash_attention(q, k, v, attn_bias=attn_bias, causal=causal,
-                          use_flash=use_flash, name=name)
-    out = reshape(out, [0, 0, n_head * d_value])
+    if packed is not None:
+        out = _packed_flash_attention(q, kv, packed, n_head, attn_bias,
+                                      causal, name)
+    else:
+        # heads stay in [B, T, H, Dh] layout end-to-end: the reshape is
+        # free and the attention dots contract with H as a batch dim, so
+        # no head split/merge transposes ever materialize (profiled ~1.4
+        # ms/step of copies in the bhtd->bhtd layout on the transformer
+        # bench)
+        q = reshape(q, [0, 0, n_head, d_key])
+        k = reshape(k, [0, 0, n_head, d_key])
+        v = reshape(v, [0, 0, n_head, d_value])
+        out = flash_attention(q, k, v, attn_bias=attn_bias, causal=causal,
+                              use_flash=use_flash, name=name)
+        out = reshape(out, [0, 0, n_head * d_value])
     if dropout_rate:
         out = dropout(out, dropout_rate,
                       dropout_implementation="upscale_in_train")

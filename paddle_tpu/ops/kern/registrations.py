@@ -94,10 +94,19 @@ def _flash_bhtd(q, k, v, layout="bhtd"):
 
 def _flash_probe(q, k, v, bias=None, causal=False, scale=None,
                  with_lse=False, causal_offset=0, *, interpret=False,
-                 layout="bhtd", window=None, **kw):
+                 layout="bhtd", window=None, packed=None, n_heads=None,
+                 **kw):
     """try_flash's own tests, less the backend gate: the short kernel's
     pick, then the tiled kernel's gate and shapes (a window is the tiled
-    kernel's alone, on a causal, unshifted diagonal, without the lse)."""
+    kernel's alone, on a causal, unshifted diagonal, without the lse); a
+    packed call's segments where the short kernel does not take them
+    whole."""
+    if packed is not None:
+        if fa.picks_packed(q, k, v, packed, n_heads, bias, interpret,
+                           window):
+            return True
+        q, k, v = fa.packed_segments(q, k, v, packed, n_heads)
+        layout = "bthd"
     if getattr(q, "ndim", 0) != 4:
         return False
     if window is not None:

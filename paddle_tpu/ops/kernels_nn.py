@@ -933,13 +933,31 @@ def _flash_attention(ctx, ins, attrs):
     long-sequence kernel still wants bhtd; try_flash transposes for it
     where it picks it.
 
+    packed attr (with n_head; multi_head_attention's fused projections,
+    always `bthd`): "qkv" takes one input QKV [B, T, 3*H*D], q, k and v
+    side by side in its lanes as the projection's matmul wrote them;
+    "kv" takes Q [B, T, H*D] and KV [B, S, 2*H*D]. Out is then
+    [B, T, H*D]. Where the short kernel takes the segments it reads
+    them in place and writes the projection's gradient packed; anywhere
+    else they are sliced here, and the tiled kernel or the composition
+    sees the arrays it sees unpacked.
+
     window attr (with causal): query t sees the `window` keys
     `t - window < s <= t` only. None (the default) compiles out of every
     kernel, as a missing Mask does."""
-    q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
+    packed = attrs.get("packed")
+    if packed == "qkv":
+        q = k = v = ins["QKV"][0]
+    elif packed == "kv":
+        q, k = ins["Q"][0], ins["KV"][0]
+        v = k
+    else:
+        q, k, v = ins["Q"][0], ins["K"][0], ins["V"][0]
     mask = _opt(ins, "Mask")
     causal = attrs.get("causal", False)
-    scale = attrs.get("scale", None) or (1.0 / np.sqrt(q.shape[-1]))
+    d = q.shape[-1] if packed is None else \
+        q.shape[-1] // (3 if packed == "qkv" else 1) // attrs["n_head"]
+    scale = attrs.get("scale", None) or (1.0 / np.sqrt(d))
     # The one dispatch policy (which kernel, from the shapes, the layout
     # and the dtype; None = the composition) lives in try_flash, reached
     # through the kern registry seam: explicit gating, no silent
@@ -948,12 +966,21 @@ def _flash_attention(ctx, ins, attrs):
     if fused is not None:
         out = fused(q, k, v, bias=mask, causal=causal, scale=scale,
                     layout=attrs.get("layout", "bhtd"),
-                    window=attrs.get("window"))
+                    window=attrs.get("window"),
+                    **({} if packed is None else
+                       {"packed": packed, "n_heads": attrs["n_head"]}))
         if out is not None:
             return {"Out": [out], "Weights": [jnp.zeros((0,), q.dtype)]}
     # no kernel wins at this shape (the measured table is in PERF.md
     # section 6, PR 28), or none can lower here: one composition, in _sdpa
-    return _sdpa(ctx, ins, attrs)
+    if packed is None:
+        return _sdpa(ctx, ins, attrs)
+    from .pallas.flash_attention import unpack
+    q, k, v = unpack(q, k, v, packed, attrs["n_head"])
+    got = _sdpa(ctx, {"Q": [q], "K": [k], "V": [v],
+                      "Mask": [mask] if mask is not None else []}, attrs)
+    return {"Out": [got["Out"][0].reshape(q.shape[:2] + (-1,))],
+            "Weights": got["Weights"]}
 
 
 # ---------------------------------------------------------------------------

@@ -48,7 +48,13 @@ _sdpa does; the scores themselves stay float32 (_sdpa rounds them to
 bf16), so it is at least as exact as the composition it replaces. At the
 transformer-base training shape ([128, 256, 8 x 64] bf16, v5e) it takes
 1.06 ms forward + backward against the composition's 2.96 and the tiled
-kernel's 4.90 (PERF.md section 6, PR 28).
+kernel's 4.90 (PERF.md section 6, PR 28). It also reads q, k and v out
+of a fused projection as the projection's matmul wrote it
+(flash_attention_packed: one [B, T, 3*H*D], or q and one [B, S, 2*H*D];
+a block's index map picks the 128-lane-aligned segment) and writes the
+gradient of such an array as one array of its shape, so the model's
+`split` and its transpose (a zero-filled pad and update per slice) leave
+the step (PR 40).
 
 Supported extras (covers the flagship transformer end-to-end):
 - `bias`: additive key-padding bias of shape [B, S] (the [B,1,1,S]
@@ -152,7 +158,8 @@ __all__ = ["flash_attention", "flash_attention_with_lse",
            "set_softmax_dtype", "active", "MIN_SEQ_LEN",
            "MIN_SEQ_LEN_BTHD", "SHORT_MIN_SEQ_LEN", "SHORT_MAX_SEQ_LEN",
            "flash_attention_bthd", "supports_short", "picks_short",
-           "tiled_min_len"]
+           "tiled_min_len", "flash_attention_packed", "picks_packed",
+           "packed_segments", "unpack"]
 
 _NEG_INF = -1e30
 
@@ -200,7 +207,11 @@ STATS = {"pallas_calls": 0,
          "tiled_bwd_fused": 0, "tiled_bwd_split": 0,
          # backwards traced with a window (a band of blocks, kernels
          # named flash_attention_win_*)
-         "tiled_window": 0}
+         "tiled_window": 0,
+         # short-kernel calls that read q, k, v from the fused
+         # projection's [B, T, 3*H*D] (or q and a [B, S, 2*H*D] kv)
+         # and write its gradient packed the same way
+         "short_packed": 0}
 
 # m/l scratch rows are stored lane-replicated at this width (1-lane
 # vectors are not a legal VMEM tile).
@@ -1006,7 +1017,8 @@ def flash_attention_with_lse(q, k, v, bias=None, causal=False, scale=None,
 
 # ---------------------------------------------------------------------------
 # short sequences, the op's own [B, T, H*D] layout: one tile, no online
-# softmax, one backward kernel
+# softmax, one backward kernel; q, k, v apart or packed as the fused
+# projection writes them
 # ---------------------------------------------------------------------------
 # The whole key axis is one block and the [tq, S] scores of one head live
 # in VMEM only. A block is over [B, T, H*D] (lane-dense: no 64-wide minor
@@ -1015,7 +1027,11 @@ def flash_attention_with_lse(q, k, v, bias=None, causal=False, scale=None,
 # inside its group is picked by a lane mask on ONE operand of each
 # product (the contraction then runs over 128 lanes, the other head's
 # contributing zeros) and by a lane select on the [*, 128] result, so no
-# 64-lane slice, shift or concat is ever made.
+# 64-lane slice, shift or concat is ever made. Where q, k and v come
+# packed (_PACKS) the block is the H*D-lane segment of the packed array
+# that the index map picks, and the backward's block of a packed
+# gradient is the whole row, dq, dk and dv stored into their segments
+# (_LaneSegment): the bodies are the same, XLA slices nothing.
 _SHORT_FWD_ROWS = 256         # rows of q per scores tile, forward
 _SHORT_BWD_ROWS = 256         # rows of q and of k per tile, backward
 _SHORT_BWD_KEYS = 256
@@ -1025,6 +1041,21 @@ _SHORT_BWD_KEYS = 256
 # ask for what they need, up to _SHORT_VMEM_MAX.
 _SHORT_VMEM_FREE = 12 * 1024 * 1024
 _SHORT_VMEM_MAX = 96 * 1024 * 1024
+# Where q, k and v lie in the arrays a short call is handed, for each the
+# array (its place among the distinct ones) and the lane segment, in units
+# of H*D. None: three arrays. "qkv": one [B, T, 3*H*D], the fused
+# self-attention projection as it comes out of its matmul, handed in three
+# times. "kv": q and one [B, S, 2*H*D], the fused cross-attention
+# projection. A block's index map picks the segment, so XLA slices
+# nothing; the backward writes one gradient an array, each of dq, dk, dv
+# into its segment, so XLA pads and adds nothing either.
+_PACKS = {None: ((0, 0), (1, 0), (2, 0)),
+          "kv": ((0, 0), (1, 0), (1, 1)),
+          "qkv": ((0, 0), (0, 1), (0, 2))}
+
+
+def _n_arrays(packed):
+    return _PACKS[packed][-1][0] + 1
 
 
 def _short_group(H, D):
@@ -1119,16 +1150,35 @@ def _short_fwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref, *,
         lse_ref[...] = rows[:H]
 
 
+class _LaneSegment:
+    """Lanes [off, off + H*D) of a packed gradient's block, stored into
+    as if they were a block of their own."""
+
+    def __init__(self, ref, off):
+        self.ref, self.off, self.dtype = ref, off, ref.dtype
+
+    def __setitem__(self, idx, val):
+        rows, lanes = idx
+        self.ref[rows, self.off + lanes.start:self.off + lanes.stop] = val
+
+
 def _short_bwd_kernel(q_ref, k_ref, v_ref, b_ref, o_ref, do_ref, lse_ref,
-                      dq_ref, dk_ref, dv_ref, db_ref=None, *, causal, scale,
-                      offset, d, tq, tk, has_bias):
+                      *out_refs, causal, scale, offset, d, tq, tk, has_bias,
+                      packed):
     """dq, dk, dv from ONE recomputation of p, in [tq, tk] tiles (given
     lse and delta the backward is pointwise in the scores, so keys tile
     too). Blocks as the forward's; delta = rowsum(dO * O) is taken here,
-    per head, from the lanes the head owns. db_ref [1, S] (float32) is
-    there only where the bias itself is being differentiated: the column
-    sums of ds over the batch row's heads and queries."""
+    per head, from the lanes the head owns. out_refs: one gradient block
+    per array the call was handed (_PACKS[packed]: dq, dk and dv land in
+    their lane segments of it), then db [1, S] (float32) only where the
+    bias itself is being differentiated: the column sums of ds over the
+    batch row's heads and queries."""
     T, HD = q_ref.shape
+    dq_ref, dk_ref, dv_ref = (
+        out_refs[a] if not s else _LaneSegment(out_refs[a], s * HD)
+        for a, s in _PACKS[packed])
+    db_ref = out_refs[_n_arrays(packed)] \
+        if len(out_refs) > _n_arrays(packed) else None
     S = k_ref.shape[0]
     W = _short_group(HD // d, d)
     per = W // d
@@ -1224,13 +1274,33 @@ def _row_spec(*shape):
     return pl.BlockSpec((None,) + shape, lambda i: (i,) + (0,) * len(shape))
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
-def _short_fwd_call(q, k, v, bias, n_heads, causal, scale, interpret,
+def _width(arrays, packed):
+    """H*D: the lanes of one of q, k, v in the arrays a call is handed."""
+    return arrays[0].shape[-1] // sum(a == 0 for a, _ in _PACKS[packed])
+
+
+def _qkv_specs(arrays, packed, HD):
+    """q, k, v as the arrays hold them (_PACKS), and the specs of their
+    blocks: one batch row's [L, H*D] lane segment, picked by the index
+    map."""
+    ops, specs = [], []
+    for a, seg in _PACKS[packed]:
+        ops.append(arrays[a])
+        specs.append(pl.BlockSpec((None, arrays[a].shape[1], HD),
+                                  lambda i, seg=seg: (i, 0, seg)))
+    return ops, specs
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
+def _short_fwd_call(arrays, bias, packed, n_heads, causal, scale, interpret,
                     has_bias):
-    """q [B, T, H*D]; k/v [B, S, H*D]; bias [B, 1, S] float32. Returns
+    """arrays: q, k, v as _PACKS[packed] lays them out (q [B, T, H*D], k/v
+    [B, S, H*D] where packed is None); bias [B, 1, S] float32. Returns
     (out [B, T, H*D], lse [B, H, T]). Jitted so that a model's many
     attentions of one shape trace and lower the unrolled body once."""
-    B, T, HD = q.shape
+    HD = _width(arrays, packed)
+    (q, k, v), specs = _qkv_specs(arrays, packed, HD)
+    B, T = q.shape[:2]
     S = k.shape[1]
     return pl.pallas_call(
         functools.partial(_short_fwd_kernel, causal=causal, scale=scale,
@@ -1238,8 +1308,7 @@ def _short_fwd_call(q, k, v, bias, n_heads, causal, scale, interpret,
                           tq=_chunk(T, _SHORT_FWD_ROWS),
                           has_bias=has_bias),
         grid=(B,),
-        in_specs=[_row_spec(T, HD), _row_spec(S, HD), _row_spec(S, HD),
-                  _row_spec(1, S)],
+        in_specs=specs + [_row_spec(1, S)],
         out_specs=[_row_spec(T, HD), _row_spec(n_heads, T)],
         out_shape=[jax.ShapeDtypeStruct((B, T, HD), q.dtype),
                    jax.ShapeDtypeStruct((B, n_heads, T), jnp.float32)],
@@ -1249,18 +1318,21 @@ def _short_fwd_call(q, k, v, bias, n_heads, causal, scale, interpret,
     )(q, k, v, bias)
 
 
-@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
-def _short_bwd_call(res, g, n_heads, causal, scale, interpret, has_bias):
-    """(dq, dk, dv, db): db [B, 1, S] float32 where the residuals say the
-    bias was being differentiated (`want_db` is not None), else None."""
-    q, k, v, bias, out, lse, want_db = res
-    B, T, HD = q.shape
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6, 7))
+def _short_bwd_call(res, g, packed, n_heads, causal, scale, interpret,
+                    has_bias):
+    """One gradient an array the call was handed, of its shape (a packed
+    array's is packed the same way), then db: [B, 1, S] float32 where the
+    residuals say the bias was being differentiated (`want_db` is not
+    None), else None."""
+    arrays, bias, out, lse, want_db = res
+    HD = _width(arrays, packed)
+    (q, k, v), specs = _qkv_specs(arrays, packed, HD)
+    B, T = q.shape[:2]
     S = k.shape[1]
-    t, s = _row_spec(T, HD), _row_spec(S, HD)
-    out_specs = [t, s, s]
-    out_shape = [jax.ShapeDtypeStruct((B, T, HD), q.dtype),
-                 jax.ShapeDtypeStruct((B, S, HD), k.dtype),
-                 jax.ShapeDtypeStruct((B, S, HD), v.dtype)]
+    t = _row_spec(T, HD)
+    out_specs = [_row_spec(*x.shape[1:]) for x in arrays]
+    out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in arrays]
     if want_db is not None:
         out_specs.append(_row_spec(1, S))
         out_shape.append(jax.ShapeDtypeStruct((B, 1, S), jnp.float32))
@@ -1269,9 +1341,9 @@ def _short_bwd_call(res, g, n_heads, causal, scale, interpret, has_bias):
                           offset=S - T, d=HD // n_heads,
                           tq=_chunk(T, _SHORT_BWD_ROWS),
                           tk=_chunk(S, _SHORT_BWD_KEYS),
-                          has_bias=has_bias),
+                          has_bias=has_bias, packed=packed),
         grid=(B,),
-        in_specs=[t, s, s, _row_spec(1, S), t, t, _row_spec(n_heads, T)],
+        in_specs=specs + [_row_spec(1, S), t, t, _row_spec(n_heads, T)],
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=_short_params(T, S, HD, q.dtype.itemsize),
@@ -1281,31 +1353,34 @@ def _short_bwd_call(res, g, n_heads, causal, scale, interpret, has_bias):
     return tuple(outs) if want_db is not None else tuple(outs) + (None,)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_short(q, k, v, bias, n_heads, causal, scale, interpret,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6, 7))
+def _flash_short(arrays, bias, packed, n_heads, causal, scale, interpret,
                  has_bias):
-    return _short_fwd_call(q, k, v, bias, n_heads, causal, scale,
+    return _short_fwd_call(arrays, bias, packed, n_heads, causal, scale,
                            interpret, has_bias)[0]
 
 
-def _flash_short_fwd(q, k, v, bias, n_heads, causal, scale, interpret,
+def _flash_short_fwd(arrays, bias, packed, n_heads, causal, scale, interpret,
                      has_bias):
     # symbolic_zeros: each primal says whether it is being differentiated.
     # The model's bias comes from integer lengths and is not; only a
     # learnable bias pays for the db output.
     want_db = jnp.zeros((0,)) if has_bias and bias.perturbed else None
-    q, k, v, bias = q.value, k.value, v.value, bias.value
-    out, lse = _short_fwd_call(q, k, v, bias, n_heads, causal, scale,
+    arrays, bias = tuple(x.value for x in arrays), bias.value
+    out, lse = _short_fwd_call(arrays, bias, packed, n_heads, causal, scale,
                                interpret, has_bias)
-    return out, (q, k, v, bias, out, lse, want_db)
+    # the residual is the arrays as handed in: a packed projection is
+    # kept whole (it is alive anyway), never as three slices
+    return out, (arrays, bias, out, lse, want_db)
 
 
-def _flash_short_bwd(n_heads, causal, scale, interpret, has_bias, res, g):
+def _flash_short_bwd(packed, n_heads, causal, scale, interpret, has_bias, res,
+                     g):
     if isinstance(g, jax.custom_derivatives.SymbolicZero):
         g = jnp.zeros(g.shape, g.dtype)
-    dq, dk, dv, db = _short_bwd_call(res, g, n_heads, causal, scale,
-                                     interpret, has_bias)
-    return dq, dk, dv, jnp.zeros_like(res[3]) if db is None else db
+    *grads, db = _short_bwd_call(res, g, packed, n_heads, causal, scale,
+                                 interpret, has_bias)
+    return tuple(grads), jnp.zeros_like(res[1]) if db is None else db
 
 
 _flash_short.defvjp(_flash_short_fwd, _flash_short_bwd, symbolic_zeros=True)
@@ -1363,11 +1438,65 @@ def flash_attention_bthd(q, k, v, bias=None, causal=False, scale=None,
     B, T, H, D = q.shape
     S = k.shape[1]
     scale = float(scale) if scale is not None else D ** -0.5
-    out = _flash_short(q.reshape(B, T, H * D), k.reshape(B, S, H * D),
-                       v.reshape(B, S, H * D), _bias_rows(bias, B, S), H,
-                       bool(causal), scale, bool(interpret),
+    out = _flash_short((q.reshape(B, T, H * D), k.reshape(B, S, H * D),
+                        v.reshape(B, S, H * D)), _bias_rows(bias, B, S),
+                       None, H, bool(causal), scale, bool(interpret),
                        bias is not None)
     return out.reshape(B, T, H, D)
+
+
+def packed_segments(q, k, v, packed, n_heads):
+    """Shape structs of q, k, v [B, L, H, D] as `unpack` would slice them
+    out of the arrays that hold them (_PACKS[packed])."""
+    arrays = (q, k, v)
+    HD = _width(arrays, packed)
+    return tuple(jax.ShapeDtypeStruct(
+        tuple(arrays[a].shape[:2]) + (n_heads, HD // n_heads),
+        arrays[a].dtype) for a, _ in _PACKS[packed])
+
+
+def unpack(q, k, v, packed, n_heads):
+    """q, k, v [B, L, H, D] sliced out of the arrays that hold them: what
+    the tiled kernel and the composition take where the short kernel
+    does not take a packed call."""
+    arrays = (q, k, v)
+    HD = _width(arrays, packed)
+    return tuple(
+        arrays[a][:, :, s * HD:(s + 1) * HD].reshape(
+            arrays[a].shape[:2] + (n_heads, HD // n_heads))
+        for a, s in _PACKS[packed])
+
+
+def picks_packed(q, k, v, packed, n_heads, bias=None, interpret=False,
+                 window=None):
+    """try_flash's test for a packed call: the short kernel takes the
+    segments (picks_short on their shapes), and a segment is whole
+    128-lane vregs, so that a block's index map can pick it."""
+    segs = packed_segments(q, k, v, packed, n_heads)
+    return _width((q, k, v), packed) % 128 == 0 and picks_short(
+        *segs, bias, layout="bthd", interpret=interpret, window=window)
+
+
+def flash_attention_packed(q, k, v, packed, n_heads, bias=None,
+                           causal=False, scale=None, interpret=False):
+    """The short-sequence kernel over the fused projection as its matmul
+    wrote it: `packed` "qkv": q, k and v are one [B, T, 3*H*D] array (in
+    that order of lane segments); "kv": q [B, T, H*D] and k, v one
+    [B, S, 2*H*D]. -> [B, T, H*D]. Differentiable (custom_vjp): the
+    gradient of each distinct array is one array of its shape, written
+    by the kernel, so XLA neither slices the projection nor pads and adds
+    its gradient."""
+    if not _HAS_PALLAS:
+        raise NotImplementedError("pallas unavailable")
+    STATS["pallas_calls"] += 1
+    STATS["short_packed"] += 1
+    arrays = (q, k, v)[:_n_arrays(packed)]
+    HD = _width(arrays, packed)
+    B, S = k.shape[:2]
+    scale = float(scale) if scale is not None else (HD // n_heads) ** -0.5
+    return _flash_short(arrays, _bias_rows(bias, B, S), packed, n_heads,
+                        bool(causal), scale, bool(interpret),
+                        bias is not None)
 
 
 def supports(q, k, v, bias=None, block_q=None, block_k=None):
@@ -1498,7 +1627,8 @@ def tiled_min_len(with_lse=False, layout="bhtd"):
 
 
 def try_flash(q, k, v, bias=None, causal=False, scale=None, with_lse=False,
-              causal_offset=0, layout="bhtd", window=None):
+              causal_offset=0, layout="bhtd", window=None, packed=None,
+              n_heads=None):
     """THE dispatch policy, in one place (used by ops/kernels_nn.py,
     parallel/ring_attention.py, parallel/ulysses.py): returns a Pallas
     kernel's result (`out`, or `(out, lse)` with `with_lse`) in the
@@ -1519,10 +1649,25 @@ def try_flash(q, k, v, bias=None, causal=False, scale=None, with_lse=False,
     shape tests. `with_lse` and `causal_offset` callers (ring attention)
     are served by the tiled kernel only, and so is a `window` (a causal
     band; one at or over the key length is no window), which neither of
-    the other two can carry."""
+    the other two can carry.
+
+    `packed` ("qkv" or "kv", with `n_heads`; the op's fused projections):
+    q, k, v are the [B, L, n*H*D] arrays that hold them (_PACKS: the one
+    qkv array three times, or q and the kv array twice) and the result
+    is [B, T, H*D]. Where picks_packed, the short kernel reads them in
+    place and writes their gradients packed; else they are sliced here
+    (`unpack`) and go the way `bthd` arrays go."""
     use_pallas, interpret = active()
     if not use_pallas:
         return None
+    if packed is not None:
+        if picks_packed(q, k, v, packed, n_heads, bias, interpret, window):
+            return flash_attention_packed(q, k, v, packed, n_heads, bias,
+                                          causal, scale, interpret)
+        out = try_flash(*unpack(q, k, v, packed, n_heads), bias=bias,
+                        causal=causal, scale=scale, layout="bthd",
+                        window=window)
+        return None if out is None else out.reshape(q.shape[:2] + (-1,))
     bthd = layout == "bthd"
     if window is not None:
         if with_lse or causal_offset or not causal:

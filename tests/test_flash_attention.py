@@ -635,6 +635,124 @@ def test_short_kernel_matches_reference(case, dtype):
                                    np.asarray(b, np.float32), **gtol)
 
 
+_PACKED_CASES = {
+    # name: (packed, B, T, S, H, D, causal); a key-padding bias in each
+    "qkv": ("qkv", 2, 64, 64, 2, 64, False),
+    "qkv_causal": ("qkv", 2, 64, 64, 2, 64, True),
+    "kv": ("kv", 2, 64, 48, 2, 64, False),
+}
+
+# what the transpose of a split, a slice or a concatenation leaves in a
+# jaxpr: none of it may touch the packed arrays
+_COPIES = ("split", "slice", "pad", "concatenate", "dynamic_slice",
+           "dynamic_update_slice")
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_PACKED_CASES))
+def test_packed_short_kernel_equals_the_split_one(case, dtype):
+    """flash_attention_packed (interpret mode) on the fused projection
+    against flash_attention_bthd on its slices: the output, and the
+    gradient of each packed array against the split path's gradients
+    side by side, equal to the last bit (the arithmetic is the same).
+    Its calls keep the short kernel's names, it is counted in
+    STATS["short_packed"], and its gradient's jaxpr slices, pads and
+    concatenates nothing."""
+    packed, B, T, S, H, D, causal = _PACKED_CASES[case]
+    dt, HD = jnp.dtype(dtype), H * D
+    rng = np.random.RandomState(7)
+    x = jnp.asarray(rng.randn(B, T, (3 if packed == "qkv" else 1) * HD), dt)
+    kv = jnp.asarray(rng.randn(B, S, 2 * HD), dt) if packed == "kv" else x
+    w = jnp.asarray(rng.randn(B, T, HD), jnp.float32)
+    bias = _pad_bias(rng, B, S).reshape(B, 1, 1, S)
+
+    def packed_fn(x, kv):
+        return fa.flash_attention_packed(x, kv, kv, packed, H, bias=bias,
+                                         causal=causal, interpret=True)
+
+    def split_fn(x, kv):
+        q, k, v = fa.unpack(x, kv, kv, packed, H)
+        assert q.shape == (B, T, H, D) and k.shape == (B, S, H, D)
+        return fa.flash_attention_bthd(q, k, v, bias=bias, causal=causal,
+                                       interpret=True).reshape(B, T, HD)
+
+    def loss(fn):
+        if packed == "qkv":
+            return lambda x: jnp.sum(fn(x, x).astype(jnp.float32) * w)
+        return lambda x, kv: jnp.sum(fn(x, kv).astype(jnp.float32) * w)
+
+    args = (x,) if packed == "qkv" else (x, kv)
+    argnums = tuple(range(len(args)))
+    counted = fa.STATS["short_packed"]
+    out = packed_fn(x, kv)
+    assert fa.STATS["short_packed"] == counted + 1
+    assert out.shape == (B, T, HD) and out.dtype == dt
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(split_fn(x, kv), np.float32))
+    grads = jax.grad(loss(packed_fn), argnums)(*args)
+    want = jax.grad(loss(split_fn), argnums)(*args)
+    for got, ref, a in zip(grads, want, args):
+        assert got.shape == a.shape and got.dtype == dt
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(ref, np.float32))
+    jaxpr = jax.make_jaxpr(jax.grad(loss(packed_fn), argnums))(*args)
+    text = str(jaxpr)
+    assert "flash_attention_short_fwd" in text
+    assert "flash_attention_short_bwd" in text
+    prims = {e.primitive.name for e in jaxpr.jaxpr.eqns}
+    assert not prims & set(_COPIES), prims
+
+
+def test_try_flash_takes_a_packed_call_by_the_short_kernels_policy():
+    """A packed call goes to the packed short kernel where picks_short
+    takes its segments and they are whole 128-lane vregs; anywhere else
+    try_flash slices it and it goes where `bthd` arrays go (the tiled
+    kernel, or None for the composition), its result [B, T, H*D]."""
+    from paddle_tpu.ops.registry import lowering_for
+    sds = jax.ShapeDtypeStruct
+
+    def picked(*a, **kw):
+        text = str(jax.make_jaxpr(lambda *a: fa.try_flash(*a, **kw))(*a))
+        return [n for n in ("flash_attention_short_fwd",
+                            "flash_attention_fwd") if n in text]
+
+    qkv = sds((4, 256, 3 * 512), jnp.bfloat16)
+    q, kv = sds((4, 256, 512), jnp.bfloat16), sds((4, 384, 1024),
+                                                  jnp.bfloat16)
+    with lowering_for("tpu"):
+        counted = fa.STATS["short_packed"]
+        assert picked(qkv, qkv, qkv, causal=True, layout="bthd",
+                      packed="qkv", n_heads=8) \
+            == ["flash_attention_short_fwd"]
+        assert picked(q, kv, kv, layout="bthd", packed="kv", n_heads=8) \
+            == ["flash_attention_short_fwd"]
+        assert fa.STATS["short_packed"] == counted + 2
+        # 8 heads of 16: 128 lanes a segment, a block the index map can
+        # pick; 4 heads of 16 are 64 lanes: the short kernel on slices
+        narrow = sds((4, 256, 3 * 64), jnp.bfloat16)
+        assert fa.picks_packed(*(sds((4, 256, 3 * 128), jnp.bfloat16),)
+                               * 3, "qkv", 8)
+        assert not fa.picks_packed(narrow, narrow, narrow, "qkv", 4)
+        assert fa.picks_short(*fa.packed_segments(narrow, narrow, narrow,
+                                                  "qkv", 4),
+                              layout="bthd", interpret=True)
+        # past the short kernel's lengths: sliced, then the tiled kernel
+        long = sds((1, fa.MIN_SEQ_LEN_BTHD, 3 * 512), jnp.bfloat16)
+        assert picked(long, long, long, layout="bthd", packed="qkv",
+                      n_heads=8) == ["flash_attention_fwd"]
+        out = jax.eval_shape(lambda x: fa.try_flash(
+            x, x, x, layout="bthd", packed="qkv", n_heads=8), long)
+        assert out.shape == (1, fa.MIN_SEQ_LEN_BTHD, 512)
+        # under the measured range: no kernel, the op's composition
+        short = sds((4, 128, 3 * 512), jnp.bfloat16)
+        assert jax.eval_shape(lambda x: fa.try_flash(
+            x, x, x, layout="bthd", packed="qkv", n_heads=8), short) is None
+        assert fa.STATS["short_packed"] == counted + 2
+    with lowering_for("cpu"):
+        assert fa.try_flash(qkv, qkv, qkv, layout="bthd", packed="qkv",
+                            n_heads=8) is None
+
+
 def test_try_flash_picks_the_short_kernel_by_layout_and_length():
     """The one policy: `bthd` arrays at a key length the short kernel
     takes go to it (no transpose); `bhtd` arrays and `with_lse` callers
@@ -696,16 +814,23 @@ def test_try_flash_picks_the_short_kernel_by_layout_and_length():
         assert fa.try_flash(q, q, q, layout="bthd") is None
 
 
-def _jaxpr_avals(jaxpr, skip=("pallas_call",)):
-    """Every intermediate's aval in a jaxpr and its sub-jaxprs, a
-    kernel's own body left out."""
+def _jaxpr_eqns(jaxpr, skip=("pallas_call",)):
+    """Every equation of a jaxpr and its sub-jaxprs, a kernel's own body
+    left out."""
     for eqn in jaxpr.eqns:
-        for var in eqn.outvars:
-            yield var.aval
+        yield eqn
         if eqn.primitive.name in skip:
             continue
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _jaxpr_avals(sub, skip)
+            yield from _jaxpr_eqns(sub, skip)
+
+
+def _jaxpr_avals(jaxpr):
+    """Every intermediate's aval in a jaxpr and its sub-jaxprs, a
+    kernel's own body left out."""
+    for eqn in _jaxpr_eqns(jaxpr):
+        for var in eqn.outvars:
+            yield var.aval
 
 
 def test_train_step_at_the_cells_widths_keeps_no_scores_tensor():
@@ -741,14 +866,25 @@ def test_train_step_at_the_cells_widths_keeps_no_scores_tensor():
     step = build_step_fn(main, [avg_cost.name], False, None)
     per = kern.STATS["by_kernel"].setdefault(
         "flash_attention", {"accepted": 0, "rejected": 0})
-    before = dict(per)
+    before, packed0 = dict(per), fa.STATS["short_packed"]
     with lowering_for("tpu"):
         jaxpr = jax.make_jaxpr(step)(persist, feed, jax.random.PRNGKey(0))
     assert per["accepted"] - before["accepted"] == 3 * L
     assert per["rejected"] == before["rejected"]
+    assert fa.STATS["short_packed"] - packed0 == 3 * L
     text = str(jaxpr)
     assert "flash_attention_short_fwd" in text
     assert "flash_attention_short_bwd" in text
+    # the fused projections reach the kernels unsplit and their gradients
+    # leave them packed: nothing of their width is split, sliced, padded
+    # or concatenated anywhere in the step
+    widths = (3 * cfg.d_model, 2 * cfg.d_model)
+    moved = [(e.primitive.name, v.aval.shape) for e in _jaxpr_eqns(
+        jaxpr.jaxpr) if e.primitive.name in _COPIES
+        for v in list(e.invars) + list(e.outvars)
+        if getattr(getattr(v, "aval", None), "shape", ())[-1:]
+        and v.aval.shape[-1] in widths]
+    assert not moved, moved
     H = cfg.n_head
     scores = [a.shape for a in _jaxpr_avals(jaxpr.jaxpr)
               if len(getattr(a, "shape", ())) >= 3

@@ -13,11 +13,13 @@ import pytest
 import paddle_tpu as pt
 from paddle_tpu import layers
 
+from paddle_tpu.ops.pallas import flash_attention as fa
+
 B, T, D, H = 2, 6, 16, 4
 DK = D // H
 
 
-def _build(fused, seed=5, cross=False):
+def _build(fused, seed=5, cross=False, T=T, D=D, H=H, train=True):
     main, startup = pt.Program(), pt.Program()
     main.random_seed = startup.random_seed = seed
     with pt.program_guard(main, startup):
@@ -25,10 +27,11 @@ def _build(fused, seed=5, cross=False):
             q_in = layers.data("q", shape=[T, D])
             kv_in = layers.data("kv", shape=[T, D]) if cross else q_in
             out = layers.multi_head_attention(
-                q_in, kv_in, kv_in, d_key=DK, d_value=DK, d_model=D,
-                n_head=H, name="attn", fused_qkv=fused)
+                q_in, kv_in, kv_in, d_key=D // H, d_value=D // H,
+                d_model=D, n_head=H, name="attn", fused_qkv=fused)
             loss = layers.mean(out)
-            pt.optimizer.SGD(0.1).minimize(loss)
+            if train:
+                pt.optimizer.SGD(0.1).minimize(loss)
     return main, startup, loss
 
 
@@ -37,21 +40,23 @@ def _params(main, scope):
             for p in main.all_parameters()}
 
 
-@pytest.mark.parametrize("cross", [False, True])
-def test_fused_matches_unfused(cross):
+def _losses_fused_and_unfused(cross, T=T, D=D, H=H):
+    """Three SGD steps of the fused and the unfused program from the
+    same weights (the fused ones the concatenation of the unfused)."""
     rng = np.random.RandomState(0)
     feed = {"q": rng.randn(B, T, D).astype("float32")}
     if cross:
         feed["kv"] = rng.randn(B, T, D).astype("float32")
+    dims = dict(T=T, D=D, H=H)
 
-    main_u, startup_u, loss_u = _build(False, cross=cross)
+    main_u, startup_u, loss_u = _build(False, cross=cross, **dims)
     scope_u = pt.Scope()
     exe = pt.Executor(pt.CPUPlace())
     with pt.scope_guard(scope_u):
         exe.run(startup_u)
         pu = _params(main_u, scope_u)
 
-    main_f, startup_f, loss_f = _build(True, cross=cross)
+    main_f, startup_f, loss_f = _build(True, cross=cross, **dims)
     scope_f = pt.Scope()
     with pt.scope_guard(scope_f):
         exe.run(startup_f)
@@ -86,8 +91,77 @@ def test_fused_matches_unfused(cross):
         for _ in range(3):
             out = exe.run(main_u, feed=feed, fetch_list=[loss_u])
             got_u.append(float(np.asarray(out[0])))
+    return got_f, got_u
 
+
+@pytest.mark.parametrize("cross", [False, True])
+def test_fused_matches_unfused(cross):
+    got_f, got_u = _losses_fused_and_unfused(cross)
     np.testing.assert_allclose(got_f, got_u, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("cross", [False, True])
+@pytest.mark.parametrize("D,H,packed_kernel", [
+    (128, 2, True),     # H*Dh 128 lanes: the short kernel reads it packed
+    (32, 2, False)])    # 32 lanes: sliced in the op, the kernel on slices
+def test_packed_attention_trains_as_the_unfused_one(cross, D, H,
+                                                    packed_kernel):
+    """Under the kernels (interpret mode) the fused program's
+    flash_attention op takes the packed short kernel where H*Dh is a
+    multiple of 128 lanes, and slices the projection in the op where it
+    is not; three SGD steps give the unfused program's losses either
+    way, and STATS["short_packed"] counts the packed traces only."""
+    packed0, calls0 = fa.STATS["short_packed"], fa.STATS["pallas_calls"]
+    fa.set_mode("interpret")
+    try:
+        got_f, got_u = _losses_fused_and_unfused(cross, T=8, D=D, H=H)
+    finally:
+        fa.set_mode("auto")
+    np.testing.assert_allclose(got_f, got_u, rtol=1e-5, atol=1e-6)
+    assert fa.STATS["pallas_calls"] > calls0
+    assert (fa.STATS["short_packed"] > packed0) == packed_kernel
+
+
+@pytest.mark.parametrize("cross", [False, True])
+def test_fused_program_hands_the_projection_to_the_op_unsplit(cross):
+    """With fused_qkv the program has no split: one flash_attention op
+    takes the projection as its matmul wrote it (QKV, or Q and KV) with
+    the `packed` and `n_head` attributes; the shape pass agrees with
+    the declared [B, T, H*Dh] output, and the op survives the
+    reference's ProgramDesc round trip and computes the same there."""
+    from paddle_tpu.core import fluid_proto as fpr
+    main, startup, loss = _build(True, cross=cross, train=False)
+    ops = main.global_block().ops
+    assert "split" not in [op.type for op in ops]
+    attn = [op for op in ops if op.type == "flash_attention"]
+    assert len(attn) == 1
+    op = attn[0]
+    packed = "kv" if cross else "qkv"
+    assert op.attrs["packed"] == packed and op.attrs["n_head"] == H
+    assert sorted(op.inputs) == (["KV", "Q"] if cross else ["QKV"])
+    out = main.global_block().var(op.outputs["Out"][0])
+    assert tuple(out.shape)[1:] == (T, D)
+    assert not [d for d in main.verify(fetch_list=[loss.name])
+                if d.pass_name == "shape-dtype"]
+
+    feeds = ["q", "kv"] if cross else ["q"]
+    blob = fpr.program_to_fluid(main, feed_names=feeds,
+                                fetch_names=[loss.name])
+    back, _, _ = fpr.program_from_fluid(blob)
+    op2 = [o for o in back.global_block().ops
+           if o.type == "flash_attention"][0]
+    assert op2.inputs == op.inputs
+    assert op2.attrs["packed"] == packed and op2.attrs["n_head"] == H
+
+    rng = np.random.RandomState(1)
+    feed = {n: rng.randn(B, T, D).astype("float32") for n in feeds}
+    exe = pt.Executor(pt.CPUPlace())
+    with pt.scope_guard(pt.Scope()):
+        exe.run(startup)
+        want = exe.run(main, feed=feed, fetch_list=[loss.name])[0]
+        got = exe.run(back, feed=feed, fetch_list=[loss.name])[0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-6, atol=1e-7)
 
 
 def test_fused_layout_param_count():

@@ -113,6 +113,47 @@ def test_short_attention_compiles_for_v5e_at_the_cells_shape(one_chip, T, S,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * B * H * T * S
 
 
+@pytest.mark.parametrize("packed,causal", [("qkv", False), ("qkv", True),
+                                           ("kv", False)])
+def test_packed_short_attention_compiles_for_v5e_at_the_cells_shape(
+        one_chip, packed, causal):
+    """The short kernel over the cell's fused projections as their
+    matmuls write them: [128, 256, 3 x 512] (self-attention) or q and a
+    [128, 256, 2 x 512] kv (cross), bf16, the key-padding bias. The same
+    two kernels, handed the packed arrays in place; the backward's
+    result is the packed gradient itself, so the module slices, pads,
+    concatenates and updates nothing, and holds no temporary."""
+    sds = jax.ShapeDtypeStruct
+    T = 256
+    x = sds((B, T, (3 if packed == "qkv" else 1) * H * D), jnp.bfloat16,
+            sharding=one_chip)
+    kv = sds((B, T, 2 * H * D), jnp.bfloat16, sharding=one_chip)
+    bias = sds((B, 1, 1, T), jnp.float32, sharding=one_chip)
+    g = sds((B, T, H * D), jnp.bfloat16, sharding=one_chip)
+    assert fa.picks_packed(x, kv, kv, packed, H, bias)
+
+    def step(x, kv, b, g):
+        if packed == "qkv":
+            out, vjp = jax.vjp(lambda x: fa.flash_attention_packed(
+                x, x, x, packed, H, bias=b, causal=causal), x)
+        else:
+            out, vjp = jax.vjp(lambda x, kv: fa.flash_attention_packed(
+                x, kv, kv, packed, H, bias=b, causal=causal), x, kv)
+        return (out,) + vjp(g)
+
+    compiled = jax.jit(step).lower(x, kv, bias, g).compile()
+    ins = _instructions(compiled.as_text())
+    names = sorted(_kernel_names(compiled))
+    assert len(names) == 2, names
+    assert "flash_attention_short_fwd" in names[1], names
+    assert "flash_attention_short_bwd" in names[0], names
+    copies = [(i[0], i[2]) for i in ins if i[2] in (
+        "slice", "pad", "concatenate", "dynamic-update-slice", "fusion",
+        "copy", "transpose")]
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
 def _prod(shape):
     n = 1
     for d in shape:
