@@ -205,9 +205,9 @@ def attention_work(cfg, traffic):
     """{kernel: (FLOPs, bytes)} of one causal grouped-query attention over
     the batch, as the algorithm needs them whatever implements it: a
     product is 2 B H T S D / 2 (the causal half); forward two of them, q
-    and out at H heads and k, v at KVH heads once each; dq three (the
-    scores again, dp, dq) reading q, k, v, dout and writing dq; dkv four
-    (the scores, dp, dv, dk) reading the same and writing dk, dv."""
+    and out at H heads and k, v at KVH heads once each; backward, the one
+    kernel `flash_attention_bwd`, five (the scores again, dp, dv, dk, dq)
+    reading q, k, v, out, dout and writing dq, dk, dv."""
     B, T = traffic["rows"], traffic["length"]
     nh, kv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     D = cfg["hidden_size"] // nh
@@ -215,8 +215,7 @@ def attention_work(cfg, traffic):
     product = 2 * B * nh * T * T * D // 2
     q_bytes, kv_bytes = B * T * nh * D * size, B * T * kv * D * size
     return {"flash_attention_fwd": (2 * product, 2 * q_bytes + 2 * kv_bytes),
-            "flash_attention_dq": (3 * product, 3 * q_bytes + 2 * kv_bytes),
-            "flash_attention_dkv": (4 * product, 2 * q_bytes + 4 * kv_bytes)}
+            "flash_attention_bwd": (5 * product, 4 * q_bytes + 4 * kv_bytes)}
 
 
 def local_pairs_per_layer(cfg, traffic):

@@ -115,6 +115,21 @@ def test_manifest_cross_references_by_name():
                 assert re.match(r"^[A-Za-z0-9_.\-]+$", f), f
 
 
+def test_a_kernel_with_a_roofline_share_has_no_time_entry_beside_it():
+    """A kernel's traced time is its floor (`kernel_work`) over its share
+    of the roofline, so a cell that reports `<k>_roofline[.tag]` reports
+    no `kernel_ms_per_step.<k>[.tag]`: the file holds 128 per-layer
+    entries at most, and a repeat takes a place a new cell needs."""
+    man = manifest.Manifest(REPO).validate()
+    assert 1 <= len(man.doc["per_layer"]) <= 128
+    for cell in man.cells:
+        names = [m["name"].split(".") for m in man.cell_per_layer(cell)]
+        timed = {n[1] for n in names if n[0] == "kernel_ms_per_step"}
+        shared = {n[0][:-len("_roofline")] for n in names
+                  if n[0].endswith("_roofline")}
+        assert not timed & shared, (cell, sorted(timed & shared))
+
+
 @pytest.mark.parametrize("breakage", ["moves", "unit", "cell"])
 def test_manifest_refuses_a_broken_cross_reference(tmp_path, breakage):
     root = tiny.make_root(tmp_path)

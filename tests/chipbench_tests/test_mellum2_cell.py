@@ -193,10 +193,10 @@ def test_the_appended_entries_list_the_new_cell_alone(man):
     order = [m["name"] for m in man.doc["per_layer"]]
     assert order.index(tagged[0]["name"]) > order.index(
         "moe_combine_roofline.solar")
-    # a benchmark holds 128 per-layer metrics at most and had 104: the
-    # cell brings 24 of ISSUE 36's 39 (a kernel's time follows from its
-    # share of the roofline; the band's two kernels keep both)
-    assert len(man.doc["per_layer"]) == 128 and len(tagged) == 24
+    # a benchmark holds 128 per-layer metrics at most; the cell reports 22
+    # (a kernel's time follows from its share of the roofline, so no
+    # kernel of it has a time entry)
+    assert len(man.doc["per_layer"]) <= 128 and len(tagged) == 22
     wanted = {
         "device_idle_share", "peak_hbm_bytes", "compiles_in_window",
         "train_device_step_ms", "train_mfu",
@@ -209,7 +209,6 @@ def test_the_appended_entries_list_the_new_cell_alone(man):
                "moe_gmm_swiglu", "moe_gmm", "moe_swiglu_bwd", "moe_tgmm",
                "moe_combine")
     wanted |= {f"{k}_roofline" for k in kernels}
-    wanted |= {f"kernel_ms_per_step.{k}" for k in kernels[:2]}
     assert {w + TAG for w in wanted} == names
     for m in tagged:
         if m["name"].split(".")[0].endswith("_roofline"):
