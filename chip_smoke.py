@@ -228,6 +228,18 @@ def chip_kernel_cases():
                        jnp.float32),
            jnp.asarray(rng.uniform(0.0, 2.0, (1, 8192, 8)), jnp.float32))
 
+    # the cell jamba2_train_1chip's selective scan: a bf16 x, a float32
+    # step from the initialisation's [0.001, 0.1] (and up to 1), A_log =
+    # log(1 .. 16), bf16 B and C, over 1280 channels of 8192 tokens
+    ssm = (f32(1, 8192, 1280).astype(jnp.bfloat16),
+           jnp.asarray(np.exp(rng.uniform(np.log(1e-3), 0.0,
+                                          (1, 8192, 1280))), jnp.float32),
+           jnp.asarray(np.log(np.broadcast_to(np.arange(1, 17), (1280, 16))),
+                       jnp.float32),
+           f32(1, 8192, 16).astype(jnp.bfloat16),
+           f32(1, 8192, 16).astype(jnp.bfloat16),
+           jnp.ones((1280,), jnp.float32))
+
     def int8(*shape):
         return jnp.asarray(rng.randint(-127, 128, size=shape), jnp.int8)
 
@@ -252,6 +264,7 @@ def chip_kernel_cases():
                                    {"causal": True, "window": 1024},
                                    (0, 1, 2)),
         "kda_attention": (kda, {}, (0, 1, 2, 3, 4)),
+        "selective_scan": (ssm, {}, (0, 1, 2, 3, 4, 5)),
         "lookup_pool": ((f32(512, 128),
                          jnp.asarray(rng.randint(-1, 512, size=(256, 8)),
                                      jnp.int32)),
@@ -283,7 +296,8 @@ def example_kernel_cases():
     from paddle_tpu.ops import kern
     rng = np.random.RandomState(0)
     grads = {"layer_norm": (0, 1, 2), "flash_attention": (0, 1, 2),
-             "kda_attention": (0, 1, 2, 3, 4)}
+             "kda_attention": (0, 1, 2, 3, 4),
+             "selective_scan": (0, 1, 2, 3, 4, 5)}
     return {s.name: s.example(rng) + (grads.get(s.name, ()),)
             for s in kern.specs()}
 

@@ -28,18 +28,29 @@ _KEEP_FP32_SLOTS = {
     "group_norm": ("Scale", "Bias"), "rms_norm": ("Scale",),
     "moe_route": ("Weight", "TopkW"),
     "kda_gate": ("ALog", "DtBias", "Out"),
+    "selective_scan": ("ALog", "D", "Dt"),
 }
+
+# ... and what a kept variable is made of through a step or a bias: a
+# selective scan's dt = softplus(x W + b) keeps the sum and the bias b
+# float32 (the product x W is the program's)
+_KEEP_FP32_THROUGH = {"softplus": "X", "elementwise_add": "Y"}
 
 
 def _fp32_by_use(program):
     """Names of the variables some op reads or writes through a slot of
-    _KEEP_FP32_SLOTS."""
+    _KEEP_FP32_SLOTS, and what they are made of through
+    _KEEP_FP32_THROUGH."""
     keep = set()
     for block in program.blocks:
         for op in block.ops:
             for slot in _KEEP_FP32_SLOTS.get(op.type, ()):
                 keep.update(op.inputs.get(slot, ()))
                 keep.update(op.outputs.get(slot, ()))
+        for op in reversed(block.ops):
+            slot = _KEEP_FP32_THROUGH.get(op.type)
+            if slot and keep.intersection(op.output_names()):
+                keep.update(op.inputs.get(slot, ()))
     return keep
 
 

@@ -8,7 +8,8 @@ of one XLA module. Shapes may use -1 for batch dims.
 import numpy as np
 
 from ..layer_helper import LayerHelper
-from ..initializer import ConstantInitializer, NormalInitializer
+from ..initializer import (ConstantInitializer, NormalInitializer,
+                           NumpyArrayInitializer)
 from ..core.dtypes import convert_dtype
 from .utils import convert_to_list
 
@@ -39,7 +40,7 @@ __all__ = [
     "scaled_dot_product_attention", "multi_head_attention",
     "flash_attention", "rms_norm", "rotary_embedding", "short_conv",
     "swiglu", "moe_route", "moe_expert_ffn",
-    "l2_norm", "kda_gate", "kda_attention",
+    "l2_norm", "kda_gate", "kda_attention", "selective_scan",
     "add_position_encoding", "lod_reset", "im2sequence",
     "logsumexp", "bilinear_tensor_product", "isfinite", "cos_sim",
     "unique_with_counts_stub", "maxout", "pixel_shuffle",
@@ -1588,15 +1589,22 @@ def rotary_embedding(input, theta=10000.0, name=None, rope_type="default",
                            "rotary_embedding", attrs)
 
 
-def short_conv(input, filter_size=3, param_attr=None, name=None):
+def short_conv(input, filter_size=3, param_attr=None, name=None,
+               bias_attr=None):
     """Causal depthwise convolution along T of [B, T, C], filter [C,
-    filter_size], no bias: out[t] = sum_j w[:, j] * x[t - (K-1) + j]."""
+    filter_size]: out[t] = sum_j w[:, j] * x[t - (K-1) + j], plus a bias
+    [C] (`<name>.b_0`, zero to start) where `bias_attr` asks for one
+    (a ParamAttr or True); with none, no bias."""
     helper = LayerHelper("short_conv", name=name)
-    w = helper.create_parameter(
-        param_attr, shape=[int(input.shape[-1]), int(filter_size)],
-        dtype=input.dtype)
-    return _same_shape_out(helper, input, "short_conv",
-                           extra_inputs={"Filter": [w]})
+    C = int(input.shape[-1])
+    w = helper.create_parameter(param_attr, shape=[C, int(filter_size)],
+                                dtype=input.dtype)
+    extra = {"Filter": [w]}
+    if bias_attr is not None and bias_attr is not False:
+        extra["Bias"] = [helper.create_parameter(
+            bias_attr if bias_attr is not True else None, [C], input.dtype,
+            is_bias=True)]
+    return _same_shape_out(helper, input, "short_conv", extra_inputs=extra)
 
 
 def swiglu(x, y, name=None):
@@ -1722,6 +1730,36 @@ def kda_attention(q, k, v, g, beta, scale=None, name=None):
                      {"Q": [q], "K": [k], "V": [v], "G": [g],
                       "Beta": [beta]}, {"Out": [out]},
                      {"scale": scale or int(q.shape[3]) ** -0.5})
+    return out
+
+
+def selective_scan(x, dt, A_log, B, C, D, name=None):
+    """The diagonal selective scan of a Mamba-1 mixer over x, dt [B, T,
+    Ch] (dt already the softplus'd step) and B, C [B, T, N]: per channel
+    c and state n a float32 state, zero before the sequence,
+
+        h_t[c, n] = exp(dt_t[c] A[c, n]) h_{t-1}[c, n] + dt_t[c] B_t[n] x_t[c]
+        y_t[c] = sum_n C_t[n] h_t[c, n] + D[c] x_t[c],  A = -exp(A_log)
+
+    `A_log` [Ch, N] (`<name>.w_0`) and `D` [Ch] (`<name>.w_1`) are the
+    ParamAttrs of two float32 parameters, whatever the program is cast to;
+    None gives Mamba's initialisation, A_log = log(1 .. N) on every channel
+    and D = 1. One op (ops/kernels_scan.py): on a TPU at Ch a multiple of
+    128 the Mosaic kernels of ops/pallas/selective_scan.py with their own
+    backward, elsewhere a chunked jnp composition differentiated by the
+    tracer; -> y [B, T, Ch] in x's dtype."""
+    helper = LayerHelper("selective_scan", name=name)
+    Ch, N = int(x.shape[2]), int(B.shape[2])
+    a_log = helper.create_parameter(
+        A_log, [Ch, N], "float32",
+        default_initializer=NumpyArrayInitializer(np.log(np.broadcast_to(
+            np.arange(1, N + 1, dtype="float32"), (Ch, N)))))
+    d = helper.create_parameter(D, [Ch], "float32",
+                                default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(x.dtype, x.shape)
+    helper.append_op("selective_scan",
+                     {"X": [x], "Dt": [dt], "ALog": [a_log], "B": [B],
+                      "C": [C], "D": [d]}, {"Out": [out]})
     return out
 
 

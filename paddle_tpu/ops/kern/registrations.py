@@ -20,6 +20,7 @@ from ..pallas import layer_norm as ln
 from ..pallas import embedding as emb
 from ..pallas import grouped_matmul as gm
 from ..pallas import kda
+from ..pallas import selective_scan as ssm
 from . import decode_attention as da
 from . import quant
 from .. import kernels_scan as scan
@@ -310,5 +311,38 @@ register(KernelSpec(
          "UT transform, sub-blocks of 16), the state resident across a "
          "head's chunks, fwd+bwd (custom_vjp, hand-derived); float32 at "
          "HIGHEST; Dk, Dv multiples of 128, else the op's jnp composition; "
+         "reference: the token-by-token recurrence",
+))
+
+
+# ---------------------------------------------------------- selective_scan
+def _scan_example(rng):
+    Bsz, T, C, N = 1, 300, 256, 16     # T off a multiple of the chunk
+    x = jnp.asarray(rng.standard_normal((Bsz, T, C)), jnp.float32)
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(0.1),
+                                        (Bsz, T, C))), jnp.float32)
+    a_log = jnp.asarray(np.log(np.broadcast_to(np.arange(1, N + 1), (C, N))),
+                        jnp.float32)
+    B = jnp.asarray(rng.standard_normal((Bsz, T, N)), jnp.float32)
+    Cm = jnp.asarray(rng.standard_normal((Bsz, T, N)), jnp.float32)
+    D = jnp.ones((C,), jnp.float32)
+    return (x, dt, a_log, B, Cm, D), {}
+
+
+register(KernelSpec(
+    name="selective_scan",
+    fn=ssm.try_selective_scan,
+    reference=scan.selective_scan_recurrent,
+    probe=ssm.supports,
+    # both walk the same recurrence in float32 and differ only in the
+    # order of the 16-term sum of y: a few float32 spacings of y's largest
+    # terms, which sum 300 tokens of decayed inputs
+    tol=(1e-5, 1e-5),
+    example=_scan_example,
+    note="diagonal selective scan (Mamba-1): the state [N, channels] "
+         "resident in VMEM across a block's chunks of 256 tokens, one "
+         "state saved a chunk, fwd+bwd (custom_vjp, hand-derived; the "
+         "backward rebuilds a chunk's states); float32 on the VPU; "
+         "channels multiples of 128, else the op's chunked composition; "
          "reference: the token-by-token recurrence",
 ))

@@ -1052,13 +1052,17 @@ def _rotary_embedding(ctx, ins, attrs):
 def _short_conv(ctx, ins, attrs):
     """Causal depthwise convolution along T of X [B, T, C] with Filter
     [C, K]: out[t, c] = sum_j Filter[c, j] * x[t - (K - 1) + j, c], zeros
-    before the sequence. K shifted products; float32 inside."""
+    before the sequence, plus Bias [C] where the op has one. K shifted
+    products; float32 inside."""
     x, filt = _x(ins), ins["Filter"][0]
     K = filt.shape[1]
     T = x.shape[1]
     xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
     ff = filt.astype(jnp.float32)
     out = sum(xf[:, j:j + T, :] * ff[:, j] for j in range(K))
+    bias = _opt(ins, "Bias")
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return {"Out": [out.astype(x.dtype)]}
 
 

@@ -37,6 +37,13 @@ batched over the chunks. `jax.value_and_grad` goes through all of it:
 the composition has no hand-written backward. The whole function is
 rematerialised in the backward pass (`jax.checkpoint`), so a layer keeps
 q, k, v, g and beta and none of the chunk tensors.
+
+The diagonal selective scan of a Mamba-1 mixer (`selective_scan`) keeps a
+state per channel and state index whose decay exp(dt A) depends on both
+and on the input, so it has no matrix form: `selective_scan_recurrent` is
+its definition, the Mosaic kernels of ops/pallas/selective_scan.py run it
+where the channels are whole vregs on a TPU, and `selective_scan_chunked`
+everywhere else.
 """
 import functools
 
@@ -45,10 +52,12 @@ import jax.numpy as jnp
 
 from .registry import kernel
 
-__all__ = ["kda_chunked", "kda_recurrent", "CHUNK", "SUB"]
+__all__ = ["kda_chunked", "kda_recurrent", "selective_scan_chunked",
+           "selective_scan_recurrent", "CHUNK", "SUB", "SCAN_CHUNK"]
 
 CHUNK = 64
 SUB = 16
+SCAN_CHUNK = 256
 HI = jax.lax.Precision.HIGHEST
 
 
@@ -185,6 +194,100 @@ def _kda_attention(ctx, ins, attrs):
     out = ctx.accel("kda_attention")(*args, scale=scale)
     if out is None:
         out = kda_chunked(*args, scale=scale)
+    return {"Out": [out]}
+
+
+def selective_scan_recurrent(x, dt, A_log, B, C, D):
+    """The diagonal selective scan of a Mamba-1 mixer, token by token: per
+    channel c and state n, with A = -exp(A_log) and h_{-1} = 0,
+
+        h_t[c, n] = exp(dt_t[c] A[c, n]) h_{t-1}[c, n] + dt_t[c] B_t[n] x_t[c]
+        y_t[c] = sum_n C_t[n] h_t[c, n] + D[c] x_t[c]
+
+    x, dt [B, T, C], A_log [C, N], B, C [B, T, N], D [C] -> y [B, T, C] in
+    x's dtype; float32 inside. The definition and the registry's
+    reference; walked in blocks of SCAN_CHUNK tokens that the backward
+    pass recomputes, so that a gradient keeps one state a block."""
+    f32 = jnp.float32
+    Bsz, T, Ch = x.shape
+    a = -jnp.exp(A_log.astype(f32))
+
+    def step(h, inp):
+        xt, dtt, bt, ct = inp
+        h = jnp.exp(dtt[..., None] * a) * h \
+            + (dtt * xt)[..., None] * bt[:, None, :]
+        return h, jnp.einsum("bcn,bn->bc", h, ct, precision=HI)
+
+    @jax.checkpoint
+    def block(h, xs):
+        return jax.lax.scan(step, h, xs)
+
+    pad = (-T) % SCAN_CHUNK          # dt = 0: the state stands still
+
+    def blocks(v):
+        v = jnp.moveaxis(v.astype(f32), 1, 0)
+        v = jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+        return v.reshape((-1, SCAN_CHUNK) + v.shape[1:])
+
+    h0 = jnp.zeros((Bsz, Ch, A_log.shape[-1]), f32)
+    _, y = jax.lax.scan(block, h0, tuple(blocks(v) for v in (x, dt, B, C)))
+    y = jnp.moveaxis(y.reshape((-1,) + y.shape[2:])[:T], 0, 1)
+    return (y + D.astype(f32) * x.astype(f32)).astype(x.dtype)
+
+
+@jax.checkpoint
+def _selective_scan_chunked(x, dt, A_log, B, C, D):
+    f32 = jnp.float32
+    Bsz, T, Ch = x.shape
+    L = min(SCAN_CHUNK, T)
+    pad = (-T) % L
+    a = -jnp.exp(A_log.astype(f32))
+
+    def chunks(v):                   # [B, T, .] -> [nc, L, B, .]
+        v = jnp.moveaxis(v.astype(f32), 1, 0)
+        v = jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+        return v.reshape((-1, L) + v.shape[1:])
+
+    def combine(early, late):        # h -> late.e (early.e h + early.b) + late.b
+        return late[0] * early[0], late[0] * early[1] + late[1]
+
+    @jax.checkpoint
+    def chunk(h, inp):
+        xc, dtc, bc, cc = inp
+        e = jnp.exp(dtc[..., None] * a)                     # [L, B, C, N]
+        bu = (dtc * xc)[..., None] * bc[:, :, None, :]
+        E, H = jax.lax.associative_scan(combine, (e, bu), axis=0)
+        hs = E * h[None] + H
+        return hs[-1], jnp.einsum("lbcn,lbn->lbc", hs, cc, precision=HI)
+
+    h0 = jnp.zeros((Bsz, Ch, A_log.shape[-1]), f32)
+    _, y = jax.lax.scan(chunk, h0, tuple(chunks(v) for v in (x, dt, B, C)))
+    y = jnp.moveaxis(y.reshape((-1,) + y.shape[2:])[:T], 0, 1)
+    return y + D.astype(f32) * x.astype(f32)
+
+
+def selective_scan_chunked(x, dt, A_log, B, C, D):
+    """The same function as `selective_scan_recurrent`, in chunks of
+    SCAN_CHUNK tokens: inside a chunk every state at once by an
+    associative scan of the pairs (exp(dt A), dt B x), between chunks one
+    state; each chunk and the whole function rematerialised in the
+    backward pass, so that a layer keeps its inputs and never a [T, C, N]
+    tensor."""
+    return _selective_scan_chunked(x, dt, A_log, B, C, D).astype(x.dtype)
+
+
+@kernel("selective_scan")
+def _selective_scan(ctx, ins, attrs):
+    """X, Dt [B, T, C], ALog [C, N], B, C [B, T, N], D [C] -> Out [B, T, C]
+    in X's dtype: the diagonal selective scan, zero state before the
+    sequence; float32 inside. Dispatched through the kern registry (whose
+    STATS count the calls and whose reference is the token-by-token
+    recurrence) to the Mosaic kernels, `pallas.selective_scan.
+    try_selective_scan`; where that says None, `selective_scan_chunked`."""
+    args = tuple(ins[s][0] for s in ("X", "Dt", "ALog", "B", "C", "D"))
+    out = ctx.accel("selective_scan")(*args)
+    if out is None:
+        out = selective_scan_chunked(*args)
     return {"Out": [out]}
 
 

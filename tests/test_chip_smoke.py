@@ -70,7 +70,7 @@ def test_kernels_phase_runs_every_registered_kernel(smoke, watch,
     assert sorted(info) == ["decode_attend", "dequant_attend_int8",
                             "flash_attention", "int8_quant",
                             "kda_attention", "layer_norm", "lookup_pool",
-                            "moe_expert_ffn"]
+                            "moe_expert_ffn", "selective_scan"]
 
 
 def test_kernels_phase_fails_on_a_rejected_shape(smoke, watch):

@@ -16,7 +16,7 @@ from paddle_tpu.ops.pallas import flash_attention as fa
 
 KERNELS = ("decode_attend", "dequant_attend_int8", "flash_attention",
            "int8_quant", "kda_attention", "layer_norm", "lookup_pool",
-           "moe_expert_ffn")
+           "moe_expert_ffn", "selective_scan")
 
 
 @pytest.fixture

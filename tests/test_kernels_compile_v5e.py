@@ -428,3 +428,44 @@ def test_the_chunked_scan_compiles_at_the_cells_shape(one_chip):
     assert not opcodes & {"while", "triangular-solve", "dot",
                           "convolution"}, opcodes
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e8
+
+
+def test_the_selective_scan_compiles_at_the_cells_shape(one_chip):
+    """`selective_scan` as jamba2_train_1chip calls it: [1, 8192, 1280]
+    bf16 x, a float32 step, A_log [1280, 16], bf16 B and C [1, 8192, 16],
+    forward and gradient through the dispatch entry under a TPU lowering:
+    Mosaic takes `selective_scan_fwd` and `selective_scan_bwd`, no XLA
+    `while` walks the tokens (the sequence is the kernels' last grid axis),
+    and the module keeps the state every chunk starts on (32 x [16, 1280]
+    float32 = 2.6e6 B) and nothing of [T, C, N]: a layer's whole state
+    history would be 671e6 B."""
+    from paddle_tpu.ops import registry
+    from paddle_tpu.ops.pallas import selective_scan as ssm
+    T, C, N = 8192, 1280, 16
+    sds = jax.ShapeDtypeStruct
+    args = (sds((1, T, C), jnp.bfloat16, sharding=one_chip),
+            sds((1, T, C), jnp.float32, sharding=one_chip),
+            sds((C, N), jnp.float32, sharding=one_chip),
+            sds((1, T, N), jnp.bfloat16, sharding=one_chip),
+            sds((1, T, N), jnp.bfloat16, sharding=one_chip),
+            sds((C,), jnp.float32, sharding=one_chip))
+
+    def loss(*a):
+        return jnp.sum(ssm.try_selective_scan(*a).astype(jnp.float32))
+
+    taken = ssm.STATS["pallas_calls"]
+    with registry.lowering_for("tpu"):
+        compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+            *args).compile()
+    assert ssm.STATS["pallas_calls"] == taken + 1
+    # (the backward's result tuple has six members and an /*index=5*/
+    # comment in its type, which _instructions' pattern does not cross)
+    names = [line.split(" = ")[0].strip().lstrip("%")
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(names) == 2, names
+    assert any("selective_scan_fwd" in n for n in names), names
+    assert any("selective_scan_bwd" in n for n in names), names
+    opcodes = {i[2] for i in _instructions(compiled.as_text())}
+    assert "while" not in opcodes, opcodes
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e8

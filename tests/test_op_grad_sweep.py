@@ -461,6 +461,11 @@ SPECS["kda_attention"] = S(
     {"Q": (1, 70, 2, 4), "K": (1, 70, 2, 4), "V": (1, 70, 2, 4),
      "G": -np.random.RandomState(34).uniform(0.05, 2.0, (1, 70, 2, 4)),
      "Beta": ("pos", (1, 70, 2))}, {"scale": 0.5}, f32=True)
+# the diagonal selective scan: a step dt in (0.3, 1.5) over 20 tokens
+# (a decay of exp(-dt exp(A_log)) a token); B, C, D, A_log signed
+SPECS["selective_scan"] = S(
+    {"X": (1, 20, 3), "Dt": ("pos", (1, 20, 3)), "ALog": (3, 4),
+     "B": (1, 20, 4), "C": (1, 20, 4), "D": (3,)}, f32=True)
 
 # recurrent (weights + input grads through lax.scan)
 SPECS["lstm"] = S(
