@@ -36,9 +36,11 @@ cell the share is handed its own part: the exchange is left out, here as
 in the program.
 
 `prec` is reference/lfm2_moe.py's: "float32" (the reference), "bfloat16"
-(what the configuration states), "int8" (the control). RMSNorm, dt and
-b_dt, A_log, D, the scan's state, the softmax and the loss are float32 in
-all three, as the configuration states them.
+(what the configuration states), "int8" (the control: every product's
+operands rounded to int8 per tensor, the scan's x, B and C among them,
+since the scan multiplies them: dt B x, C h). RMSNorm, dt and b_dt,
+A_log, D, the scan's state, the softmax and the loss are float32 in all
+three, as the configuration states them.
 
 A planted fault for the limits: `state_reset` in the configuration drops
 the scan's state to zero every that many tokens (a mechanism left out
@@ -57,8 +59,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from chipbench.reference.lfm2_moe import (  # noqa: F401  (tree_norms: API)
-    HI, Q_BLOCK, _act, _adam, _attend_block, _ein, _swiglu, _zeros,
-    dwconv_causal, rms_norm, tree_norms)
+    HI, Q_BLOCK, _act, _adam, _attend_block, _ein, _fake_int8, _swiglu,
+    _zeros, dwconv_causal, rms_norm, tree_norms)
 from chipbench.reference.solar_open2 import _half
 
 SCAN_BLOCK = 256
@@ -99,6 +101,12 @@ def selective_scan(x, dt, A_log, B, C, D, reset=None):
     return y + D.astype(f32) * x.astype(f32)
 
 
+def _scan_operand(v, prec):
+    """x, B or C as the scan reads them: the int8 control rounds them as
+    it rounds every product's operands; dt stays float32."""
+    return _fake_int8(v) if prec == "int8" else v
+
+
 def mamba_in(p, name, x, cfg, prec):
     """(x after the convolution, z, this share's part of x W_x)."""
     xz = _act(_ein("bth,hc->btc", x, p[f"{name}_in.w_0"], prec), prec)
@@ -119,7 +127,8 @@ def mamba_out(p, name, xc, z, x_proj, cfg, prec):
                                (x_proj[..., R + N:], "c")))
     dt = jax.nn.softplus(_ein("btr,rc->btc", dt_r, p[f"{name}_dt.w_0"], prec)
                          + p[f"{name}_dt.b_0"].astype(jnp.float32))
-    y = _act(selective_scan(xc, dt, p[f"{name}_scan.w_0"], B, C,
+    xs, B, C = (_scan_operand(v, prec) for v in (xc, B, C))
+    y = _act(selective_scan(xs, dt, p[f"{name}_scan.w_0"], B, C,
                             p[f"{name}_scan.w_1"], cfg.get("state_reset")),
              prec)
     return _ein("btc,ch->bth", _act(y * jax.nn.silu(z), prec),

@@ -41,9 +41,9 @@ q, k, v, g and beta and none of the chunk tensors.
 The diagonal selective scan of a Mamba-1 mixer (`selective_scan`) keeps a
 state per channel and state index whose decay exp(dt A) depends on both
 and on the input, so it has no matrix form: `selective_scan_recurrent` is
-its definition, the Mosaic kernels of ops/pallas/selective_scan.py run it
-where the channels are whole vregs on a TPU, and `selective_scan_chunked`
-everywhere else.
+its definition and what the op runs off the TPU; the Mosaic kernels of
+ops/pallas/selective_scan.py run it where the channels are whole vregs on
+a TPU.
 """
 import functools
 
@@ -52,8 +52,8 @@ import jax.numpy as jnp
 
 from .registry import kernel
 
-__all__ = ["kda_chunked", "kda_recurrent", "selective_scan_chunked",
-           "selective_scan_recurrent", "CHUNK", "SUB", "SCAN_CHUNK"]
+__all__ = ["kda_chunked", "kda_recurrent", "selective_scan_recurrent",
+           "CHUNK", "SUB", "SCAN_CHUNK"]
 
 CHUNK = 64
 SUB = 16
@@ -205,9 +205,10 @@ def selective_scan_recurrent(x, dt, A_log, B, C, D):
         y_t[c] = sum_n C_t[n] h_t[c, n] + D[c] x_t[c]
 
     x, dt [B, T, C], A_log [C, N], B, C [B, T, N], D [C] -> y [B, T, C] in
-    x's dtype; float32 inside. The definition and the registry's
-    reference; walked in blocks of SCAN_CHUNK tokens that the backward
-    pass recomputes, so that a gradient keeps one state a block."""
+    x's dtype; float32 inside. The definition, the registry's reference
+    and the op's path off the TPU; walked in blocks of SCAN_CHUNK tokens
+    that the backward pass recomputes, so that a gradient keeps one state
+    a block and never a [T, C, N] tensor."""
     f32 = jnp.float32
     Bsz, T, Ch = x.shape
     a = -jnp.exp(A_log.astype(f32))
@@ -235,47 +236,6 @@ def selective_scan_recurrent(x, dt, A_log, B, C, D):
     return (y + D.astype(f32) * x.astype(f32)).astype(x.dtype)
 
 
-@jax.checkpoint
-def _selective_scan_chunked(x, dt, A_log, B, C, D):
-    f32 = jnp.float32
-    Bsz, T, Ch = x.shape
-    L = min(SCAN_CHUNK, T)
-    pad = (-T) % L
-    a = -jnp.exp(A_log.astype(f32))
-
-    def chunks(v):                   # [B, T, .] -> [nc, L, B, .]
-        v = jnp.moveaxis(v.astype(f32), 1, 0)
-        v = jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
-        return v.reshape((-1, L) + v.shape[1:])
-
-    def combine(early, late):        # h -> late.e (early.e h + early.b) + late.b
-        return late[0] * early[0], late[0] * early[1] + late[1]
-
-    @jax.checkpoint
-    def chunk(h, inp):
-        xc, dtc, bc, cc = inp
-        e = jnp.exp(dtc[..., None] * a)                     # [L, B, C, N]
-        bu = (dtc * xc)[..., None] * bc[:, :, None, :]
-        E, H = jax.lax.associative_scan(combine, (e, bu), axis=0)
-        hs = E * h[None] + H
-        return hs[-1], jnp.einsum("lbcn,lbn->lbc", hs, cc, precision=HI)
-
-    h0 = jnp.zeros((Bsz, Ch, A_log.shape[-1]), f32)
-    _, y = jax.lax.scan(chunk, h0, tuple(chunks(v) for v in (x, dt, B, C)))
-    y = jnp.moveaxis(y.reshape((-1,) + y.shape[2:])[:T], 0, 1)
-    return y + D.astype(f32) * x.astype(f32)
-
-
-def selective_scan_chunked(x, dt, A_log, B, C, D):
-    """The same function as `selective_scan_recurrent`, in chunks of
-    SCAN_CHUNK tokens: inside a chunk every state at once by an
-    associative scan of the pairs (exp(dt A), dt B x), between chunks one
-    state; each chunk and the whole function rematerialised in the
-    backward pass, so that a layer keeps its inputs and never a [T, C, N]
-    tensor."""
-    return _selective_scan_chunked(x, dt, A_log, B, C, D).astype(x.dtype)
-
-
 @kernel("selective_scan")
 def _selective_scan(ctx, ins, attrs):
     """X, Dt [B, T, C], ALog [C, N], B, C [B, T, N], D [C] -> Out [B, T, C]
@@ -283,11 +243,11 @@ def _selective_scan(ctx, ins, attrs):
     sequence; float32 inside. Dispatched through the kern registry (whose
     STATS count the calls and whose reference is the token-by-token
     recurrence) to the Mosaic kernels, `pallas.selective_scan.
-    try_selective_scan`; where that says None, `selective_scan_chunked`."""
+    try_selective_scan`; where that says None, the recurrence itself."""
     args = tuple(ins[s][0] for s in ("X", "Dt", "ALog", "B", "C", "D"))
     out = ctx.accel("selective_scan")(*args)
     if out is None:
-        out = selective_scan_chunked(*args)
+        out = selective_scan_recurrent(*args)
     return {"Out": [out]}
 
 

@@ -329,7 +329,7 @@ def supports(x, dt, A_log, B, C, D, interpret=False):
 
 def try_selective_scan(x, dt, A_log, B, C, D):
     """The dispatch entry (try_* convention): y through the kernels, or
-    None and the op lowers `kernels_scan.selective_scan_chunked`."""
+    None and the op lowers `kernels_scan.selective_scan_recurrent`."""
     use_pallas, interpret = active()
     if not use_pallas or not supports(x, dt, A_log, B, C, D):
         return None

@@ -42,7 +42,8 @@ PUBLISHED = {
     "num_logits_to_keep": 1, "rms_norm_eps": 1e-06, "sliding_window": None,
     "tie_word_embeddings": True, "use_mamba_kernels": True,
     "vocab_size": 65536}
-REDUCED = ["num_hidden_layers", "channels_held", "heads_held", "vocab_size"]
+REDUCED = ["num_hidden_layers", "channels_held", "heads_held",
+           "intermediate_held", "vocab_size"]
 
 
 def _run(root, *argv):
@@ -91,7 +92,7 @@ def test_every_published_width_is_unchanged_and_each_cut_is_listed(man):
     assert cfg["intermediate_held"] * 4 == PUBLISHED["intermediate_size"] \
         == cfg["published"]["intermediate_held"]
     assert cfg["vocab_size"] * 4 == PUBLISHED["vocab_size"]
-    assert set(REDUCED) <= set(cfg["reduced_why"])
+    assert set(REDUCED) == set(cfg["reduced_why"])
     assert all(len(why) > 40 for why in cfg["reduced_why"].values())
     # one whole period: attention at layer 7 of 0-13
     types = man.model("jamba2").layer_types(cfg)
@@ -102,11 +103,10 @@ def test_every_published_width_is_unchanged_and_each_cut_is_listed(man):
     assert (cfg["model"], cfg["driver"], cfg["entry"]) == (
         "jamba2", "train", "Executor.run")
     assert cfg["precision"]["control"] == "int8"
-    # no width among the cuts: the depth, two shares, the vocabulary
-    assert not any(re.search(r"_dim$|_rank$|_size$|hidden|intermediate|"
-                             r"state|expand", k)
-                   for k in REDUCED if k not in ("num_hidden_layers",
-                                                 "vocab_size"))
+    # every share this chip holds is a cut and listed: the depth, the
+    # three tensor-parallel shares, the vocabulary
+    assert set(REDUCED) == {"num_hidden_layers", "vocab_size"} | {
+        k for k in cfg if k.endswith("_held")}
 
 
 def test_the_held_parameters_are_counted_as_the_issue_counts_them(man, model):
@@ -150,8 +150,10 @@ def test_the_appended_entries_list_the_new_cell_alone(man):
                for p in ("forward", "backward", "optimizer")}
     wanted |= {f"train_op_ms_per_step.{op}" for op in (
         "selective_scan", "short_conv", "mul", "matmul", "rms_norm",
-        "swiglu", "flash_attention", "unscoped")}
+        "flash_attention", "unscoped")}
     wanted |= {"selective_scan_fwd_roofline", "selective_scan_bwd_roofline"}
+    wanted |= {"exec_compile_s.backend", "exec_gap_ms_per_step.fetch_readback",
+               "exec_gap_ms_per_step.feed_put"}
     assert {m["name"] for m in tagged} == {w + ".jamba2" for w in wanted}
     for m in tagged:
         assert m["workloads"] == [CELL]
@@ -213,7 +215,7 @@ def test_a_broken_timed_path_comes_out_not_correct(root, monkeypatch, fault):
 
         def d_only(x, dt, A_log, B, C, D):
             return (x * D).astype(x.dtype)
-        monkeypatch.setattr(scan, "selective_scan_chunked", d_only)
+        monkeypatch.setattr(scan, "selective_scan_recurrent", d_only)
     rc, res = _run(root, "--workload", CELL, "--seed", "43", "--seconds",
                    "1", "--trace", "0")
     assert rc == 0 and res["correct"] is False

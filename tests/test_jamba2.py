@@ -76,12 +76,15 @@ def test_short_conv_with_and_without_a_bias():
         "plain.w_0", "biased.w_0", "biased.b_0"}
 
 
-# the tiny programs of the cells that use short_conv without a bias, as
-# the parent commit builds them (ops, their inputs, outputs and attributes,
-# and every variable's dtype after the bf16 cast)
+# the tiny programs of the other cells, as the parent commit builds them
+# (ops, their inputs, outputs and attributes, and every variable's dtype
+# after the bf16 cast): lfm2 and solar use short_conv without a bias, and
+# every one goes through amp's cast
 UNCHANGED = {"lfm2_24b_a2b_train_ep8": ("lfm2_moe", "1a45c2b677b7ab8f"),
              "solar_open2_250b_train_ep40_tp8": ("solar_open2",
-                                                 "761403f0e7e9b50e")}
+                                                 "761403f0e7e9b50e"),
+             "mellum2_12b_train_ep4": ("mellum2", "e3c90e5f3cdcc0a6"),
+             "nmt_base_train_nodrop": ("nmt", "4e95d6b6eb799e41")}
 
 
 @pytest.mark.parametrize("config", sorted(UNCHANGED))
@@ -95,6 +98,9 @@ def test_the_other_decoders_programs_are_unchanged(config):
         cfg.update(json.load(f))
     traffic = {"kind": "lm_stream_batches", "rows": 1, "length": 96,
                "pool": 2}
+    if model_name == "nmt":
+        traffic = {"kind": "train_batches", "batch_rows": 4, "src_len": 16,
+                   "trg_len": 16, "pool": 2}
     main, _, _ = man.model(model_name).build(cfg, traffic, fluid)
     fluid.amp.cast_program_to_bf16(main)
     block = main.global_block()

@@ -3,8 +3,9 @@
 `layers.selective_scan`, forward and every gradient, against the
 token-by-token recurrence (`kernels_scan.selective_scan_recurrent`) at a
 length off the chunk, at two blocks of channels, at a strong decay; the
-float32 state under bfloat16 operands; the chunked composition that runs
-off the TPU; and the policy that decides between them from the shapes.
+float32 state under bfloat16 operands; the recurrence's blocks, which are
+the op's path off the TPU; and the policy that decides between them from
+the shapes.
 """
 import jax
 import jax.numpy as jnp
@@ -118,16 +119,33 @@ def test_bfloat16_in_float32_state_inside_bfloat16_out(interpret):
                                    rtol=eps, atol=eps * np.abs(w).max())
 
 
+def _plain_walk(x, dt, A_log, B, C, D):
+    """The recurrence in one `lax.scan` over every token: no blocks, no
+    padding, no recomputation."""
+    a = -jnp.exp(A_log)
+
+    def step(h, inp):
+        xt, dtt, bt, ct = inp
+        h = jnp.exp(dtt[..., None] * a) * h \
+            + (dtt * xt)[..., None] * bt[:, None, :]
+        return h, jnp.einsum("bcn,bn->bc", h, ct)
+
+    h0 = jnp.zeros((x.shape[0], x.shape[2], A_log.shape[-1]))
+    _, y = jax.lax.scan(step, h0, tuple(jnp.moveaxis(v, 1, 0)
+                                        for v in (x, dt, B, C)))
+    return jnp.moveaxis(y, 0, 1) + D * x
+
+
 @pytest.mark.parametrize("T", [300, 40], ids=["T300_two_chunks",
                                               "T40_one_short_chunk"])
-def test_the_chunked_composition_matches_the_recurrence(T):
-    """The op's path off the TPU (and at widths off 128): chunks of 256
-    tokens, an associative scan inside, forward and every gradient."""
+def test_the_blocked_recurrence_matches_one_plain_walk(T):
+    """The op's path off the TPU (and at widths off 128) walks blocks of
+    SCAN_CHUNK tokens, the last one padded, each recomputed in the
+    backward pass: forward and every gradient as one plain walk."""
     vals = _case(T, 24, Bsz=2, N=4, dt_max=1.0)
     probe = RNG.standard_normal(vals["x"].shape).astype("float32")
-    got, got_g = _recurrence_and_grads(vals, probe,
-                                       scan.selective_scan_chunked)
-    want, want_g = _recurrence_and_grads(vals, probe)
+    got, got_g = _recurrence_and_grads(vals, probe)
+    want, want_g = _recurrence_and_grads(vals, probe, _plain_walk)
     _close(got, want, got_g, want_g)
 
 
@@ -147,7 +165,7 @@ def test_the_recurrence_by_hand_at_the_first_two_tokens():
 
 
 def test_the_policy_reads_the_shapes_and_nothing_else(interpret):
-    """Channels of 64 are the composition's and `ssm.STATS` does not move;
+    """Channels of 64 are the recurrence's and `ssm.STATS` does not move;
     channels of 128 are the kernels'; with the kernels off, nothing is."""
     def counts():
         per = kreg.STATS["by_kernel"].get("selective_scan", {})
