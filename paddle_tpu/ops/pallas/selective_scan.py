@@ -33,9 +33,16 @@ e_{t+1} lam_{t+1} from the token after it),
     dA_log = sum_t g_t dt_t a             dD = sum_t dy_t x_t
 
 B_t and C_t arrive as rows of N lanes and are turned into columns exactly
-(the diagonal of the row's broadcast, summed over the lanes). Tokens are
-walked `UNROLL` at a time in straight-line code, so that one token's
-exponentials and loads overlap the previous token's chain of updates.
+(the diagonal of the row's broadcast, summed over the lanes by the XLU).
+Tokens are walked several at a time in straight-line code, so that one
+token's exponentials, loads and lane sums overlap the previous token's
+chain of updates: the forward `FWD_UNROLL` a loop step, the backward
+`BWD_UNROLL`, where a chunk holds a whole number of them (else `UNROLL`).
+The backward makes a loop step's columns one loop step before it (two
+slots in VMEM), so that their lane sums overlap a whole step of token work
+instead of holding up its start. Every float32 operation and its order is
+what the narrower walk does: the outputs do not depend on the unroll
+(bit for bit on the chip: PERF.md 6).
 """
 import functools
 
@@ -59,7 +66,11 @@ __all__ = ["try_selective_scan", "selective_scan", "supports", "STATS",
 # trace, as kda.STATS)
 STATS = {"pallas_calls": 0}
 
-UNROLL = 8               # tokens of one loop step, in straight-line code
+# tokens of one loop step, in straight-line code: the forward's and the
+# backward's where a chunk holds a whole number of them, else UNROLL
+UNROLL = 8
+FWD_UNROLL = 32
+BWD_UNROLL = 32
 _LANES = 128
 _BLOCK_LANES = 256       # the widest block of channels a grid step holds
 _F32 = jnp.float32
@@ -87,6 +98,38 @@ def _token(ref, t):
     return ref[pl.ds(t, 1), :]
 
 
+def _unroll(L, wide):
+    """`wide` tokens a loop step where a chunk of L holds a whole number of
+    them, else UNROLL."""
+    return wide if L % wide == 0 else UNROLL
+
+
+class _Columns:
+    """The backward's columns of B_t and C_t (one for each of `refs`), made
+    a loop step before the step that uses them: slot `i % 2` of `cs`
+    [2, >= len(refs) * u * N, 128] holds loop step i's, each `_col` broadcast
+    over 128 lanes, and step i fills the other slot for the next step, so
+    that the XLU's lane sums overlap a step of token work instead of
+    starting each step's chain of updates."""
+
+    def __init__(self, cs, refs, u, shape):
+        self.cs, self.refs, self.u = cs, refs, u
+        self.n, self.lanes = shape
+
+    def fill(self, slot, base):
+        for q, r in enumerate(self.refs):
+            for j in range(self.u):
+                self.cs[slot, pl.ds((q * self.u + j) * self.n, self.n), :] = \
+                    jnp.broadcast_to(_col(_token(r, base + j)),
+                                     (self.n, _LANES))
+
+    def at(self, slot, j):
+        """Token j of the step's columns, [N, lanes] each."""
+        return [jnp.concatenate(
+            [self.cs[slot, pl.ds((q * self.u + j) * self.n, self.n), :]]
+            * (self.lanes // _LANES), axis=1) for q in range(len(self.refs))]
+
+
 def _fwd_kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, y_ref, s_ref,
                 h_scr, xs, ys):
     @pl.when(pl.program_id(2) == 0)
@@ -98,10 +141,12 @@ def _fwd_kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, y_ref, s_ref,
     a = -jnp.exp(alog_ref[...])
     d = d_ref[...]
     xs[...] = x_ref[...].astype(_F32)
+    L = xs.shape[0]
+    u = _unroll(L, FWD_UNROLL)
 
     def step(i, h):
-        base = pl.multiple_of(i * UNROLL, UNROLL)
-        for j in range(UNROLL):
+        base = pl.multiple_of(i * u, u)
+        for j in range(u):
             t = base + j
             dt, x = _token(dt_ref, t), _token(xs, t)
             h = jnp.exp(dt * a) * h + _col(_token(b_ref, t)) * (dt * x)
@@ -109,13 +154,13 @@ def _fwd_kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, y_ref, s_ref,
                                          keepdims=True) + d * x
         return h
 
-    h_scr[...] = jax.lax.fori_loop(0, xs.shape[0] // UNROLL, step, h0)
+    h_scr[...] = jax.lax.fori_loop(0, L // u, step, h0)
     y_ref[...] = ys[...].astype(y_ref.dtype)
 
 
 def _bwd_kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, dy_ref, s_ref,
                 dx_ref, ddt_ref, db_ref, dc_ref, dalog_ref, dd_ref,
-                lam_scr, da_scr, dd_scr, hs, xs, dys, dxs):
+                lam_scr, da_scr, dd_scr, hs, xs, dys, dxs, cs):
     @pl.when(pl.program_id(2) == 0)
     def _():
         lam_scr[...] = jnp.zeros_like(lam_scr)
@@ -126,34 +171,44 @@ def _bwd_kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, dy_ref, s_ref,
     d = d_ref[...]
     xs[...] = x_ref[...].astype(_F32)
     dys[...] = dy_ref[...].astype(_F32)
-    steps = xs.shape[0] // UNROLL
+    L = xs.shape[0]
+    u = _unroll(L, BWD_UNROLL)
+    steps = L // u
+    bcols = _Columns(cs, (b_ref,), u, a.shape)
+    bcols.fill(0, 0)
 
     def rebuild(i, h):                  # hs[t]: the state before token t
-        base = pl.multiple_of(i * UNROLL, UNROLL)
-        for j in range(UNROLL):
+        base = pl.multiple_of(i * u, u)
+        slot = i % 2
+        for j in range(u):
             t = base + j
             hs[t] = h
             dt, x = _token(dt_ref, t), _token(xs, t)
-            h = jnp.exp(dt * a) * h + _col(_token(b_ref, t)) * (dt * x)
+            b, = bcols.at(slot, j)
+            h = jnp.exp(dt * a) * h + b * (dt * x)
+        bcols.fill(1 - slot, jnp.minimum(base + u, L - u))
         return h
 
     jax.lax.fori_loop(0, steps, rebuild, s_ref[...])
+    cols = _Columns(cs, (b_ref, c_ref), u, a.shape)
+    cols.fill(0, L - u)
 
     def step(i, carry):
         lam_next, da, dd = carry        # lam_next: e_{t+1} lam_{t+1}
-        base = pl.multiple_of((steps - 1 - i) * UNROLL, UNROLL)
-        for j in reversed(range(UNROLL)):
+        base = pl.multiple_of((steps - 1 - i) * u, u)
+        slot = i % 2
+        for j in reversed(range(u)):
             t = base + j
             dt, x, dy = _token(dt_ref, t), _token(xs, t), _token(dys, t)
-            b = _col(_token(b_ref, t))
+            b, c = cols.at(slot, j)
             e = jnp.exp(dt * a)
             h_prev = hs[t]
-            u = dt * x
-            h = e * h_prev + b * u
-            lam = _col(_token(c_ref, t)) * dy + lam_next
+            u_t = dt * x
+            h = e * h_prev + b * u_t
+            lam = c * dy + lam_next
             dc_ref[pl.ds(t, 1), :] = _row(jnp.sum(dy * h, axis=1,
                                                   keepdims=True))
-            db_ref[pl.ds(t, 1), :] = _row(jnp.sum(lam * u, axis=1,
+            db_ref[pl.ds(t, 1), :] = _row(jnp.sum(lam * u_t, axis=1,
                                                   keepdims=True))
             du = jnp.sum(lam * b, axis=0, keepdims=True)
             g = lam * h_prev * e
@@ -163,6 +218,7 @@ def _bwd_kernel(x_ref, dt_ref, b_ref, c_ref, alog_ref, d_ref, dy_ref, s_ref,
             da = da + g * dt
             dd = dd + dy * x
             lam_next = e * lam
+        cols.fill(1 - slot, jnp.maximum(base - u, 0))
         return lam_next, da, dd
 
     lam, da, dd = jax.lax.fori_loop(
@@ -277,7 +333,9 @@ def _bwd_call(res, dy, interpret):
                         pltpu.VMEM((L, N, cb), _F32),        # the states
                         pltpu.VMEM((L, cb), _F32),           # x
                         pltpu.VMEM((L, cb), _F32),           # dy
-                        pltpu.VMEM((L, cb), _F32)],          # dx
+                        pltpu.VMEM((L, cb), _F32),           # dx
+                        pltpu.VMEM((2, 2 * _unroll(L, BWD_UNROLL) * N,
+                                    _LANES), _F32)],         # columns
         name="selective_scan_bwd", interpret=interpret,
         **common)(*ops, dyp, states)
     return (dx[:, :T], ddt[:, :T].astype(dt.dtype),

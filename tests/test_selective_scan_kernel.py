@@ -2,10 +2,10 @@
 (ops/pallas/selective_scan.py) in the Pallas interpreter: the op through
 `layers.selective_scan`, forward and every gradient, against the
 token-by-token recurrence (`kernels_scan.selective_scan_recurrent`) at a
-length off the chunk, at two blocks of channels, at a strong decay; the
-float32 state under bfloat16 operands; the recurrence's blocks, which are
-the op's path off the TPU; and the policy that decides between them from
-the shapes.
+length off the chunk, at two blocks of channels, at a strong decay, at a
+short chunk of whole wide loop steps; the float32 state under bfloat16
+operands; the recurrence's blocks, which are the op's path off the TPU;
+and the policy that decides between them from the shapes.
 """
 import jax
 import jax.numpy as jnp
@@ -75,7 +75,9 @@ def _close(got, want, grads, want_g):
     (300, 256, 1, 0.1),     # off a multiple of the 256-token chunk
     (70, 512, 2, 0.1),      # two blocks of 256 channels, two rows
     (256, 128, 1, 2.0),     # one whole chunk, a strong decay: exp(-32)
-], ids=["T300_off_the_chunk", "C512_two_blocks", "T256_strong_decay"])
+    (90, 128, 2, 0.5),      # a chunk of 96: three wide loop steps, padded
+], ids=["T300_off_the_chunk", "C512_two_blocks", "T256_strong_decay",
+        "T90_wide_loop_steps"])
 def test_the_kernels_match_the_token_by_token_recurrence(interpret, T, C,
                                                          Bsz, dt_max):
     vals = _case(T, C, Bsz, dt_max=dt_max)
@@ -190,6 +192,11 @@ def test_the_policy_reads_the_shapes_and_nothing_else(interpret):
     assert counts() == (before[0] + 1, before[1] + 1, before[2])
     assert ssm._blocks(8192, 1280) == (256, 256)   # the cell: 5 blocks
     assert ssm._blocks(50, 512) == (56, 256)
+    # tokens a loop step: the wide ones where the chunk holds them whole
+    # (the cell's chunk of 256 takes both), else UNROLL
+    for u in (ssm.FWD_UNROLL, ssm.BWD_UNROLL):
+        assert ssm._unroll(256, u) == u
+    assert ssm._unroll(96, 32) == 32 and ssm._unroll(56, 32) == ssm.UNROLL
 
     args = [jnp.asarray(wide[n]) for n in NAMES]
     ops_registry.set_mode("off")
